@@ -26,12 +26,11 @@ end-to-end against the same 1-rank baseline as ``fig6_atad_P*``.
 from __future__ import annotations
 
 import json
-import os
 import re
 import subprocess
 import sys
 
-from benchmarks.common import emit, smoke
+from benchmarks.common import emit, fake_device_env, smoke
 from repro.core.task_tree import ell_distributed
 
 _CHILD = r"""
@@ -40,7 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.distributed import ata_tile_parallel
 devs = len(jax.devices())
 d = {d}; m = devs // d
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((d, m), ("data", "model"))
 r = np.random.default_rng(0)
 a_host = r.standard_normal(({m_}, {n})).astype(np.float32)
@@ -64,9 +63,7 @@ print("TIME", float(np.median(tc)), float(np.median(tt)))
 
 
 def _run_child(p: int, d: int, m: int, n: int):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-    env["PYTHONPATH"] = os.path.abspath("src")
+    env = fake_device_env(p)
     out = subprocess.run(
         [sys.executable, "-c", _CHILD.format(d=d, m_=m, n=n)],
         env=env, capture_output=True, text=True, timeout=900,
@@ -83,7 +80,7 @@ _COLLECTIVES_CHILD = r"""
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.analysis.hlo import collective_bytes, compiled_text
 from repro.core.distributed import ata_tile_parallel, gram_rowshard
 from repro.obs import metrics as obs_metrics
@@ -104,7 +101,7 @@ for mode in ("dense", "packed"):
 row_abs = jax.ShapeDtypeStruct((m, n), jnp.float32)
 for mode in ("dense", "packed"):
     out_spec = P(None, None, None) if mode == "packed" else P(None, None)
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda x, mode=mode: gram_rowshard(x, "data", out=mode),
         mesh=make_mesh((8,), ("data",)),
         in_specs=(P("data", None),), out_specs=out_spec))
@@ -116,9 +113,7 @@ print("BYTES " + json.dumps(out))
 
 
 def _run_collectives_child(p: int, m: int, n: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-    env["PYTHONPATH"] = os.path.abspath("src")
+    env = fake_device_env(p)
     script = _COLLECTIVES_CHILD.replace("@M@", str(m)).replace("@N@", str(n))
     out = subprocess.run(
         [sys.executable, "-c", script],
@@ -154,7 +149,7 @@ _BFSDFS_CHILD = r"""
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.analysis.hlo import collective_bytes, compiled_text
 from repro.core.distributed import ata_bfs_dfs
 from repro.obs import metrics as obs_metrics
@@ -192,9 +187,7 @@ print("BYTES " + json.dumps(out))
 
 
 def _run_bfsdfs_child(p: int, m: int, n: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-    env["PYTHONPATH"] = os.path.abspath("src")
+    env = fake_device_env(p)
     script = _BFSDFS_CHILD.replace("@M@", str(m)).replace("@N@", str(n))
     out = subprocess.run(
         [sys.executable, "-c", script],
@@ -241,7 +234,7 @@ def run_collectives_bfsdfs(m: int = 1024, n: int = 1024):
 _BFS_FIG6_CHILD = r"""
 import jax, jax.numpy as jnp, numpy as np, time
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.tune import cost
 from repro.tune.apply import ata_distributed_with_plan
 devs = len(jax.devices())
@@ -273,9 +266,7 @@ print("TIME", float(np.median(tc)), float(np.median(tt)))
 
 
 def _run_bfs_fig6_child(p: int, d: int, m: int, n: int):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-    env["PYTHONPATH"] = os.path.abspath("src")
+    env = fake_device_env(p)
     out = subprocess.run(
         [sys.executable, "-c", _BFS_FIG6_CHILD.format(d=d, m_=m, n=n)],
         env=env, capture_output=True, text=True, timeout=900,

@@ -9,17 +9,16 @@ speedup (Eq. 8 via the LPT makespan) for the same P.
 
 from __future__ import annotations
 
-import os
 import re
 import subprocess
 import sys
 
-from benchmarks.common import emit
+from benchmarks.common import emit, fake_device_env
 from repro.core.task_tree import ell_shared, modeled_speedup
 
 _CHILD = r"""
 import jax, jax.numpy as jnp, numpy as np, time
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import ata_tile_parallel
 mesh = make_mesh((len(jax.devices()),), ("model",))
 r = np.random.default_rng(0)
@@ -34,9 +33,7 @@ print("TIME", float(np.median(ts)))
 
 
 def _run_child(p: int, m: int, n: int) -> float:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-    env["PYTHONPATH"] = os.path.abspath("src")
+    env = fake_device_env(p)
     out = subprocess.run(
         [sys.executable, "-c", _CHILD.format(m=m, n=n)],
         env=env, capture_output=True, text=True, timeout=900,

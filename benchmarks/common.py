@@ -29,6 +29,7 @@ __all__ = [
     "drain_rows",
     "smoke",
     "SMOKE",
+    "fake_device_env",
 ]
 
 # rows emitted since the last drain — run.py drains after each bench module
@@ -44,6 +45,19 @@ _META: dict | None = None
 
 def smoke() -> bool:
     return SMOKE or os.environ.get("REPRO_BENCH_SMOKE") == "1"
+
+
+def fake_device_env(p: int) -> dict:
+    """Environment of a child process that emulates ``p`` devices on the CPU.
+
+    ``JAX_PLATFORMS=cpu`` keeps the child off any accelerator: the parent
+    process has already opened JAX and holds the chip, if there is one.
+    """
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
+    env["PYTHONPATH"] = os.path.abspath("src")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 def backend_meta() -> dict:

@@ -116,6 +116,9 @@ class _profile_trace:
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:]
     out_dir = "."
     profile = False

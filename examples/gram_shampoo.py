@@ -60,7 +60,7 @@ def main():
 
     # distributed gram on this host's device pool (1 device here; run with
     # XLA_FLAGS=--xla_force_host_platform_device_count=8 for real sharding)
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((len(jax.devices()),), ("model",))
     a = jnp.asarray(np.random.default_rng(1).standard_normal((1024, 512)), jnp.float32)

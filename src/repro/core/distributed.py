@@ -55,12 +55,12 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro import obs
-from repro.compat import shard_map
 from repro.core.ata import ata
-from repro.core.strassen import strassen_tn
+from repro.core.precision import dot_precision
+from repro.core.strassen import _plan_base_fns, strassen_tn
 from repro.core.symmetric import SymmetricMatrix, sym_tile
 
 __all__ = [
@@ -126,6 +126,7 @@ def gram_rowshard(
         else:
             local = jax.lax.dot_general(
                 a_local, a_local, (((0,), (0,)), ((), ())),
+                precision=dot_precision(a_local),
                 preferred_element_type=jnp.float32,
             )
             if out == "packed":
@@ -169,6 +170,18 @@ def choose_tiling(
     )
 
 
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which the
+    schedules' root-level re-tiling of a sharded tile stack (slices and
+    scatters across the sharded dim) is a type error. The schedules leave
+    that data movement to the compiler, as on an ``Auto`` mesh.
+    """
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def _tri_coords_traced(t):
     tf = t.astype(jnp.float32)
     i = jnp.floor((jnp.sqrt(8.0 * tf + 1.0) - 1.0) / 2.0).astype(jnp.int32)
@@ -176,6 +189,62 @@ def _tri_coords_traced(t):
     i = jnp.where(i * (i + 1) // 2 > t, i - 1, i)
     j = t - i * (i + 1) // 2
     return i, j
+
+
+def _tile_fn(w, *, plan, use_strassen, n_base, variant, leaf_dispatch,
+             acc_dtype):
+    """``compute_tile(a_local, t)``: tri-order tile ``t`` of ``a_localᵀa_local``
+    over stripes of width ``w`` — the leaf body both tile schedules share.
+    A plan with ``use_kernels`` puts its Pallas TN kernel at the bottom of
+    every tile's Strassen recursion."""
+    _, base_dot = _plan_base_fns(plan, None, None)
+
+    def compute_tile(a_local, t):
+        i, j = _tri_coords_traced(t)
+        ai = jax.lax.dynamic_slice_in_dim(a_local, i * w, w, axis=1)
+        aj = jax.lax.dynamic_slice_in_dim(a_local, j * w, w, axis=1)
+        if use_strassen:
+            return strassen_tn(
+                ai, aj, n_base=n_base, variant=variant,
+                leaf_dispatch=leaf_dispatch, base_dot=base_dot,
+                acc_dtype=acc_dtype,
+            )
+        return jax.lax.dot_general(
+            ai, aj, (((0,), (0,)), ((), ())),
+            precision=dot_precision(ai, aj),
+            preferred_element_type=acc_dtype,
+        )
+
+    return compute_tile
+
+
+def _slot_tile(compute_tile, a_local, g, valid, like):
+    """One per-device tile slot: tile ``g``, or a zero dummy tile.
+
+    When T % p ≠ 0 some devices own dummy slots. They are **masked to a
+    zero tile** behind ``lax.cond`` — real control flow, so the dummy's
+    dot never runs — which keeps the exact LPT flop model
+    (:func:`tile_parallel_device_flops`, regression-tested). ``valid`` is
+    ``True`` for a slot that is real on every device, which skips the cond
+    statically; otherwise it is the traced predicate, and ``g`` must
+    already be clamped to a real tile id.
+
+    Under ``shard_map`` the computed tile varies over the mesh axes its
+    inputs vary over (``g`` comes from ``axis_index``), so the zero tile
+    is cast to the same varying axes: ``cond`` requires both branches to
+    have one type, varying manual axes included. Its shape and dtype come
+    from ``like``, the ``eval_shape`` of a real tile, so they follow
+    ``acc_dtype``.
+    """
+    if valid is True:
+        return compute_tile(a_local, g)
+    vma = tuple(sorted(jax.typeof(a_local).vma | jax.typeof(g).vma))
+
+    def dummy():
+        z = jnp.zeros(like.shape, like.dtype)
+        return jax.lax.pcast(z, vma, to="varying") if vma else z
+
+    return jax.lax.cond(valid, lambda: compute_tile(a_local, g), dummy)
 
 
 def ata_tile_parallel(
@@ -283,29 +352,9 @@ def ata_tile_parallel(
     if n_pad > n:
         a = jnp.pad(a, ((0, 0), (0, n_pad - n)))
 
-    def compute_tile(a_local, t):
-        i, j = _tri_coords_traced(t)
-        ai = jax.lax.dynamic_slice_in_dim(a_local, i * w, w, axis=1)
-        aj = jax.lax.dynamic_slice_in_dim(a_local, j * w, w, axis=1)
-        if use_strassen:
-            return strassen_tn(
-                ai, aj, n_base=n_base, variant=variant,
-                leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
-            )
-        return jax.lax.dot_general(
-            ai, aj, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc_dtype,
-        )
-
-    # shape/dtype of one computed tile, without tracing a real one: the
-    # dummy-slot zero tile must agree with it exactly, or the two lax.cond
-    # branches fail to trace (e.g. a bf16 accumulation dtype against the
-    # previously hardcoded f32 dummy).
-    m_local = m // mesh.shape[row_axis] if row_axis is not None else m
-    tile_abs = jax.eval_shape(
-        compute_tile,
-        jax.ShapeDtypeStruct((m_local, n_pad), a.dtype),
-        jax.ShapeDtypeStruct((), jnp.int32),
+    compute_tile = _tile_fn(
+        w, plan=plan, use_strassen=use_strassen, n_base=n_base,
+        variant=variant, leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
     )
 
     obs.metrics.inc("dispatch.ata_tile_parallel")
@@ -313,26 +362,16 @@ def ata_tile_parallel(
 
     def local_fn(a_local):
         p = jax.lax.axis_index(task_axis)
+        like = jax.eval_shape(compute_tile, a_local, p)
 
         def tile_slot(q):
-            """Slot q of this device: tile p·t_per+q, or a zero dummy.
-
-            When T % p ≠ 0 the trailing devices own dummy slots. The seed
-            clamped them to tile T−1 and recomputed it up to t_per−1 extra
-            times per device; dummies are now **masked to a zero tile**
-            behind ``lax.cond`` — real control flow, so the dot never runs —
-            which restores the exact LPT flop model
-            (:func:`tile_parallel_device_flops`, regression-tested).
-            Slots that are valid on *every* device skip the cond statically.
-            """
+            # slot q of this device: tile p·t_per+q, statically when it is
+            # valid on every device, else behind the dummy mask
             g = p * t_per + q
             if (p_task - 1) * t_per + q < t_total:
-                return compute_tile(a_local, g)
-            return jax.lax.cond(
-                g < t_total,
-                lambda: compute_tile(a_local, jnp.minimum(g, t_total - 1)),
-                lambda: jnp.zeros(tile_abs.shape, tile_abs.dtype),
-            )
+                return _slot_tile(compute_tile, a_local, g, True, like)
+            return _slot_tile(compute_tile, a_local,
+                              jnp.minimum(g, t_total - 1), g < t_total, like)
 
         # python-unrolled tile loop (t_per is small): keeps every tile's
         # matmuls visible to XLA's cost model (lax.map would count the body
@@ -346,8 +385,9 @@ def ata_tile_parallel(
         return tiles
 
     in_spec = P(row_axis, None) if row_axis else P(None, None)
-    tiles = shard_map(
-        local_fn, mesh=mesh, in_specs=(in_spec,), out_specs=P(task_axis, None, None)
+    tiles = jax.shard_map(
+        local_fn, mesh=_auto_axes(mesh), in_specs=(in_spec,),
+        out_specs=P(task_axis, None, None),
     )(a)
     # tiles: global (p_task * t_per, w, w), tri-enumerated — exactly the
     # packed retrieval payload. Assemble the SymmetricMatrix straight from
@@ -642,25 +682,9 @@ def ata_bfs_dfs(
     if n_pad > n:
         a = jnp.pad(a, ((0, 0), (0, n_pad - n)))
 
-    def compute_tile(a_local, t):
-        i, j = _tri_coords_traced(t)
-        ai = jax.lax.dynamic_slice_in_dim(a_local, i * w, w, axis=1)
-        aj = jax.lax.dynamic_slice_in_dim(a_local, j * w, w, axis=1)
-        if use_strassen:
-            return strassen_tn(
-                ai, aj, n_base=n_base, variant=variant,
-                leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
-            )
-        return jax.lax.dot_general(
-            ai, aj, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc_dtype,
-        )
-
-    m_local = m // d_row
-    tile_abs = jax.eval_shape(
-        compute_tile,
-        jax.ShapeDtypeStruct((m_local, n_pad), a.dtype),
-        jax.ShapeDtypeStruct((), jnp.int32),
+    compute_tile = _tile_fn(
+        w, plan=plan, use_strassen=use_strassen, n_base=n_base,
+        variant=variant, leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
     )
 
     obs.metrics.inc("dispatch.ata_bfs_dfs")
@@ -677,16 +701,14 @@ def ata_bfs_dfs(
     def local_fn(a_local):
         pidx = jax.lax.axis_index(task_axis)
         row = jax.lax.dynamic_slice_in_dim(table, pidx, 1, axis=0)[0]
+        like = jax.eval_shape(compute_tile, a_local, pidx)
 
         def tile_slot(q):
             g = row[q]
             if all_valid[q]:
-                return compute_tile(a_local, g)
-            return jax.lax.cond(
-                g >= 0,
-                lambda: compute_tile(a_local, jnp.maximum(g, 0)),
-                lambda: jnp.zeros(tile_abs.shape, tile_abs.dtype),
-            )
+                return _slot_tile(compute_tile, a_local, g, True, like)
+            return _slot_tile(compute_tile, a_local, jnp.maximum(g, 0),
+                              g >= 0, like)
 
         with obs.span("distributed.tile_body", t_per=s_eff, w=w):
             tiles = jnp.stack([tile_slot(q) for q in range(s_eff)])
@@ -723,8 +745,8 @@ def ata_bfs_dfs(
     in_spec = P(row_axis, None) if row_axis else P(None, None)
     out_spec = (P(merged, None, None) if scatter
                 else P(task_axis, None, None))
-    tiles = shard_map(
-        local_fn, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec
+    tiles = jax.shard_map(
+        local_fn, mesh=_auto_axes(mesh), in_specs=(in_spec,), out_specs=out_spec
     )(a)
     # either way the global stack is the tri-order prefix: scatter path by
     # construction (chunk k holds tiles [k·chunk, (k+1)·chunk)), psum path
@@ -850,17 +872,19 @@ def gemm_tn_colshard(
     # on the per-device leaf shape (m, n, k/p) — every dispatch is planned.
 
     obs.metrics.inc("dispatch.gemm_tn_colshard")
+    _, base_dot = _plan_base_fns(plan, None, None)
 
     def local_fn(a_local, b_local):
         with obs.span("distributed.colshard_body", use_strassen=use_strassen):
             if use_strassen:
                 c_local = strassen_tn(
                     a_local, b_local, n_base=n_base, variant=variant,
-                    leaf_dispatch=leaf_dispatch,
+                    leaf_dispatch=leaf_dispatch, base_dot=base_dot,
                 )
             else:
                 c_local = jax.lax.dot_general(
                     a_local, b_local, (((0,), (0,)), ((), ())),
+                    precision=dot_precision(a_local, b_local),
                     preferred_element_type=jnp.float32,
                 )
         if row_axis is not None:
@@ -869,9 +893,9 @@ def gemm_tn_colshard(
         return c_local
 
     row_spec = row_axis if row_axis else None
-    return shard_map(
+    return jax.shard_map(
         local_fn,
-        mesh=mesh,
+        mesh=_auto_axes(mesh),
         in_specs=(P(row_spec, None), P(row_spec, task_axis)),
         out_specs=P(None, task_axis),
     )(a, b)

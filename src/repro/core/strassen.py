@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core.precision import dot_precision
 from repro.tune.defaults import DEFAULT_N_BASE  # re-export (tunables live there)
 
 __all__ = ["strassen_tn", "DEFAULT_N_BASE", "resolve_tunables"]
@@ -168,6 +169,7 @@ def _dot_tn(a, b, acc_dtype):
         a,
         b,
         dimension_numbers=(((nb,), (nb,)), (batch, batch)),
+        precision=dot_precision(a, b),
         preferred_element_type=acc_dtype,
     )
 

@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.core.precision import dot_precision
+from repro.kernels.shapes import out_struct
 
 # (bm, bn, bk): contraction block, output-row block, output-col block.
 # The constant lives with every other tunable in repro.tune.defaults; the
@@ -51,10 +52,12 @@ def _gemm_tn_kernel(a_ref, b_ref, c_ref, acc_ref, *, alpha: float, l_axis: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    a = a_ref[...].reshape(a_ref.shape[-2:])
+    b = b_ref[...].reshape(b_ref.shape[-2:])
     acc_ref[...] += jax.lax.dot_general(
-        a_ref[...].reshape(a_ref.shape[-2:]),
-        b_ref[...].reshape(b_ref.shape[-2:]),
+        a, b,
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=dot_precision(a, b),
         preferred_element_type=jnp.float32,
     )
 
@@ -128,9 +131,9 @@ def gemm_tn_pallas(
         out_specs=pl.BlockSpec(
             lead + (bn, bk), lambda *idx: _pre(idx) + (idx[-3], idx[-2])
         ),
-        out_shape=jax.ShapeDtypeStruct(batch_dims + (np_, kp), out_dtype),
+        out_shape=out_struct(batch_dims + (np_, kp), out_dtype, a, b),
         scratch_shapes=[pltpu.VMEM((bn, bk), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * l_axis + ("arbitrary",),
         ),
         interpret=interpret,
@@ -181,10 +184,12 @@ def _gemm_tn_fused_kernel(
         mid = (lo + hi) // 2
         return combine(slot_refs, sgn, lo, mid) + combine(slot_refs, sgn, mid, hi)
 
+    a = combine(a_refs, asg, 0, w)
+    b = combine(b_refs, bsg, 0, w)
     acc_ref[...] += jax.lax.dot_general(
-        combine(a_refs, asg, 0, w),
-        combine(b_refs, bsg, 0, w),
+        a, b,
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=dot_precision(a, b),
         preferred_element_type=jnp.float32,
     )
 
@@ -292,10 +297,11 @@ def gemm_tn_fused_pallas(
             _gemm_tn_fused_kernel, w=w, alpha=alpha, t_axis=t_axis, l_axis=l_axis
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (g_count * t_count,) + batch_dims + (np_, kp), out_dtype
+        out_shape=out_struct(
+            (g_count * t_count,) + batch_dims + (np_, kp), out_dtype,
+            a_blocks, b_blocks,
         ),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * l_axis + ("arbitrary",),
         ),
         interpret=interpret,

@@ -34,8 +34,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels.shapes import out_struct
 
 __all__ = ["potrf_pallas"]
 
@@ -88,8 +89,8 @@ def potrf_pallas(
         grid=grid,
         in_specs=[pl.BlockSpec(lead + (nn, nn), lambda *idx: _pre(idx) + (0, 0))],
         out_specs=pl.BlockSpec(lead + (nn, nn), lambda *idx: _pre(idx) + (0, 0)),
-        out_shape=jax.ShapeDtypeStruct(batch_dims + (nn, nn), out_dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=out_struct(batch_dims + (nn, nn), out_dtype, a),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * len(batch_dims) + ("arbitrary",),
         ),
         interpret=interpret,

@@ -9,13 +9,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import dot_precision
+
 __all__ = ["syrk_ref", "gemm_tn_ref"]
 
 
 def gemm_tn_ref(a: jax.Array, b: jax.Array, alpha: float = 1.0) -> jax.Array:
     """``C = alpha·AᵀB`` with f32 accumulation, f32 output."""
     out = jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((0,), (0,)), ((), ())), precision=dot_precision(a, b),
+        preferred_element_type=jnp.float32,
     )
     return (alpha * out).astype(jnp.float32)
 

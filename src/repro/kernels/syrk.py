@@ -49,8 +49,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.core.precision import dot_precision
 from repro.core.symmetric import SymmetricMatrix, default_block_size, sym_tile
+from repro.kernels.shapes import out_struct
 
 # (bm, bn): contraction block, output block (output tiles are bn × bn).
 # The constant lives with every other tunable in repro.tune.defaults; the
@@ -90,10 +91,12 @@ def _syrk_kernel(
 
     @pl.when(l < n_l)
     def _accum():
+        ai = ai_ref[...].reshape(ai_ref.shape[-2:])
+        aj = aj_ref[...].reshape(aj_ref.shape[-2:])
         acc_ref[...] += jax.lax.dot_general(
-            ai_ref[...].reshape(ai_ref.shape[-2:]),
-            aj_ref[...].reshape(aj_ref.shape[-2:]),
+            ai, aj,
             dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=dot_precision(ai, aj),
             preferred_element_type=jnp.float32,
         )
 
@@ -207,7 +210,7 @@ def syrk_pallas(
         out_specs = pl.BlockSpec(
             lead + (1, bn, bn), lambda *idx: _pre(idx) + (idx[-2], 0, 0)
         )
-        out_shape = jax.ShapeDtypeStruct(batch_dims + (t_total, bn, bn), out_dtype)
+        out_shape = out_struct(batch_dims + (t_total, bn, bn), out_dtype, a)
     else:
 
         def _c_index(*idx):
@@ -216,7 +219,7 @@ def syrk_pallas(
             return _pre(idx) + (jnp.where(lower, i, j), jnp.where(lower, j, i))
 
         out_specs = pl.BlockSpec(lead + (bn, bn), _c_index)
-        out_shape = jax.ShapeDtypeStruct(batch_dims + (np_, np_), out_dtype)
+        out_shape = out_struct(batch_dims + (np_, np_), out_dtype, a)
     dim_sem = ("parallel",) * (len(grid) - 1) + ("arbitrary",)
 
     raw = pl.pallas_call(
@@ -226,7 +229,7 @@ def syrk_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bn, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(dimension_semantics=dim_sem),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=dim_sem),
         interpret=interpret,
         name="syrk_packed" if out == "packed" else "syrk_dual",
     )(a, a)
@@ -331,10 +334,10 @@ def syrk_gather_pallas(
     raw = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (s_count,) + batch_dims + (np_, np_), out_dtype
+        out_shape=out_struct(
+            (s_count,) + batch_dims + (np_, np_), out_dtype, a_blocks
         ),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",),
         ),
         interpret=interpret,

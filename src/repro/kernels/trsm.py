@@ -32,8 +32,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels.shapes import out_struct
 
 __all__ = ["trsm_pallas"]
 
@@ -119,8 +120,8 @@ def trsm_pallas(
         out_specs=pl.BlockSpec(
             lead + (bm, nn), lambda *idx: _pre(idx) + (idx[-1], 0)
         ),
-        out_shape=jax.ShapeDtypeStruct(batch_dims + (mp, nn), out_dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=out_struct(batch_dims + (mp, nn), out_dtype, l, b_pad),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * len(grid),
         ),
         interpret=interpret,

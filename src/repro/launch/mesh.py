@@ -10,9 +10,8 @@ from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple, Union
 
-from jax.sharding import Mesh
-
-from repro.compat import make_mesh as _compat_make_mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
     "make_production_mesh",
@@ -28,9 +27,9 @@ MULTI_POD = (2, 16, 16)        # 2 pods = 512 chips
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh with explicit Auto axis types (GSPMD propagation)
-    where the installed jax supports them."""
-    return _compat_make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types (GSPMD propagation), not the
+    Explicit default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def merged_axis(

@@ -14,7 +14,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 
 __all__ = [
     "rms_norm",
@@ -406,7 +405,7 @@ def attention_decode_sp(
         args = args + (win_arg,)
     else:
         fn = lambda q_l, kn, vn, ck, cv, pl: local(q_l, kn, vn, ck, cv, pl, None)
-    out, ck, cv = shard_map(
+    out, ck, cv = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )(*args)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
@@ -482,7 +481,7 @@ def attention_train_cp(
     b_ax = tuple(a for a in ("pod", "data")
                  if a in mesh.shape and b % mesh.shape[a] == 0) or None
     rep4 = P(b_ax, None, None, None)
-    out = shard_map(
+    out = jax.shard_map(
         lambda xf, wq, wk, wv, wo, bq_, bk_, bv_: local(xf, wq, wk, wv, wo,
                                                         bq_, bk_, bv_),
         mesh=mesh,

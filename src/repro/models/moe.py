@@ -30,7 +30,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 __all__ = ["init_moe", "moe_layer", "moe_capacity"]
 
@@ -176,7 +175,7 @@ def moe_layer(
     # model-invariant and model-varying values, which the strict VMA
     # checker rejects even though the collective semantics are exactly
     # what we want (classic shard_map behavior).
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )(x, p["router"], p["wg"], p["wu"], p["wd"])
     return y, aux

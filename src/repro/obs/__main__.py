@@ -28,6 +28,9 @@ def main(argv=None) -> int:
         i = argv.index("--out")
         out_path = argv[i + 1]
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     obs.enable()
 
     import dataclasses

@@ -117,6 +117,10 @@ def main(argv=None) -> int:
     if not (args.warm or args.smoke or args.replay):
         ap.error("pick one of --warm / --smoke / --replay")
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.obs import metrics as obs_metrics
     from repro.serve import metrics as serve_metrics
     from repro.serve.bucketing import BucketLattice, BucketSpec

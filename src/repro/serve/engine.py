@@ -369,6 +369,7 @@ def _build_bucket_fn(spec: BucketSpec, sp):
     import jax.numpy as jnp
 
     from repro.core.ata import ata_batched
+    from repro.core.precision import dot_precision
     from repro.solve.cholesky import cholesky
     from repro.solve.triangular import solve_cholesky, solve_triangular
 
@@ -390,6 +391,7 @@ def _build_bucket_fn(spec: BucketSpec, sp):
             # _dot_tn (Aᵀ never formed)
             rhs = jax.lax.dot_general(
                 a32, b32, (((1,), (1,)), ((0,), (0,))),
+                precision=dot_precision(a32, b32),
                 preferred_element_type=jnp.float32)
             return solve_cholesky(f, rhs, plan=sp, base_trsm=per_slice_trsm)
         # whiten: z = L⁻¹·v — forward substitution only

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.core.precision import dot_precision
 
 __all__ = ["cg_gram", "cg_lstsq"]
 
@@ -136,7 +137,8 @@ def cg_lstsq(
         # (m, r) plain NN dot — accumulation width pinned so the operator
         # keeps f32 accumulation even if the cast above is ever relaxed to
         # sub-f32 operands (the repro.check acc-dtype contract)
-        ap = jnp.matmul(a, p, preferred_element_type=jnp.float32)
+        ap = jnp.matmul(a, p, precision=dot_precision(a, p),
+                        preferred_element_type=jnp.float32)
         atap = strassen_tn(a, ap, **kw)    # Aᵀ(A·p): planned TN product
         return atap + ridge * p if ridge else atap
 

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import dot_precision
 from repro.core.symmetric import (
     SymmetricMatrix,
     default_block_size,
@@ -265,6 +266,7 @@ def cholesky(
             # the repro.check acc-dtype contract
             s = s - jnp.einsum(
                 "k...ab,k...cb->...ac", lrow, lrow,
+                precision=dot_precision(lrow),
                 preferred_element_type=jnp.float32,
             )
         # the LOWER half of a packed diagonal tile is the authoritative
@@ -290,6 +292,7 @@ def cholesky(
             )
             p = p - jnp.einsum(
                 "rk...ab,k...cb->r...ac", li, lrow,
+                precision=dot_precision(li, lrow),
                 preferred_element_type=jnp.float32,
             )
         ljj = jnp.broadcast_to(out[(j, j)], p.shape)

@@ -29,6 +29,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import dot_precision
 from repro.solve.cholesky import CholeskyFactor, _flat_call
 
 __all__ = ["solve_triangular", "solve_cholesky"]
@@ -100,6 +101,7 @@ def solve_triangular(
                 # repro.check acc-dtype contract)
                 c = c - jnp.einsum(
                     "k...ab,k...br->...ar", lt, xt,
+                    precision=dot_precision(lt, xt),
                     preferred_element_type=jnp.float32,
                 )
         else:
@@ -109,6 +111,7 @@ def solve_triangular(
                 xt = jnp.stack([xs[j] for j in done], axis=0)
                 c = c - jnp.einsum(
                     "k...ba,k...br->...ar", lt, xt,
+                    precision=dot_precision(lt, xt),
                     preferred_element_type=jnp.float32,
                 )
         xs[i] = solve_diag(f.block(i, i), c, transpose=transpose)

@@ -225,6 +225,10 @@ class Machine:
     # (CAPS's memory-vs-bandwidth rule): schedules whose per-device
     # residency exceeds it are infeasible.
     device_memory_bytes: float = 16e9
+    # ``device_kind`` strings (as JAX reports them) the parameters were set
+    # for; empty for a model not tied to one chip. ``machine_for`` refuses
+    # to price an attached device of any other kind.
+    device_kinds: Tuple[str, ...] = ()
 
     def mxu_eff(self, d: int) -> float:
         d = max(int(d), 1)
@@ -241,6 +245,7 @@ def _tpu_machine() -> Machine:
         launch_overhead_s=1.5e-6,
         # ICI-class interconnect: ~1 µs collective step, ~9e10 B/s per link
         alpha_s=1e-6, beta_s_per_byte=1.1e-11, device_memory_bytes=16e9,
+        device_kinds=("TPU v5 lite",),  # the v5e, as JAX names it
     )
 
 
@@ -282,7 +287,29 @@ MACHINES = {
 
 
 def machine_for(backend: str) -> Machine:
-    return MACHINES.get(backend, MACHINES["cpu"])()
+    """The cost model of ``backend``.
+
+    Raises for a backend that has no model, and for an attached device
+    whose ``device_kind`` the model was not set for. Planning for a
+    backend that is not attached (``backend='tpu'`` on a CPU host) prices
+    the model as stated.
+    """
+    if backend not in MACHINES:
+        raise ValueError(
+            f"no cost model for backend {backend!r}; known: {sorted(MACHINES)}"
+        )
+    mach = MACHINES[backend]()
+    if mach.device_kinds:
+        import jax
+
+        if jax.default_backend() == backend:
+            kind = jax.devices()[0].device_kind
+            if kind not in mach.device_kinds:
+                raise ValueError(
+                    f"the {backend!r} cost model was set for "
+                    f"{mach.device_kinds}, not the attached {kind!r}"
+                )
+    return mach
 
 
 # ---------------------------------------------------------------------------

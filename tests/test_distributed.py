@@ -233,7 +233,7 @@ a = jax.device_put(a, NamedSharding(mesh, P("data", None)))
 f = jax.jit(lambda a: ata_tile_parallel(
     a, mesh, task_axis="model", row_axis="data", n_base=32))
 c = f(a)
-np.testing.assert_allclose(np.asarray(c), np.asarray(a.T @ a), rtol=1e-4, atol=1e-4)
+np.testing.assert_allclose(np.asarray(c), np.asarray(a).T @ np.asarray(a), rtol=1e-4, atol=1e-4)
 # collective check: the psum reduces the packed tile stack, not dense (n,n)
 from repro.analysis.hlo import compiled_text
 hlo = compiled_text(f, a)
@@ -244,12 +244,11 @@ print("OK")
 ROWSHARD_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.distributed import gram_rowshard
 mesh = jax.make_mesh((8,), ("data",))
 r = np.random.default_rng(2)
 a = jnp.asarray(r.standard_normal((512, 96)), dtype=jnp.float32)
-f = jax.jit(shard_map(
+f = jax.jit(jax.shard_map(
     lambda x: gram_rowshard(x, "data", n_base=32),
     mesh=mesh, in_specs=(P("data", None),), out_specs=P(None, None)))
 c = f(a)
@@ -333,7 +332,7 @@ f_packed = jax.jit(lambda a: ata_tile_parallel(
     a, mesh, task_axis="model", row_axis="data", n_base=32, out="packed"))
 dense, packed = f_dense(a), f_packed(a)
 assert (np.asarray(packed.to_dense()) == np.asarray(dense)).all()
-np.testing.assert_allclose(np.asarray(dense), np.asarray(a.T @ a),
+np.testing.assert_allclose(np.asarray(dense), np.asarray(a).T @ np.asarray(a),
                            rtol=1e-4, atol=1e-4)
 print("OK")
 """
@@ -341,19 +340,18 @@ print("OK")
 ROWSHARD_PACKED_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.distributed import gram_rowshard
 from repro.analysis.hlo import collective_bytes, compiled_text
 mesh = jax.make_mesh((8,), ("data",))
 r = np.random.default_rng(7)
 a = jnp.asarray(r.standard_normal((512, 96)), dtype=jnp.float32)
-fd = jax.jit(shard_map(
+fd = jax.jit(jax.shard_map(
     lambda x: gram_rowshard(x, "data", n_base=32),
     mesh=mesh, in_specs=(P("data", None),), out_specs=P(None, None)))
 # packed_block=24 -> a 4x4 packed grid (T=10 of 16 blocks): the psum moves
 # T*bn^2 = 0.625*n^2 words; n=96 with the default 128-block would be a
 # single block (no saving to observe)
-fp = jax.jit(shard_map(
+fp = jax.jit(jax.shard_map(
     lambda x: gram_rowshard(x, "data", n_base=32, out="packed",
                             packed_block=24),
     mesh=mesh, in_specs=(P("data", None),), out_specs=P(None, None, None)))
@@ -391,7 +389,6 @@ print("OK")
 FUSED_DISPATCH_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.distributed import ata_tile_parallel, gemm_tn_colshard, gram_rowshard
 mesh = jax.make_mesh((8,), ("model",))
 r = np.random.default_rng(10)
@@ -416,7 +413,7 @@ assert (np.asarray(gu) == np.asarray(gf)).all()
 # rowshard: fused local gram under the packed psum
 mesh2 = jax.make_mesh((8,), ("data",))
 a2 = jnp.asarray(r.standard_normal((512, 96)), dtype=jnp.float32)
-mkr = lambda ld: jax.jit(shard_map(
+mkr = lambda ld: jax.jit(jax.shard_map(
     lambda x: gram_rowshard(x, "data", n_base=32, variant="strassen",
                             leaf_dispatch=ld),
     mesh=mesh2, in_specs=(P("data", None),), out_specs=P(None, None)))
@@ -442,7 +439,7 @@ kw = dict(mesh=mesh, task_axis="model", row_axis="data", n_base=32, nb=4,
           packed_block=48)
 dense0 = jax.jit(lambda a: ata_tile_parallel(a, **kw))(a)
 packed0 = jax.jit(lambda a: ata_tile_parallel(a, out="packed", **kw))(a)
-np.testing.assert_allclose(np.asarray(dense0), np.asarray(a.T @ a),
+np.testing.assert_allclose(np.asarray(dense0), np.asarray(a).T @ np.asarray(a),
                            rtol=1e-4, atol=1e-4)
 for il in ("D", "B", "BD", "DB"):
     dense = jax.jit(lambda a, il=il: ata_bfs_dfs(a, interleaving=il, **kw))(a)
@@ -584,7 +581,6 @@ print("OK")
 POWERSGD_SHARDED_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 from repro.optim import powersgd
 mesh = jax.make_mesh((8,), ("data",))
 r = np.random.default_rng(9)
@@ -598,7 +594,7 @@ def sharded(g, err, q):
     st = powersgd.PowerSGDState(q=q, error=err)
     p_l, q_new, st_new = powersgd.compress_sharded(g, st, "data", n_base=32)
     return p_l, q_new, st_new.error
-f = jax.jit(shard_map(
+f = jax.jit(jax.shard_map(
     sharded, mesh=mesh,
     in_specs=(P("data", None), P("data", None), P(None, None)),
     out_specs=(P("data", None), P(None, None), P("data", None))))
@@ -639,7 +635,7 @@ def test_bfsdfs_six_devices():
 
 SP_DECODE_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_smoke
 from repro.models import layers as L
 
@@ -669,7 +665,7 @@ def test_seq_parallel_flash_decode():
 
 CP_ATTENTION_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_smoke
 from repro.models import layers as L
 from repro.models.transformer import forward_train, init

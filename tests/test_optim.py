@@ -164,7 +164,7 @@ def test_shampoo_packed_state_specs_shard_blocks_over_data():
     leading block-ownership dim over 'data' exactly like dense stacks."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs.base import SHAPES, OptimizerConfig, RunConfig
     from repro.configs.registry import get_smoke
     from repro.models.transformer import init

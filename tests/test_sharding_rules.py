@@ -10,15 +10,7 @@ the spec functions with a mocked mesh shape via AbstractMesh.
 import jax
 import jax.numpy as jnp
 import pytest
-
-try:
-    from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
-except ImportError:  # AxisType landed in jax 0.5.x; skip cleanly on older jax
-    pytest.skip(
-        "jax.sharding.AxisType not available on this JAX version "
-        f"({jax.__version__}) — sharding-rule specs need explicit axis types",
-        allow_module_level=True,
-    )
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.configs.base import SHAPES
 from repro.configs.registry import ARCHS

@@ -572,3 +572,51 @@ else:  # pragma: no cover
     )
     def test_property_packed_cholesky_round_trip():
         pass
+
+
+# ---------------------------------------------------------------------------
+# matmul precision: float32 means float32 on the chip too
+# ---------------------------------------------------------------------------
+
+
+def _kernel_solve_plan():
+    return dataclasses.replace(
+        tune.plan(op="solve", m=256, n=128, k=2, out="packed", backend="tpu"),
+        algorithm="winograd", n_base=32, packed_block=64, use_kernels=True,
+        method="factor")
+
+
+def _traced(case):
+    from repro.serve.bucketing import BucketSpec
+    from repro.serve.engine import _build_bucket_fn, serve_abstract_args
+
+    plan = _kernel_solve_plan()
+    a = jax.ShapeDtypeStruct((256, 128), jnp.float32)
+    b = jax.ShapeDtypeStruct((256, 2), jnp.float32)
+    if case == "ata_packed":
+        ata_plan = dataclasses.replace(plan, op="ata", k=128, method=None)
+        return jax.make_jaxpr(lambda a: ata(a, plan=ata_plan, out="packed"))(a)
+    if case == "lstsq":
+        return jax.make_jaxpr(lambda a, b: solve.lstsq(a, b, plan=plan))(a, b)
+    spec = BucketSpec(op="lstsq", m=256, n=128, r=2, batch=2, dtype="float32",
+                      exact_m=True)
+    return jax.make_jaxpr(_build_bucket_fn(spec, plan))(*serve_abstract_args(spec))
+
+
+@pytest.mark.parametrize("case", ["ata_packed", "lstsq", "serve_bucket"])
+def test_f32_matmuls_ask_for_highest_precision(case):
+    """At default precision a TPU multiplies float32 operands in one bf16
+    pass (a float32 Gram then carries ~2e-3 relative error). Every dot of
+    the float32 Gram → solve → serve path, Pallas kernel bodies included,
+    must state HIGHEST."""
+    from repro.check.artifacts import walk_eqns
+
+    jaxpr = _traced(case)
+    dots = [s for s in walk_eqns(jaxpr.jaxpr, into_pallas=True)
+            if s.eqn.primitive.name == "dot_general"]
+    assert any("pallas_call" in s.path for s in dots), "no kernel dot traced"
+    for s in dots:
+        assert all(v.aval.dtype == jnp.float32 for v in s.eqn.invars)
+        prec = s.eqn.params["precision"]
+        assert prec is not None and all(
+            p == jax.lax.Precision.HIGHEST for p in prec), (s.path, prec)

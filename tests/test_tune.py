@@ -342,6 +342,28 @@ def test_comm_model_prices_bfs_under_psum_at_bench_meshes():
         assert b < d, (devices, row_devices, b, d)
 
 
+
+def test_machine_for_refuses_an_unknown_backend():
+    with pytest.raises(ValueError, match="nonsense"):
+        cost.machine_for("nonsense")
+
+
+def test_tpu_machine_refuses_an_attached_chip_of_another_kind(monkeypatch):
+    """The tpu model states the chip it was set for: with no TPU attached it
+    prices plans as stated; an attached TPU of another kind is refused."""
+    mach = cost.machine_for("tpu")
+    assert "TPU v5 lite" in mach.device_kinds
+
+    class Chip:
+        device_kind = "TPU v4"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+    with pytest.raises(ValueError, match="TPU v4"):
+        cost.machine_for("tpu")
+    Chip.device_kind = "TPU v5 lite"
+    assert cost.machine_for("tpu") == mach
+
 # --- autotune ---------------------------------------------------------------
 
 
