@@ -6,7 +6,7 @@ the rectangular FastStrassen ``AᵀB``, flop accounting (the paper's
 2/3-of-Strassen claim), packed-native least squares (plan → ata →
 ``solve.lstsq`` — the gram is factored and solved without ever being
 densified), the Pallas kernel base case, and the ``repro.obs``
-observability switch (spans + metrics snapshot + calibration drift).
+observability switch (spans, metrics snapshot, the program's compile steps).
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -94,23 +94,25 @@ def main():
     print(f"ata with Pallas base (interpret on CPU): max err = "
           f"{float(jnp.abs(c_k - a_small.T @ a_small).max()):.2e}")
 
-    # --- 7. observability: obs.enable() → ata → metrics snapshot ------------
-    # Counters (dispatch/leaf/cache accounting) are always on; enable() adds
-    # spans (named_scope regions per recursion level, zero jaxpr ops) and
-    # per-dispatch calibration of the cost model's predicted_s against wall
-    # clock. Disabled, every instrumented path is bitwise-identical.
+    # --- 7. observability: obs.enable() → jitted ata → what it recorded -----
+    # Counters (dispatch/leaf/cache accounting) and JAX's compile steps are
+    # always on, and every span's named scope is always compiled in (op
+    # names carry it; zero ops, bitwise-identical values). enable() adds the
+    # recording: span events and counts, profiler annotations, and the root
+    # spans' host-clock times that tie a jitted program to its compile steps.
     obs.enable()
     # a recursing batched plan so the per-level spans have levels to name
     pr = dataclasses.replace(p, n_base=128, leaf_dispatch="batched",
                              source="analytic")
-    _ = ata(a, plan=pr, out="packed")  # eager: times itself vs predicted_s
+    jax.jit(lambda a: ata(a, plan=pr, out="packed"))(a)
     snap = obs.metrics.snapshot()  # JSON-ready, schema "repro.obs/v1"
     obs.metrics.validate_snapshot(snap)
+    (prog,) = obs.compiles.programs()
     print(f"obs: dispatch.ata.* counters = "
           f"{ {k: v for k, v in snap['counters'].items() if k.startswith('dispatch.ata')} }, "
           f"spans = {sorted(snap['spans'])}, "
-          f"calibration rows = {len(snap['calibration'])}")
-    print(obs.report())  # predicted-vs-measured drift table (DESIGN.md §8)
+          f"trace/lower/compile = {prog.trace_s:.2f}/{prog.lower_s:.2f}/"
+          f"{prog.compile_s:.2f} s")
     obs.disable()
 
     # --- 8. static contract checks: check.trace_plan → check.run ------------

@@ -137,7 +137,9 @@ def _rec_ata(slabs, n_base, base_syrk, strassen_rec, base_dot, acc_dtype):
         with obs.span("ata.rec.base", n=n, slabs=len(slabs)):
             out = base_syrk(slabs[0])
             for s in slabs[1:]:
-                out = out + base_syrk(s)
+                p = base_syrk(s)
+                with obs.span("ata.slab_sum"):
+                    out = out + p
             return out
 
     halves = []
@@ -162,12 +164,14 @@ def _rec_ata(slabs, n_base, base_syrk, strassen_rec, base_dot, acc_dtype):
         strassen_rec, n_base=n_base, base_dot=base_dot, acc_dtype=acc_dtype
     )
 
-    with obs.span(f"ata.rec.n{n}", slabs=len(slabs)):
+    with obs.span("ata.rec", n=n, slabs=len(slabs)):
         c11 = rec(left)
         c22 = rec(right)
         c21 = st(right[0], left[0])
         for r, l in zip(right[1:], left[1:]):
-            c21 = c21 + st(r, l)
+            p = st(r, l)
+            with obs.span("ata.slab_sum"):
+                c21 = c21 + p
         return _TriNode(c11, c21, c22)
 
 
@@ -255,7 +259,7 @@ def _ata_level_sync(a, L, *, variant, base_syrk, base_dot,
     parts_a, parts_b, sizes = [], [], []
     P_levels = [] if fused else None
     for lev in range(1, L + 1):
-      with obs.span(f"ata.encode.L{lev}", fused=fused):
+      with obs.span("ata.encode", level=lev, fused=fused):
         Rl, H = 1 << lev, 1 << (lev - 1)
         q = R // Rl
         if fused and fused_dot is None:
@@ -278,7 +282,8 @@ def _ata_level_sync(a, L, *, variant, base_syrk, base_dot,
         if fused:
             # one fused Pallas launch per level: the ±1 combinations run in
             # the kernel prologue against these block grids
-            with obs.span(f"ata.fused_dot.L{lev}", leaves=A.shape[0] * 7 ** (L - lev)):
+            with obs.span("ata.fused_dot", level=lev,
+                          leaves=A.shape[0] * 7 ** (L - lev)):
                 P_levels.append(fused_dot(A, B, _slot_tables(L - lev)))
             sizes.append(A.shape[0] * 7 ** (L - lev))
             continue
@@ -320,7 +325,7 @@ def _ata_level_sync(a, L, *, variant, base_syrk, base_dot,
     # levels back up, fold the slab sum in block form, then unblock
     c21 = {}
     for lev, p in zip(range(1, L + 1), P_levels):
-      with obs.span(f"ata.decode.L{lev}"):
+      with obs.span("ata.decode", level=lev):
         p = p[:, None, None]
         for _ in range(L - lev):
             p = dec(p)
@@ -468,11 +473,11 @@ def _ata_impl(
         "ata.leaves.strassen",
         sum(2 ** (2 * lev - 1) * 7 ** (L - lev) for lev in range(1, L + 1)),
     )
-    t0 = obs.dispatch_start(plan, a)
     with obs.span(
         "ata", m=a.shape[-2], n=n, levels=L, leaf_dispatch=leaf_dispatch
     ):
-        ap = _pad_root(a, L) if L else a
+        with obs.span("ata.pad"):
+            ap = _pad_root(a, L) if L else a
         if leaf_dispatch in ("batched", "fused"):
             node = _ata_level_sync(
                 ap, L, variant=variant, base_syrk=base_syrk, base_dot=base_dot,
@@ -491,7 +496,8 @@ def _ata_impl(
             )
 
         if out == "packed":
-            result = _finalize_packed(node, n, packed_block)
+            with obs.span("ata.pack"):
+                result = _finalize_packed(node, n, packed_block)
             if alpha != 1.0:
                 result = result.scale(alpha)
             if c is not None:
@@ -501,7 +507,7 @@ def _ata_impl(
                         f"SymmetricMatrix c, got {type(c).__name__}"
                     )
                 result = result.add(c.scale(beta) if beta != 1.0 else c)
-            return obs.dispatch_finish(plan, t0, result)
+            return result
 
         result = _finalize_dense(node, n)
         if alpha != 1.0:
@@ -510,7 +516,7 @@ def _ata_impl(
             if isinstance(c, SymmetricMatrix):
                 c = c.to_dense()
             result = result + (beta * c if beta != 1.0 else c)
-        return obs.dispatch_finish(plan, t0, result)
+        return result
 
 
 def ata(

@@ -228,31 +228,34 @@ def _rec_strassen(a, b, n_base, base_dot, acc_dtype):
     if min(m, n, k) <= n_base:
         return base_dot(a, b)
 
-    a11, a12, a21, a22 = _quadrants(a)
-    b11, b12, b21, b22 = _quadrants(b)
-
     rec = functools.partial(
         _rec_strassen, n_base=n_base, base_dot=base_dot, acc_dtype=acc_dtype
     )
     # With X = Aᵀ: X11=A11ᵀ X12=A21ᵀ X21=A12ᵀ X22=A22ᵀ. Classical formulas:
-    m1 = rec(a11 + a22, b11 + b22)  # (X11+X22)(Y11+Y22)
-    m2 = rec(a12 + a22, b11)        # (X21+X22)Y11
-    m3 = rec(a11, b12 - b22)        # X11(Y12-Y22)
-    m4 = rec(a22, b21 - b11)        # X22(Y21-Y11)
-    m5 = rec(a11 + a21, b22)        # (X11+X12)Y22
-    m6 = rec(a12 - a11, b11 + b12)  # (X21-X11)(Y11+Y12)
-    m7 = rec(a21 - a22, b21 + b22)  # (X12-X22)(Y21+Y22)
+    with obs.span("strassen.encode", n=n):
+        a11, a12, a21, a22 = _quadrants(a)
+        b11, b12, b21, b22 = _quadrants(b)
+        operands = [
+            (a11 + a22, b11 + b22),  # (X11+X22)(Y11+Y22)
+            (a12 + a22, b11),        # (X21+X22)Y11
+            (a11, b12 - b22),        # X11(Y12-Y22)
+            (a22, b21 - b11),        # X22(Y21-Y11)
+            (a11 + a21, b22),        # (X11+X12)Y22
+            (a12 - a11, b11 + b12),  # (X21-X11)(Y11+Y12)
+            (a21 - a22, b21 + b22),  # (X12-X22)(Y21+Y22)
+        ]
+    m1, m2, m3, m4, m5, m6, m7 = (rec(x, y) for x, y in operands)
 
     # Balanced association (not the textbook left-to-right chain): the fused
     # leaf dispatch evaluates its per-leaf slot tables as perfect binary add
     # trees, and keeping every dispatch on the same association keeps the
     # three of them bitwise-equal.
-    c11 = (m1 + m4) + (m7 - m5)
-    c12 = m3 + m5
-    c21 = m2 + m4
-    c22 = (m1 - m2) + (m3 + m6)
-
-    return jnp.block([[c11, c12], [c21, c22]])
+    with obs.span("strassen.decode", n=n):
+        c11 = (m1 + m4) + (m7 - m5)
+        c12 = m3 + m5
+        c21 = m2 + m4
+        c22 = (m1 - m2) + (m3 + m6)
+        return jnp.block([[c11, c12], [c21, c22]])
 
 
 def _rec_winograd(a, b, n_base, base_dot, acc_dtype):
@@ -262,22 +265,22 @@ def _rec_winograd(a, b, n_base, base_dot, acc_dtype):
     if min(m, n, k) <= n_base:
         return base_dot(a, b)
 
-    a11, a12, a21, a22 = _quadrants(a)
-    b11, b12, b21, b22 = _quadrants(b)
-
     rec = functools.partial(
         _rec_winograd, n_base=n_base, base_dot=base_dot, acc_dtype=acc_dtype
     )
     # X blocks in A-space: X11=A11 X12=A21 X21=A12 X22=A22 (all transposed
     # implicitly by the TN product). Winograd schedule:
-    s1 = a12 + a22          # X21 + X22
-    s2 = s1 - a11           # S1 - X11
-    s3 = a11 - a12          # X11 - X21
-    s4 = a21 - s2           # X12 - S2
-    t1 = b12 - b11          # Y12 - Y11
-    t2 = b22 - t1           # Y22 - T1
-    t3 = b22 - b12          # Y22 - Y12
-    t4 = t2 - b21           # T2 - Y21
+    with obs.span("strassen.encode", n=n):
+        a11, a12, a21, a22 = _quadrants(a)
+        b11, b12, b21, b22 = _quadrants(b)
+        s1 = a12 + a22          # X21 + X22
+        s2 = s1 - a11           # S1 - X11
+        s3 = a11 - a12          # X11 - X21
+        s4 = a21 - s2           # X12 - S2
+        t1 = b12 - b11          # Y12 - Y11
+        t2 = b22 - t1           # Y22 - T1
+        t3 = b22 - b12          # Y22 - Y12
+        t4 = t2 - b21           # T2 - Y21
 
     p1 = rec(a11, b11)      # X11 Y11
     p2 = rec(a21, b21)      # X12 Y21
@@ -287,16 +290,17 @@ def _rec_winograd(a, b, n_base, base_dot, acc_dtype):
     p6 = rec(s2, t2)        # S2 T2
     p7 = rec(s3, t3)        # S3 T3
 
-    u2 = p1 + p6
-    u3 = u2 + p7
-    u4 = u2 + p5
+    with obs.span("strassen.decode", n=n):
+        u2 = p1 + p6
+        u3 = u2 + p7
+        u4 = u2 + p5
 
-    c11 = p1 + p2
-    c12 = u4 + p3
-    c21 = u3 - p4
-    c22 = u3 + p5
+        c11 = p1 + p2
+        c12 = u4 + p3
+        c21 = u3 - p4
+        c22 = u3 + p5
 
-    return jnp.block([[c11, c12], [c21, c22]])
+        return jnp.block([[c11, c12], [c21, c22]])
 
 
 # ---------------------------------------------------------------------------
@@ -447,7 +451,7 @@ def _strassen_batched(a, b, L, base_dot, variant):
     enc, dec = _encode_fns(variant)
     A, B = _to_blocks(a, L)[None], _to_blocks(b, L)[None]
     for lev in range(1, L + 1):
-        with obs.span(f"strassen.encode.L{lev}"):
+        with obs.span("strassen.encode", level=lev):
             A, B = enc(A, B)
     # stacks are now (7^L, 1, 1, *batch, mb, nb): the block grid collapsed
     # into the leaf batch — squeeze it into the base dot's layout for free.
@@ -455,7 +459,7 @@ def _strassen_batched(a, b, L, base_dot, variant):
         P = _leaf_dot(base_dot, A[:, 0, 0], B[:, 0, 0])
     P = P[:, None, None]
     for lev in range(L, 0, -1):
-        with obs.span(f"strassen.decode.L{lev}"):
+        with obs.span("strassen.decode", level=lev):
             P = dec(P)
     return _unblock(P)[0]
 
@@ -607,7 +611,7 @@ def _strassen_fused(a, b, L, base_dot, fused_dot=None):
             ])
     P = P[:, None, None]
     for lev in range(L, 0, -1):
-        with obs.span(f"strassen.decode.L{lev}"):
+        with obs.span("strassen.decode", level=lev):
             P = _decode_strassen(P)
     return _unblock(P)[0]
 
@@ -692,7 +696,6 @@ def strassen_tn(
     L = tree_depth((m, n, k), n_base)
     obs.metrics.inc(f"dispatch.gemm_tn.{leaf_dispatch}")
     obs.metrics.inc("gemm_tn.leaves", 7 ** L)
-    t0 = obs.dispatch_start(plan, a)
     with obs.span(
         "strassen_tn", m=m, n=n, k=k, levels=L, leaf_dispatch=leaf_dispatch
     ):
@@ -713,4 +716,4 @@ def strassen_tn(
             out = alpha * out
         if c is not None:
             out = out + (beta * c if beta != 1.0 else c)
-        return obs.dispatch_finish(plan, t0, out)
+        return out

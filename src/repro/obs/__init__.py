@@ -1,46 +1,53 @@
-"""``repro.obs`` — zero-dependency observability for the ATA stack.
+"""``repro.obs`` — observability for the ATA stack.
 
-Three small modules, one switch:
+Four small modules, one switch:
 
-* :mod:`repro.obs.trace` — nestable **spans** naming recursion levels,
-  batched/fused leaf launches, kernel wrappers, the solve front door and
-  the SPMD schedule bodies. Disabled (the default) they are strict no-ops
-  — instrumented paths stay bitwise- and jaxpr-identical (tested); enabled
-  they record events and wrap regions in ``jax.named_scope`` +
-  ``jax.profiler.TraceAnnotation`` so profiler timelines carry the same
-  names.
+* :mod:`repro.obs.trace` — nestable **spans** with stable dotted names
+  (recursion steps, the recursion's operand sums and output combinations,
+  the packing, kernel wrappers, the solve stages, the SPMD schedule
+  bodies). Each span's ``jax.named_scope`` is always compiled in: it is
+  metadata only, so op names in every compiled program carry the span path
+  and the jaxpr, the values and the stripped StableHLO stay identical
+  (tested). :func:`enable` adds recording: event buffer, span counts,
+  ``jax.profiler.TraceAnnotation``, the root spans' host-clock times, and
+  the garbage collector's pauses as ``host.gc`` annotations.
+* :mod:`repro.obs.compiles` — always-on listener for JAX's own compile
+  steps (trace, lower, backend compile or cache load) and persistent-cache
+  hits and misses; ``compiles.programs()`` attributes them to the program
+  that holds a root span.
 * :mod:`repro.obs.metrics` — always-on process-local counters / gauges /
   histograms (plan-cache hits/misses/migrations, autotune trials and win
   margins, leaf counts per dispatch, kernel launches, collective bytes,
-  solve iterations) with a validated JSON snapshot
+  solve iterations, JAX cache hits/misses) with a validated JSON snapshot
   (``metrics.export_json`` → ``BENCH_obs.json``).
-* :mod:`repro.obs.calibrate` — every planned *eager* dispatch records
-  ``(plan, predicted_seconds, measured_seconds)``; ``calibrate.report()``
-  renders the predicted-vs-measured drift table per Machine profile,
-  closing the loop on ``tune.cost.predict_seconds``.
+* :mod:`repro.obs.calibrate` — the autotuner records every timed
+  candidate ``(plan, predicted_seconds, measured_seconds)``;
+  ``calibrate.report()`` renders the predicted-vs-measured drift table per
+  Machine profile.
 
 Quickstart (DESIGN.md §8):
 
     from repro import obs
     obs.enable()
-    c = ata(a, out="packed")            # spans + dispatch counters
-    x = solve.lstsq(a, b)               # + one calibration row
-    snap = obs.metrics.snapshot()       # JSON-ready; obs.report() for text
+    f = jax.jit(lambda a: ata(a, out="packed"))
+    c = f(a)                             # spans + dispatch counters
+    obs.compiles.programs()              # f's trace / lower / compile seconds
+    snap = obs.metrics.snapshot()        # JSON-ready
 
 Smoke entry point: ``python -m repro.obs`` runs one planned
-``plan → ata → solve.lstsq`` with tracing on, validates the snapshot, and
-writes ``BENCH_obs.json`` — the CI obs-smoke step.
+``plan → ata → solve.lstsq`` with tracing on, validates the snapshot, the
+scopes and the compile events, and writes ``BENCH_obs.json`` — the CI
+obs-smoke step.
 """
 
 from __future__ import annotations
 
-import time
-
-from repro.obs import calibrate, metrics, trace
+from repro.obs import calibrate, compiles, metrics, trace
 from repro.obs.trace import disable, enable, enabled, span
 
 __all__ = [
     "trace",
+    "compiles",
     "metrics",
     "calibrate",
     "enable",
@@ -48,49 +55,9 @@ __all__ = [
     "enabled",
     "span",
     "report",
-    "dispatch_start",
-    "dispatch_finish",
 ]
 
 
 def report() -> str:
     """The calibration drift table (text) — see ``calibrate.report``."""
     return calibrate.report()
-
-
-# ---------------------------------------------------------------------------
-# dispatch-site calibration helpers (used by core.ata / core.strassen /
-# solve.lstsq — the three planned front doors)
-# ---------------------------------------------------------------------------
-
-
-def dispatch_start(plan, operand):
-    """Start a calibration measurement for one planned dispatch, or return
-    ``None`` when there is nothing meaningful to measure:
-
-    * obs disabled (the common case — this is the one-branch fast path);
-    * no plan / no ``predicted_s`` on it (hand-pinned dispatches);
-    * ``operand`` is a tracer — inside ``jit``/``shard_map`` the wrapped
-      region runs at *trace* time, where wall clock means compile time.
-    """
-    if not trace.enabled():
-        return None
-    if plan is None or getattr(plan, "predicted_s", None) is None:
-        return None
-    import jax
-
-    if isinstance(operand, jax.core.Tracer):
-        return None
-    return time.perf_counter()
-
-
-def dispatch_finish(plan, t0, result):
-    """Close a measurement opened by :func:`dispatch_start`: block on the
-    result (pytree-aware), record the pair, hand the result back."""
-    if t0 is None:
-        return result
-    import jax
-
-    result = jax.block_until_ready(result)
-    calibrate.record(plan, time.perf_counter() - t0)
-    return result

@@ -4,12 +4,11 @@ One planned ``plan → ata → solve.lstsq`` pipeline with tracing on, then:
 
 * assert the metrics snapshot is non-empty and schema-valid
   (``metrics.validate_snapshot``);
-* assert spans exist for every recursion level of a forced-recursing
-  dispatch and for the kernel wrappers it launched;
-* assert the calibration table holds ≥ 1 predicted-vs-measured row per
-  dispatched op;
-* write the snapshot to ``BENCH_obs.json`` (``--out PATH`` overrides) and
-  print the calibration drift report.
+* assert spans exist for the steps of a forced-recursing dispatch and
+  that its compiled program names its ops with the recursion's scopes;
+* assert JAX's compile steps (trace, lower, compile) were recorded for
+  that jitted program (``obs.compiles.programs``);
+* write the snapshot to ``BENCH_obs.json`` (``--out PATH`` overrides).
 
 Exit code 0 only if every assertion holds — CI uploads the JSON artifact.
 """
@@ -58,7 +57,9 @@ def main(argv=None) -> int:
         plan, algorithm="strassen", n_base=32, leaf_dispatch="batched",
         source="analytic",
     )
-    gram_rec = ata(a, plan=rec_plan, out="packed")
+    compiled = jax.jit(lambda a: ata(a, plan=rec_plan, out="packed")) \
+        .lower(a).compile()
+    gram_rec = compiled(a)
     np.testing.assert_allclose(
         np.asarray(gram.to_dense()), np.asarray(gram_rec.to_dense()),
         rtol=2e-4, atol=2e-4,
@@ -80,20 +81,25 @@ def main(argv=None) -> int:
     )
 
     spans = snap["spans"]
-    levels = {k for k in spans if ".encode.L" in k or ".rec." in k}
-    assert levels, "no recursion-level spans recorded: " + ", ".join(sorted(spans))
+    steps = {"ata.encode", "ata.decode", "ata.pack"}
+    assert steps <= set(spans), "missing step spans: " + ", ".join(sorted(spans))
     assert any(k.startswith("solve.") for k in spans), sorted(spans)
+    text = compiled.as_text()
+    missing = sorted(s for s in steps if f"{s}/" not in text)
+    assert not missing, f"compiled program lacks the scopes {missing}"
 
-    cal_ops = {row["op"] for row in snap["calibration"]}
-    assert {"ata", "solve"} <= cal_ops, (
-        f"calibration rows cover {sorted(cal_ops)}, want ata + solve"
+    progs = [p for p in obs.compiles.programs() if "ata" in p.roots]
+    assert progs and progs[0].lower_s is not None \
+        and progs[0].compile_s is not None, (
+        f"no trace/lower/compile recorded for the jitted ata: {progs}"
     )
 
     obs.metrics.export_json(out_path)
-    print(obs.report())
+    p = progs[0]
     print(
         f"obs smoke OK: {len(counters)} counters, {len(spans)} span names, "
-        f"{len(snap['calibration'])} calibration rows -> {out_path}"
+        f"program {p.fun_name}: trace {p.trace_s:.3f} s, lower "
+        f"{p.lower_s:.3f} s, compile {p.compile_s:.3f} s -> {out_path}"
     )
     return 0
 

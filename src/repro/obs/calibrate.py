@@ -1,23 +1,17 @@
 """Cost-model calibration: predicted-vs-measured seconds per plan.
 
 ``tune.cost.predict_seconds`` is the planner's whole claim to authority —
-every analytic dispatch is an argmin over its predictions — yet until this
-module nothing ever held those predictions against a wall clock outside
-the autotuner's private comparisons. Two producers feed the table:
-
-* **eager dispatch sites** (``core.ata``, ``core.strassen``,
-  ``solve.lstsq``): with obs enabled and concrete (non-traced) operands,
-  each planned front-door call times itself end-to-end
-  (``block_until_ready``) and records ``(plan, measured)`` against the
-  plan's own ``predicted_s``;
-* **the autotuner** (``tune.search.autotune``): every timed candidate
-  already carries an analytic prediction — each trial's
-  min-of-interleaved floor is recorded against it.
+every analytic dispatch is an argmin over its predictions. The autotuner
+(``tune.search.autotune``) holds those predictions against a clock: every
+timed candidate already carries an analytic prediction, and each trial's
+min-of-interleaved floor is recorded against it.
 
 ``report()`` renders the drift table per Machine profile (backend):
 ``ratio = measured / predicted`` per plan key, plus the per-profile
 geometric-mean drift — the number to re-fit ``tune.cost.MACHINES``
-against (the PR-4/PR-6 recalibrations did exactly this by hand).
+against (the PR-4/PR-6 recalibrations did exactly this by hand). On the
+chip the benchmark prints each run's plan ``predicted_s`` beside its
+measured ``call_s`` instead.
 """
 
 from __future__ import annotations

@@ -3,8 +3,8 @@
 The metrics registry is **always on** — counters are plain integers behind
 one lock, incremented at Python dispatch/trace time (never inside the
 compiled program), so they cost nanoseconds and can't perturb a jaxpr.
-What ``obs.enable()`` gates is the *tracing* half (spans) and the
-*calibration* timing, both of which do real work.
+What ``obs.enable()`` gates is the recording half of the spans, which
+does real work.
 
 Semantics on traced code paths: a counter incremented inside a function
 under ``jax.jit`` counts **traces**, not executions — e.g.
@@ -23,6 +23,9 @@ Naming convention (dotted, lowercase):
     collective_bytes.* per-kind HLO collective payload (via record_collective_bytes)
     check.*            repro.check analyzer accounting: rules_run /
                        artifacts / findings.<rule-id> / violations
+    jax.cache_{hits,misses}  JAX persistent-cache answers (repro.obs.compiles)
+    host.gc_s          garbage-collector pauses while obs is enabled
+                       (histogram, repro.obs.trace)
 
 Snapshot schema (``SNAPSHOT_SCHEMA``): see :func:`snapshot` /
 :func:`validate_snapshot` — the contract the CI obs-smoke step asserts.

@@ -101,7 +101,6 @@ def lstsq(
         )
 
     obs.metrics.inc(f"dispatch.solve.{method}")
-    t0 = obs.dispatch_start(plan, a)
     if method == "cg":
         with obs.span("solve.lstsq", method="cg", m=m, n=n, r=r):
             if pinned:
@@ -109,7 +108,7 @@ def lstsq(
                              **static_kw)
             else:
                 x = cg_lstsq(a, b, ridge=ridge, iters=iters, tol=tol, plan=plan)
-            return obs.dispatch_finish(plan, t0, x)
+            return x
 
     # --- factor path: planned packed gram → packed Cholesky → substitutions
     from repro.core.ata import ata
@@ -121,8 +120,7 @@ def lstsq(
         if packed_block is None:
             packed_block = plan.packed_block
         # predicted_s=None: the solve-level prediction prices the whole
-        # pipeline, not the inner gram — carrying it over would record a
-        # mislabeled op='ata' calibration row at the inner dispatch.
+        # pipeline, not the inner gram.
         ata_plan = dataclasses.replace(
             plan, op="ata", k=n, out="packed", method=None, predicted_s=None
         )
@@ -143,4 +141,4 @@ def lstsq(
         with obs.span("solve.substitution"):
             x = solve_cholesky(factor, rhs, plan=plan)
         x = x[..., 0] if vector else x
-        return obs.dispatch_finish(plan, t0, x)
+        return x

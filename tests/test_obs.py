@@ -1,26 +1,33 @@
 """Tests for the ``repro.obs`` observability subsystem (DESIGN.md §8).
 
-The load-bearing guarantee: with obs **disabled** (the default) the
-instrumented dispatch paths are strict no-ops — same jaxpr, bitwise-same
-values — and even **enabled**, spans never add an op to the traced program
-(``jax.named_scope`` is metadata-only). Plus the registry/calibration
-contracts and the `analysis.hlo.collective_bytes` edge cases the metrics
-wiring depends on.
+The load-bearing guarantee: spans never change the program. Their named
+scopes are always compiled in and are metadata only — same jaxpr, same
+stripped StableHLO, bitwise-same values, obs on or off — and they reach
+``compiled.as_text()`` even through the persistent cache. Plus JAX's
+compile steps attributed to the program that holds a root span, the
+registry/calibration contracts, and the `analysis.hlo.collective_bytes`
+edge cases the metrics wiring depends on.
 """
 
+import contextlib
+import dataclasses
+import functools
+import gc
 import json
 import logging
+import re
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import obs
+from repro import obs, tune
 from repro.analysis.hlo import collective_bytes
 from repro.core.ata import ata
 from repro.core.strassen import strassen_tn
-from repro.obs import calibrate, metrics, trace
+from repro.obs import calibrate, compiles, metrics, trace
 from repro.tune import cache as tune_cache
 from repro.tune import cost
 
@@ -31,11 +38,13 @@ def _clean_obs():
     was_enabled = trace.enabled()
     trace.disable()
     trace.reset()
+    compiles.reset()
     metrics.reset()
     calibrate.reset()
     yield
     trace.enable() if was_enabled else trace.disable()
     trace.reset()
+    compiles.reset()
     metrics.reset()
     calibrate.reset()
 
@@ -47,16 +56,26 @@ def _rng(shape, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# spans: disabled = strict no-op; enabled = zero jaxpr ops
+# spans: disabled = the named scope alone; enabled = zero jaxpr ops
 # ---------------------------------------------------------------------------
 
 
 def test_span_disabled_is_shared_noop():
-    s1 = obs.span("anything", attr=1)
-    s2 = obs.span("else")
-    assert s1 is s2  # one shared null object — no per-call allocation
+    # disabled, a span is the named scope and nothing of the recording half:
+    # no _Span, no count, no event, no root time
+    s1 = obs.span("ata", attr=1)
+    assert not isinstance(s1, trace._Span)
     with s1:
         pass
+    assert trace.span_counts() == {} and trace.span_events() == []
+    assert trace.root_spans() == []
+
+    def f(x):
+        with obs.span("demo.scope", attr=2):
+            return x * 2
+
+    low = jax.jit(f).lower(jnp.ones(3)).as_text(debug_info=True)
+    assert "demo.scope/mul" in low
     assert trace.span_counts() == {}
 
 
@@ -122,10 +141,202 @@ def test_level_spans_cover_every_recursion_level():
         trace.disable()
     spans = trace.span_counts()
     L = 2  # 128 / 2^2 = 32 = n_base
+    # stable names, the level in the attrs
+    assert spans["ata.encode"] == L and spans["ata.decode"] == L
+    levels = {(name, attrs["level"]) for name, _, attrs in trace.span_events()
+              if name in ("ata.encode", "ata.decode")}
     for lev in range(1, L + 1):
-        assert f"ata.encode.L{lev}" in spans
-        assert f"ata.decode.L{lev}" in spans
+        assert ("ata.encode", lev) in levels
+        assert ("ata.decode", lev) in levels
     assert "ata.leaf_dot" in spans and "ata.syrk_batch" in spans
+
+
+# ---------------------------------------------------------------------------
+# scopes always compiled in: metadata only, through the persistent cache
+# ---------------------------------------------------------------------------
+
+_LOC = re.compile(r'loc\("([^"]*)"')
+_SHAPE = (300, 200)   # L = 3 at n_base 32; 300 rows pad to 304
+
+
+def _packed_program(leaf_dispatch):
+    """A new jitted planned packed ``ata`` (a new function object, so no
+    trace cache answers for it)."""
+    plan = dataclasses.replace(
+        tune.plan(op="ata", m=_SHAPE[0], n=_SHAPE[1], dtype="float32",
+                  out="packed"),
+        algorithm="winograd" if leaf_dispatch == "unrolled" else "strassen",
+        n_base=32, leaf_dispatch=leaf_dispatch)
+    return jax.jit(lambda a: ata(a, plan=plan, out="packed"))
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered_op_names(leaf_dispatch):
+    a = jax.ShapeDtypeStruct(_SHAPE, jnp.float32)
+    text = _packed_program(leaf_dispatch).lower(a).as_text(debug_info=True)
+    return frozenset(_LOC.findall(text))
+
+
+@pytest.mark.parametrize("leaf_dispatch,scope", [
+    ("unrolled", "strassen.encode"), ("unrolled", "strassen.decode"),
+    ("unrolled", "ata.slab_sum"), ("unrolled", "ata.pad"),
+    ("unrolled", "ata.pack"), ("unrolled", "ata.rec"),
+    ("batched", "ata.encode"), ("batched", "ata.decode"),
+    ("batched", "ata.pack"),
+])
+def test_scopes_compiled_in_with_obs_off(leaf_dispatch, scope):
+    assert not trace.enabled()
+    names = _lowered_op_names(leaf_dispatch)
+    assert any(f"/{scope}/" in n for n in names), sorted(names)[:20]
+
+
+def test_scopes_leave_the_stripped_stablehlo_unchanged(monkeypatch):
+    a = jax.ShapeDtypeStruct(_SHAPE, jnp.float32)
+    scoped = _packed_program("unrolled").lower(a)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _packed_program("unrolled").lower(a)
+    assert "/ata.pack/" in scoped.as_text(debug_info=True)
+    assert "/ata.pack/" not in bare.as_text(debug_info=True)
+    assert scoped.as_text() == bare.as_text()
+
+
+@pytest.fixture
+def fresh_compile_cache(tmp_path):
+    """JAX's persistent cache in an empty directory, every program kept."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    cc.reset_cache()
+    for k, v in zip(keys, (True, str(tmp_path / "cache"), 0.0, 0)):
+        jax.config.update(k, v)
+    yield
+    for k, v in was.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+def test_scopes_survive_the_persistent_cache(fresh_compile_cache):
+    a = jax.ShapeDtypeStruct(_SHAPE, jnp.float32)
+    _packed_program("unrolled").lower(a).compile()
+    assert metrics.get("jax.cache_hits") == 0
+    jax.clear_caches()
+    loaded = _packed_program("unrolled").lower(a).compile()
+    assert metrics.get("jax.cache_hits") >= 1
+    text = loaded.as_text()
+    for scope in ("strassen.encode", "strassen.decode", "ata.slab_sum",
+                  "ata.pad", "ata.pack"):
+        assert re.search(rf'op_name="[^"]*/{re.escape(scope)}/', text), scope
+
+
+# ---------------------------------------------------------------------------
+# JAX's compile steps, attributed to the program that holds a root span
+# ---------------------------------------------------------------------------
+
+
+def test_compile_steps_belong_to_the_program_that_calls_ata():
+    def make_operand(key):
+        return jax.random.normal(key, (96, 64))
+
+    def program(a):
+        return ata(a, n_base=16, variant="winograd", leaf_dispatch="unrolled",
+                   out="packed").blocks
+
+    def reference(a):
+        return a.T @ a
+
+    trace.enable()
+    try:
+        a = jax.jit(make_operand)(jax.random.key(0))
+        compiled = jax.jit(program).lower(a).compile()
+        compiled(a)
+        jax.jit(reference)(a)
+    finally:
+        trace.disable()
+    (prog,) = compiles.programs()
+    assert prog.fun_name == "program" and prog.roots == ("ata",)
+    evs = compiles.events()
+    own = {e.kind: e for e in evs if e.fun_name in ("program", "jit(program)")}
+    assert set(own) == {"trace", "lower", "compile"}
+    assert prog.trace_s == own["trace"].end - own["trace"].start
+    assert prog.lower_s == own["lower"].end - own["lower"].start
+    assert prog.compile_s == own["compile"].end - own["compile"].start
+    # the maker before and the reference after were compiled, and are not it
+    others = {e.fun_name for e in evs if e.kind == "compile"}
+    assert {"jit(make_operand)", "jit(reference)"} <= others
+
+
+def test_programs_rule_on_a_recorded_list():
+    E = compiles.Event
+    evs = [
+        E("trace", "make", 0.0, 1.0), E("lower", "jit(make)", 1.0, 2.0),
+        E("compile", "jit(make)", 2.0, 3.0),
+        E("trace", "step", 4.0, 6.0),                 # holds the root span
+        E("lower", "jit(step)", 6.5, 7.0),
+        E("cache_hit", "", 7.5, 7.5),
+        E("compile", "jit(step)", 7.25, 8.0),
+        E("trace", "ref", 9.0, 9.5), E("lower", "jit(ref)", 9.5, 9.8),
+        E("compile", "jit(ref)", 9.8, 11.0),
+    ]
+    roots = [("solve.lstsq", 4.1, 5.9), ("ata", 4.2, 5.0)]
+    (prog,) = compiles.programs(evs, roots)
+    assert prog == compiles.Program(
+        fun_name="step", roots=("solve.lstsq", "ata"), trace_s=2.0,
+        lower_s=0.5, compile_s=0.75, cache="hit")
+    assert compiles.programs(evs, []) == []
+
+
+def test_compile_listener_keeps_outermost_steps_and_their_cache_events():
+    on = compiles._on_span
+    t = time.time()
+    on("/jax/core/compile/jaxpr_trace_duration", t + 0.1, t + 0.2,
+       fun_name="add")                            # a jnp helper inside
+    on("/jax/core/compile/jaxpr_trace_duration", t, t + 1.0, fun_name="f")
+    on("/jax/core/compile/jaxpr_trace_duration", t + 1.2, t + 1.3,
+       fun_name="threefry")                       # traced while lowering
+    on("/jax/core/compile/jaxpr_to_mlir_module_duration", t + 1.1, t + 1.5,
+       fun_name="jit(f)")
+    c0 = time.time()
+    compiles._on_event("/jax/compilation_cache/cache_misses")
+    on("/jax/core/compile/backend_compile_duration", c0, time.time() + 1.0,
+       fun_name="jit(f)")
+    assert [(e.kind, e.fun_name) for e in compiles.events()] == [
+        ("trace", "f"), ("lower", "jit(f)"), ("cache_miss", ""),
+        ("compile", "jit(f)")]
+    assert metrics.get("jax.cache_misses") == 1
+
+
+def test_span_overflow_evicts_no_compile_step(monkeypatch):
+    monkeypatch.setattr(trace, "MAX_EVENTS", 3)
+    a = _rng((96, 64))
+    trace.enable()
+    try:
+        jax.jit(lambda x: ata(x, n_base=16, variant="winograd",
+                              leaf_dispatch="unrolled")).lower(a).compile()
+    finally:
+        trace.disable()
+    assert len(trace.span_events()) == 3 < sum(trace.span_counts().values())
+    (prog,) = compiles.programs()
+    assert prog.lower_s > 0 and prog.compile_s > 0
+
+
+def test_gc_pauses_recorded_only_while_enabled():
+    assert trace._on_gc not in gc.callbacks
+    gc.collect()
+    assert "host.gc_s" not in metrics.histograms()
+    trace.enable()
+    try:
+        assert trace._on_gc in gc.callbacks
+        gc.collect()
+        gc.collect()
+    finally:
+        trace.disable()
+    assert trace._on_gc not in gc.callbacks
+    h = metrics.histograms()["host.gc_s"]
+    assert h["count"] >= 2 and h["min"] >= 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -210,47 +421,6 @@ def test_calibrate_drift_aggregates_per_key():
     assert g["n"] == 2
     assert g["measured_s"] == pytest.approx(2e-3)   # min over rows
     assert g["ratio"] == pytest.approx(4.0)         # geomean of 2 and 8
-
-
-def test_eager_planned_dispatch_records_calibration_row():
-    import dataclasses
-
-    a = _rng((192, 96))
-    plan = dataclasses.replace(
-        cost.analytic_plan(
-            "ata", 192, 96, dtype="float32", backend=jax.default_backend()
-        ),
-        algorithm="strassen", n_base=32, leaf_dispatch="batched",
-    )
-    assert plan.predicted_s is not None
-    trace.enable()
-    try:
-        ata(a, plan=plan)
-    finally:
-        trace.disable()
-    rows = calibrate.rows()
-    assert len(rows) == 1 and rows[0]["op"] == "ata"
-    assert rows[0]["measured_s"] > 0
-
-
-def test_no_calibration_under_jit_tracing():
-    import dataclasses
-
-    a = _rng((96, 64))
-    plan = dataclasses.replace(
-        cost.analytic_plan(
-            "ata", 96, 64, dtype="float32", backend=jax.default_backend()
-        ),
-        algorithm="strassen", n_base=32,
-    )
-    trace.enable()
-    try:
-        jax.jit(lambda x: ata(x, plan=plan))(a)
-    finally:
-        trace.disable()
-    # inside jit the region runs at trace time — wall clock there would be
-    # compile time, so the dispatch site must not record
-    assert calibrate.rows() == []
 
 
 # ---------------------------------------------------------------------------
