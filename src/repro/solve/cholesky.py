@@ -10,19 +10,26 @@ materializes a dense ``(n, n)`` anywhere:
         S_jj   = A[j,j] − Σ_{k<j} L[j,k]·L[j,k]ᵀ   (one NT block einsum)
         L[j,j] = potrf(S_jj)                        (diagonal base kernel)
         S_ij   = A[i,j] − Σ_{k<j} L[i,k]·L[j,k]ᵀ   (one batched einsum)
-        L[i,j] = trsm(L[j,j], S_ij)  for all i > j  (ONE batched panel
-                                                     launch per column)
+        L[i,j] = S_ij · L[j,j]⁻ᵀ     for all i > j  (the panel step)
 
 Base engines follow the plan like every other consumer of the stack:
 ``plan.use_kernels`` → the Pallas ``potrf``/``trsm`` kernels
 (``repro.kernels``), whose leading batch grid dimension receives the whole
-flattened ``batch × panel-rows`` stack per the PR-4 batched-dispatch
-contract — a batched Shampoo stat stack factors as ONE launch per block
-column per op. Otherwise the jnp/LAPACK-lowered base
-(``jnp.linalg.cholesky`` / ``lax.linalg.triangular_solve``) serves every
-backend. Either way the *walk* — and therefore the block arithmetic and
-its float rounding — is identical, which is what makes packed and dense
-inputs factor bitwise-identically (tested).
+flattened batch stack per that package's batched-dispatch contract — a
+batched Shampoo stat stack factors as ONE launch per block column per op.
+There the panel step inverts the diagonal tile once per column,
+``W = L[j,j]⁻ᵀ`` (one Pallas ``trsm`` of a single ``bn``-row tile against
+the identity, per batch entry), and applies it to the whole sub-diagonal
+panel in one einsum at the operands' ``dot_precision`` (``HIGHEST`` for
+f32): the kernel's ``bn``-step recurrence runs once per column instead of
+once per panel tile, and the panel's work goes through the MXU. Otherwise
+the jnp/LAPACK-lowered base (``jnp.linalg.cholesky`` /
+``lax.linalg.triangular_solve``) serves every backend, and the panel step stays one batched ``trsm`` over the
+flattened ``batch × panel-rows`` stack (``L[j,j]`` broadcast to every panel
+tile); explicit ``base_potrf``/``base_trsm`` engines take that route too.
+On either engine the *walk* — and therefore the block arithmetic and its
+float rounding — is the same for packed and dense inputs, which is what
+makes them factor bitwise-identically (tested).
 
 Padding: the packed grid covers ``nb·bn ≥ n``; the pad rows/cols of a gram
 are zero, which would make the trailing diagonal block singular. The walk
@@ -40,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.precision import dot_precision
 from repro.core.symmetric import (
     SymmetricMatrix,
@@ -172,6 +180,10 @@ def _trsm_panel_jnp(l, p):
     )
 
 
+def _uses_kernels(plan) -> bool:
+    return plan is not None and getattr(plan, "use_kernels", False)
+
+
 def base_solver_fns(plan):
     """(base_potrf, base_trsm) for the factor walk under this plan.
 
@@ -179,7 +191,7 @@ def base_solver_fns(plan):
     elsewhere — ``kernels.ops`` decides); otherwise the jnp bases. Both
     accept one flattened leading batch dim (``_flat_call`` guarantees it).
     """
-    if plan is not None and getattr(plan, "use_kernels", False):
+    if _uses_kernels(plan):
         from repro.kernels import ops
 
         return ops.potrf, functools.partial(ops.trsm, transpose=True)
@@ -226,7 +238,8 @@ def cholesky(
         (dense inputs) and the base-engine choice (``use_kernels``).
       packed_block: block size override when packing a dense input.
       base_potrf / base_trsm: explicit base engines (must accept one
-        leading batch dim, per the ``repro.kernels`` contract).
+        leading batch dim, per the ``repro.kernels`` contract); the panel
+        step then solves every panel tile with ``base_trsm``.
 
     Returns:
       :class:`CholeskyFactor` with the same batch dims and block grid.
@@ -243,8 +256,11 @@ def cholesky(
         a = SymmetricMatrix.from_dense(a, packed_block)
     if ridge:
         a = a.add_scaled_identity(ridge)
+    # only the plan's kernel engine multiplies by inverted diagonal tiles
+    panel_inverse = False
     if base_potrf is None and base_trsm is None:
         base_potrf, base_trsm = base_solver_fns(plan)
+        panel_inverse = _uses_kernels(plan)
     elif base_potrf is None or base_trsm is None:
         raise ValueError("pass both base_potrf and base_trsm, or neither")
 
@@ -295,8 +311,19 @@ def cholesky(
                 precision=dot_precision(li, lrow),
                 preferred_element_type=jnp.float32,
             )
-        ljj = jnp.broadcast_to(out[(j, j)], p.shape)
-        panel = _flat_call(base_trsm, ljj, p)
+        ljj = out[(j, j)]
+        if panel_inverse:
+            obs.metrics.inc("solve.cholesky.panel_inverse")
+            # W·L[j,j]ᵀ = I  ⇒  W = L[j,j]⁻ᵀ, then L[i,j] = S_ij·W
+            eye = jnp.broadcast_to(jnp.eye(bn, dtype=p.dtype), ljj.shape)
+            w = _flat_call(base_trsm, ljj, eye)
+            panel = jnp.einsum(
+                "r...ab,...bc->r...ac", p, w,
+                precision=dot_precision(p, w),
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            panel = _flat_call(base_trsm, jnp.broadcast_to(ljj, p.shape), p)
         for r, i in enumerate(rows):
             out[(i, j)] = panel[r]
 

@@ -20,6 +20,7 @@ Coverage per the PR's acceptance criteria:
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -195,6 +196,143 @@ def test_packed_cholesky_kernel_base_matches_jnp_base():
     np.testing.assert_allclose(np.asarray(f_kern.to_dense()),
                                np.asarray(f_jnp.to_dense()),
                                rtol=1e-4, atol=1e-4)
+
+
+def _kernel_engine_plan():
+    """A plan whose solver bases are the Pallas kernels (interpret mode on
+    CPU): the engine that solves each panel by an inverted diagonal tile."""
+    return dataclasses.replace(
+        tune.plan(op="solve", m=256, n=128, k=2, out="packed", backend="cpu"),
+        use_kernels=True, method="factor")
+
+
+def _broadcast_panel_walk(a, potrf, trsm):
+    """The factor walk with the panel solved tile by tile: ``L[j,j]``
+    broadcast to every panel tile and ONE batched ``trsm`` per column,
+    op for op as the jnp and explicit-base engines run it."""
+    from repro.core.symmetric import sym_tile
+    from repro.solve.cholesky import _flat_call, _pad_identity_mask
+
+    nb, bn, n = a.nb, a.bn, a.n
+    out = {}
+    for j in range(nb):
+        s = a.block(j, j)
+        if j:
+            lrow = jnp.stack([out[(j, k)] for k in range(j)], axis=0)
+            s = s - jnp.einsum("k...ab,k...cb->...ac", lrow, lrow,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+        s = sym_tile(s)
+        if nb * bn > n and j == nb - 1:
+            valid, eye_pad = _pad_identity_mask(n, nb, bn)
+            s = s * valid + eye_pad
+        out[(j, j)] = _flat_call(potrf, s)
+        rows = range(j + 1, nb)
+        if not rows:
+            continue
+        p = jnp.moveaxis(a.col_panel(j), -3, 0)
+        if j:
+            li = jnp.stack(
+                [jnp.stack([out[(i, k)] for k in range(j)], 0) for i in rows], 0)
+            p = p - jnp.einsum("rk...ab,k...cb->r...ac", li, lrow,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+        panel = _flat_call(trsm, jnp.broadcast_to(out[(j, j)], p.shape), p)
+        for r, i in enumerate(rows):
+            out[(i, j)] = panel[r]
+    return jnp.stack([out[(i, j)] for i in range(nb) for j in range(i + 1)],
+                     axis=-3)
+
+
+@pytest.mark.parametrize("m,n,bn", CHOL_SHAPES)
+def test_kernel_engine_panel_inverse_matches_jnp_engine(m, n, bn):
+    """The kernel engine's panel step (inverted diagonal tile + one einsum)
+    factors and solves like the jnp engine's per-tile substitution, over
+    bn-misaligned tails and the identity pad."""
+    rng = np.random.default_rng(n * 11 + bn)
+    g = _packed_gram(rng, m, n, bn)
+    plan = _kernel_engine_plan()
+    f_kern = solve.cholesky(g, plan=plan)
+    f_jnp = solve.cholesky(g)
+    np.testing.assert_allclose(np.asarray(f_kern.to_dense()),
+                               np.asarray(f_jnp.to_dense()),
+                               rtol=2e-4, atol=2e-4)
+    b = jnp.asarray(rng.standard_normal((n, 2)), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(solve.solve_cholesky(f_kern, b, plan=plan)),
+        np.asarray(solve.solve_cholesky(f_jnp, b)), rtol=2e-3, atol=2e-3)
+
+
+def test_kernel_engine_panel_inverse_batched_stack():
+    """A stack of 3 (Shampoo-style stats) inverts one diagonal tile per
+    batch entry per column and matches the jnp engine."""
+    rng = np.random.default_rng(13)
+    a = jnp.asarray(rng.standard_normal((3, 80, 41)), jnp.float32)
+    g = ata_batched(a, n_base=16, out="packed", packed_block=16)
+    g = g.add_scaled_identity(41.0)
+    plan = _kernel_engine_plan()
+    f_kern = solve.cholesky(g, plan=plan)
+    f_jnp = solve.cholesky(g)
+    assert f_kern.blocks.shape == g.blocks.shape
+    np.testing.assert_allclose(np.asarray(f_kern.to_dense()),
+                               np.asarray(f_jnp.to_dense()),
+                               rtol=2e-4, atol=2e-4)
+    b = jnp.asarray(rng.standard_normal((3, 41, 2)), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(solve.solve_cholesky(f_kern, b, plan=plan)),
+        np.asarray(jnp.linalg.solve(g.to_dense(), b)), rtol=2e-3, atol=2e-3)
+
+
+def test_kernel_engine_panel_inverse_ill_conditioned():
+    """At cond(A) = 1e6 the inverted tiles cost at most 4x the backward
+    error of the jnp engine's substitution."""
+    rng = np.random.default_rng(14)
+    ad = _spd(rng, 100, cond=1e6)
+    a = SymmetricMatrix.from_dense(ad, 16)
+    ref = np.asarray(ad, np.float64)
+
+    def backward_err(f):
+        ld = np.asarray(f.to_dense(), np.float64)
+        return np.linalg.norm(ld @ ld.T - ref) / np.linalg.norm(ref)
+
+    err_kern = backward_err(solve.cholesky(a, plan=_kernel_engine_plan()))
+    err_jnp = backward_err(solve.cholesky(a))
+    assert np.isfinite(err_kern) and err_kern <= 4.0 * err_jnp, (err_kern, err_jnp)
+
+
+def test_kernel_engine_packed_and_dense_inputs_factor_bitwise_equal():
+    rng = np.random.default_rng(15)
+    g = _packed_gram(rng, 120, 72, 16)
+    plan = _kernel_engine_plan()
+    f_packed = solve.cholesky(g, plan=plan)
+    f_dense = solve.cholesky(g.to_dense(), packed_block=16, plan=plan)
+    np.testing.assert_array_equal(np.asarray(f_packed.blocks),
+                                  np.asarray(f_dense.blocks))
+
+
+@pytest.mark.parametrize("engine", ["kernel", "jnp", "explicit"])
+def test_panel_inverse_counter_and_per_tile_engines_bitwise(engine):
+    """``solve.cholesky.panel_inverse`` counts the nb−1 columns that take
+    the inverted-tile route on the kernel engine, and none elsewhere; the
+    jnp and explicit-base engines keep the per-tile panel solve, bitwise."""
+    from repro import obs
+    from repro.solve.cholesky import _potrf_jnp, _trsm_panel_jnp
+
+    rng = np.random.default_rng(16)
+    g = _packed_gram(rng, 100, 72, 16)   # nb = 5, a padded tail
+    explicit = dict(base_potrf=ops.potrf,
+                    base_trsm=functools.partial(ops.trsm, transpose=True))
+    kwargs = {"kernel": dict(plan=_kernel_engine_plan()), "jnp": {},
+              "explicit": explicit}[engine]
+    before = obs.metrics.get("solve.cholesky.panel_inverse")
+    f = solve.cholesky(g, **kwargs)
+    counted = obs.metrics.get("solve.cholesky.panel_inverse") - before
+    assert counted == (g.nb - 1 if engine == "kernel" else 0)
+    if engine != "kernel":
+        bases = {"jnp": (_potrf_jnp, _trsm_panel_jnp),
+                 "explicit": (explicit["base_potrf"], explicit["base_trsm"])}
+        ref = _broadcast_panel_walk(g, *bases[engine])
+        np.testing.assert_array_equal(np.asarray(f.blocks), np.asarray(ref))
 
 
 def test_cholesky_factor_identity_and_pytree():
