@@ -74,6 +74,18 @@ __all__ = [
 ]
 
 
+def _collective(name: str, fn, x, **attrs):
+    """``fn(x)``, one collective of a schedule, under its ``distributed.*``
+    scope (``distributed.psum`` or ``distributed.psum_scatter``), with the
+    payload each device hands it (every leaf of ``x``) added at trace time
+    to the ``distributed.collective_bytes`` counter."""
+    obs.metrics.inc("distributed.collective_bytes",
+                    sum(leaf.size * leaf.dtype.itemsize
+                        for leaf in jax.tree.leaves(x)))
+    with obs.span(name, **attrs):
+        return fn(x)
+
+
 # ---------------------------------------------------------------------------
 # rowshard: C = Σ_p A_pᵀ A_p
 # ---------------------------------------------------------------------------
@@ -139,8 +151,9 @@ def gram_rowshard(
                 local = SymmetricMatrix.from_dense(local, packed_block)
         # psum maps over the SymmetricMatrix pytree leaf — the packed stack
         # is the collective payload, never a mirrored square.
-        with obs.span("distributed.psum", axis=axis, out=out):
-            return jax.lax.psum(local, axis)
+        return _collective("distributed.psum",
+                           lambda x: jax.lax.psum(x, axis), local,
+                           axis=axis, out=out)
 
 
 # ---------------------------------------------------------------------------
@@ -245,6 +258,21 @@ def _slot_tile(compute_tile, a_local, g, valid, like):
         return jax.lax.pcast(z, vma, to="varying") if vma else z
 
     return jax.lax.cond(valid, lambda: compute_tile(a_local, g), dummy)
+
+
+def _assemble(tiles, n, nb, packed_block, alpha, out, *,
+              presymmetrized=False):
+    """The schedule's answer from its tri-order tile stack, under the
+    ``distributed.assemble`` scope: what the compiler moves between chips
+    to assemble it (the psum schedule's diagonal-tile gather) is then
+    named in the compiled program like the schedule's own collectives."""
+    with obs.span("distributed.assemble", out=out):
+        sym = SymmetricMatrix.from_tile_stack(
+            tiles, n, nb=nb, packed_block=packed_block,
+            presymmetrized=presymmetrized)
+        if alpha != 1.0:
+            sym = sym.scale(alpha)
+        return sym if out == "packed" else sym.to_dense()
 
 
 def ata_tile_parallel(
@@ -380,27 +408,22 @@ def ata_tile_parallel(
             tiles = jnp.stack([tile_slot(q) for q in range(t_per)])
         if row_axis is not None:
             # packed retrieval: reduce the tile stack, not a dense (n, n)
-            with obs.span("distributed.psum", axis=row_axis, out="packed"):
-                tiles = jax.lax.psum(tiles, row_axis)
+            tiles = _collective("distributed.psum",
+                                lambda x: jax.lax.psum(x, row_axis), tiles,
+                                axis=row_axis, out="packed")
         return tiles
 
     in_spec = P(row_axis, None) if row_axis else P(None, None)
-    tiles = jax.shard_map(
-        local_fn, mesh=_auto_axes(mesh), in_specs=(in_spec,),
-        out_specs=P(task_axis, None, None),
-    )(a)
-    # tiles: global (p_task * t_per, w, w), tri-enumerated — exactly the
-    # packed retrieval payload. Assemble the SymmetricMatrix straight from
-    # it (pure slice when w matches the packed grid; static re-tile
-    # otherwise); dense output is its one root-level mirror. The seed's
-    # per-tile dynamic_update_slice loop into a replicated (n_pad, n_pad)
-    # square is gone from both modes.
-    sym = SymmetricMatrix.from_tile_stack(tiles, n, nb=nb, packed_block=packed_block)
-    if alpha != 1.0:
-        sym = sym.scale(alpha)
-    if out == "packed":
-        return sym
-    return sym.to_dense()
+    with obs.span("distributed.ata", schedule="psum", tiles=t_total):
+        tiles = jax.shard_map(
+            local_fn, mesh=_auto_axes(mesh), in_specs=(in_spec,),
+            out_specs=P(task_axis, None, None),
+        )(a)
+        # tiles: global (p_task * t_per, w, w), tri-enumerated — exactly the
+        # packed retrieval payload. Assemble the SymmetricMatrix straight
+        # from it (pure slice when w matches the packed grid; static re-tile
+        # otherwise); dense output is its one root-level mirror.
+        return _assemble(tiles, n, nb, packed_block, alpha, out)
 
 
 # ---------------------------------------------------------------------------
@@ -723,10 +746,11 @@ def ata_bfs_dfs(
             ids = jnp.where(row >= 0, row, t_pad)
             buf = jnp.zeros((t_pad + 1, *tiles.shape[1:]), tiles.dtype)
             buf = buf.at[ids].set(tiles)[:t_pad]
-            with obs.span("distributed.psum_scatter", axis=str(merged),
-                          out="packed"):
-                tiles = jax.lax.psum_scatter(
-                    buf, merged, scatter_dimension=0, tiled=True)
+            tiles = _collective(
+                "distributed.psum_scatter",
+                lambda x: jax.lax.psum_scatter(x, merged, scatter_dimension=0,
+                                               tiled=True),
+                buf, axis=str(merged), out="packed")
             # local diagonal symmetrization: the chunk's global tile ids
             # are axis_index-affine, so diag membership is a tiny static
             # table lookup — from_tile_stack can then skip its cross-shard
@@ -737,29 +761,25 @@ def ata_bfs_dfs(
             dm = jnp.take(diag_tbl, k * chunk + jnp.arange(chunk))
             tiles = jnp.where(dm[:, None, None], sym_tile(tiles), tiles)
         elif row_axis is not None:
-            with obs.span("distributed.psum", axis=row_axis,
-                          out="packed"):
-                tiles = jax.lax.psum(tiles, row_axis)
+            tiles = _collective("distributed.psum",
+                                lambda x: jax.lax.psum(x, row_axis), tiles,
+                                axis=row_axis, out="packed")
         return tiles
 
     in_spec = P(row_axis, None) if row_axis else P(None, None)
     out_spec = (P(merged, None, None) if scatter
                 else P(task_axis, None, None))
-    tiles = jax.shard_map(
-        local_fn, mesh=_auto_axes(mesh), in_specs=(in_spec,), out_specs=out_spec
-    )(a)
-    # either way the global stack is the tri-order prefix: scatter path by
-    # construction (chunk k holds tiles [k·chunk, (k+1)·chunk)), psum path
-    # because contiguous per-task assignment puts task t's tiles at
-    # [t·s_eff, …) with dummies trailing — retrieval is a pure slice.
-    sym = SymmetricMatrix.from_tile_stack(tiles, n, nb=nb,
-                                          packed_block=packed_block,
-                                          presymmetrized=scatter)
-    if alpha != 1.0:
-        sym = sym.scale(alpha)
-    if out == "packed":
-        return sym
-    return sym.to_dense()
+    with obs.span("distributed.ata", schedule=interleaving, tiles=t_total):
+        tiles = jax.shard_map(
+            local_fn, mesh=_auto_axes(mesh), in_specs=(in_spec,),
+            out_specs=out_spec,
+        )(a)
+        # either way the global stack is the tri-order prefix: scatter path
+        # by construction (chunk k holds tiles [k·chunk, (k+1)·chunk)), psum
+        # path because contiguous per-task assignment puts task t's tiles at
+        # [t·s_eff, …) with dummies trailing — retrieval is a pure slice.
+        return _assemble(tiles, n, nb, packed_block, alpha, out,
+                         presymmetrized=scatter)
 
 
 def tile_parallel_device_flops(
@@ -888,8 +908,9 @@ def gemm_tn_colshard(
                     preferred_element_type=jnp.float32,
                 )
         if row_axis is not None:
-            with obs.span("distributed.psum", axis=row_axis, out="dense"):
-                c_local = jax.lax.psum(c_local, row_axis)
+            c_local = _collective("distributed.psum",
+                                  lambda x: jax.lax.psum(x, row_axis), c_local,
+                                  axis=row_axis, out="dense")
         return c_local
 
     row_spec = row_axis if row_axis else None

@@ -67,7 +67,7 @@ MAX_EVENTS = 10_000
 # the spans that open one planned dispatch; their (name, start, end) on the
 # host clock (``time.time``, JAX's clock for compile events) are kept apart
 # from the event buffer, newest last, so that no overflow can evict them
-ROOTS = frozenset({"ata", "strassen_tn", "solve.lstsq"})
+ROOTS = frozenset({"ata", "strassen_tn", "solve.lstsq", "distributed.ata"})
 _ROOT_SPANS: deque = deque(maxlen=1_000)
 
 

@@ -129,3 +129,34 @@ def test_sharded_kernel_schedule_compiles_for_v5e(schedule, shape, topo,
     text = _text(lambda a: schedule(a, mesh, plan=plan, nb=5,
                                     row_axis=row_axis), a)
     assert "tpu_custom_call" in text
+
+
+def test_row_sharded_f32_gram_cell_compiles_for_v5e(topo, compiled_kernels):
+    """The program of the cell ``gram-f32-2x2.rowshard_131072x16384`` at its
+    full size: the planner's schedule for an f32 (131072, 16384) A with its
+    rows over ``data`` and its tiles over ``model`` of a 2×2 mesh compiles
+    with the Pallas launches inside ``shard_map``; its one collective is the
+    reduce-scatter of the packed tile stack under ``distributed.psum_scatter``;
+    and two operand shards (the cell's pool), the temporaries and the answer
+    fit one chip's 16 GiB with a tenth to spare."""
+    from repro.tune.apply import ata_distributed_with_plan
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    plan = tune.plan(op="ata", m=131072, n=16384, dtype="float32", out="packed",
+                     devices=2, row_devices=2, backend="tpu")
+    assert plan.use_kernels and "B" in plan.comm_schedule
+    a = jax.ShapeDtypeStruct((131072, 16384), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data", None)))
+    compiled = jax.jit(lambda a: ata_distributed_with_plan(
+        a, mesh, plan, task_axis="model", row_axis="data")).lower(a).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    collectives = [line for line in text.splitlines()
+                   if " reduce-scatter(" in line or " all-reduce(" in line
+                   or " all-gather(" in line or " collective-permute(" in line]
+    assert len(collectives) == 1, collectives
+    assert "distributed.psum_scatter/reduce_scatter" in collectives[0]
+    mem = compiled.memory_analysis()
+    per_chip = (2 * mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes)
+    assert per_chip < 0.9 * 16 * 2**30, per_chip

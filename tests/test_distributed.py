@@ -626,6 +626,104 @@ def test_multidevice(script):
     _run_in_subprocess(script)
 
 
+PLANNED_2X2_SCRIPT = r"""
+import dataclasses, re
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro import obs, tune
+from repro.core.reference import syrk_ref
+from repro.core.symmetric import SymmetricMatrix
+from repro.obs import compiles
+from repro.tune.apply import ata_distributed_with_plan
+
+TOL = 1e-5   # f32 products over 256 rows: about 1e-7 relative, in float64 terms
+mesh = jax.make_mesh((2, 2), ("data", "model"))
+m, n = 256, 256
+plan = tune.plan(op="ata", m=m, n=n, dtype="float32", out="packed",
+                 devices=2, row_devices=2)
+assert plan.source == "analytic" and "B" in plan.comm_schedule, plan
+if SCHEDULE == "psum":
+    # 5 stripes: T = 15 tiles over the 2-device task axis, one dummy slot
+    plan = dataclasses.replace(plan, comm_schedule=None, nb=5, tile_w=56)
+t_total = plan.nb * (plan.nb + 1) // 2
+a_host = np.random.default_rng(16).standard_normal((m, n)).astype(np.float32)
+a = jax.device_put(a_host, NamedSharding(mesh, P("data", None)))
+want = syrk_ref(a_host.astype(np.float64))
+
+
+def planned():
+    return jax.jit(lambda a: ata_distributed_with_plan(
+        a, mesh, plan, task_axis="model", row_axis="data"))
+
+
+def rel_err(sym):
+    assert isinstance(sym, SymmetricMatrix), type(sym)
+    got = np.asarray(sym.to_dense(), np.float64)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+if CHECK == "correct":
+    err = rel_err(planned()(a))
+    assert err <= TOL, err
+    # the row collective removed: only row shard 0's partial Gram is reduced
+    psum, scatter = jax.lax.psum, jax.lax.psum_scatter
+
+    def first_rows(x):
+        keep = jax.lax.axis_index("data") == 0
+        return jax.tree.map(lambda v: jnp.where(keep, v, 0), x)
+
+    jax.lax.psum = lambda x, axis, **kw: psum(first_rows(x), axis, **kw)
+    jax.lax.psum_scatter = lambda x, axis, **kw: scatter(first_rows(x), axis, **kw)
+    broken = rel_err(planned()(a))
+    jax.lax.psum, jax.lax.psum_scatter = psum, scatter
+    assert broken > 1e4 * TOL, (err, broken)
+else:
+    obs.enable()
+    obs.trace.reset()
+    obs.metrics.reset()
+    compiles.reset()
+    compiled = planned().lower(a).compile()
+    # one program holds the root span: the set-up readers find it
+    progs = compiles.programs()
+    assert len(progs) == 1 and "distributed.ata" in progs[0].roots, progs
+    # every collective of the compiled program sits under a distributed.* scope
+    kinds = "all-reduce|reduce-scatter|all-gather|collective-permute|all-to-all"
+    found = 0
+    for line in compiled.as_text().splitlines():
+        if not re.search(r"\s(%s)(-start)?\(" % kinds, line):
+            continue
+        found += 1
+        path = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+        scopes = [p for p in path[:-1] if "." in p and not p.startswith("jit(")]
+        assert scopes and scopes[-1].startswith("distributed."), line
+    assert found, "no collective compiled"
+    # the counter holds the packed tile stack each device hands its collective
+    w = plan.tile_w
+    if SCHEDULE == "psum":
+        tiles = -(-t_total // 2)           # t_per slots of the psum
+    else:
+        tiles = -(-t_total // 4) * 4       # the T-padded staging buffer
+        assert tiles * w * w * 4 < 0.6 * n * n * 4
+    assert obs.metrics.get("distributed.collective_bytes") == tiles * w * w * 4
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("check", ["correct", "obs"])
+@pytest.mark.parametrize("schedule", ["plan", "psum"])
+def test_planned_2x2_packed_gram(schedule, check):
+    """The planned f32 packed Gram on a (2, 2) mesh, A's rows over ``data``
+    and the tiles over ``model``, under the planner's BFS interleaving
+    (nb=16: 64 and 72 tiles on the two task devices, dummy slots on the
+    first) and under the psum schedule on a ragged 15-tile grid: it matches
+    the float64 reference; with the row collective reduced to one row
+    shard it does not; one program holds the ``distributed.ata`` root span;
+    every collective is scoped ``distributed.*``; and
+    ``distributed.collective_bytes`` counts the packed stack."""
+    _run_in_subprocess(f"SCHEDULE, CHECK = {schedule!r}, {check!r}\n"
+                       + PLANNED_2X2_SCRIPT, devices=4)
+
+
 def test_bfsdfs_six_devices():
     """BFS/DFS on a 6-device pool — not a power of two, not the 8 the other
     scripts assume: bfs_tiling's pool-divisible triangle, subgroup splits
