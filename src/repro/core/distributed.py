@@ -60,7 +60,7 @@ from jax.sharding import AxisType, Mesh, PartitionSpec as P
 from repro import obs
 from repro.core.ata import ata
 from repro.core.precision import dot_precision
-from repro.core.strassen import _plan_base_fns, strassen_tn
+from repro.core.strassen import _plan_base_fns, strassen_tn, tree_depth
 from repro.core.symmetric import SymmetricMatrix, sym_tile
 
 __all__ = [
@@ -207,9 +207,15 @@ def _tri_coords_traced(t):
 def _tile_fn(w, *, plan, use_strassen, n_base, variant, leaf_dispatch,
              acc_dtype):
     """``compute_tile(a_local, t)``: tri-order tile ``t`` of ``a_localᵀa_local``
-    over stripes of width ``w`` — the leaf body both tile schedules share.
-    A plan with ``use_kernels`` puts its Pallas TN kernel at the bottom of
-    every tile's Strassen recursion."""
+    over stripes of width ``w`` — the sliced tile body of :func:`_tile_stack`.
+    It cuts the tile's two column stripes out of ``a_local`` and hands them
+    to ``strassen_tn``; a plan with ``use_kernels`` puts its Pallas TN kernel
+    at the bottom of every tile's Strassen recursion. Used where a tile is
+    not one kernel leaf (``w > n_base``: a recursion inside the tile), by
+    plans without kernels or Strassen, and where the stripe-major copy
+    would not fit the device (:func:`_takes_direct`); otherwise the direct
+    path of :func:`_direct_stack` computes the whole stack without the
+    cuts."""
     _, base_dot = _plan_base_fns(plan, None, None)
 
     def compute_tile(a_local, t):
@@ -258,6 +264,99 @@ def _slot_tile(compute_tile, a_local, g, valid, like):
         return jax.lax.pcast(z, vma, to="varying") if vma else z
 
     return jax.lax.cond(valid, lambda: compute_tile(a_local, g), dummy)
+
+
+def _direct_stack(a_local, ids, w, plan, acc_dtype):
+    """The tile stack as ONE ``gemm_tn_fused`` launch over a stripe-major
+    copy of ``a_local``.
+
+    ``a_local`` ``(m_l, nb·w)`` is relaid once, under the
+    ``distributed.stripes`` scope, into the block-major leaf grid
+    ``(1, 1, nb, m_l, w)`` (``core.strassen._to_blocks``'s layout with one
+    block row and ``nb`` block columns): ``m_l·nb·w`` words more on the
+    device, in place of two ``(m_l, w)`` stripe copies per tile. Slot ``q``
+    is a one-slot leaf reading stripe ``i`` as A and stripe ``j`` as B, sign
+    1, or sign 0 for a dummy slot (``ids[q] < 0``) — a dead leaf, whose dot
+    the kernel skips. The kernel's chunking is ``gemm_tn_pallas``'s on the
+    cut stripes and a ×1 sign is exact, so each tile is bitwise the sliced
+    path's.
+    """
+    from repro.kernels import ops
+
+    m_l, n_pad = a_local.shape
+    with obs.span("distributed.stripes", nb=n_pad // w, w=w):
+        # a stack of static slices: XLA writes it in one pass, in place,
+        # where a reshape + moveaxis compiles for the TPU to two full copies
+        # (through a column-major A) and twice the memory
+        stripes = jnp.stack([a_local[:, k * w:(k + 1) * w]
+                             for k in range(n_pad // w)])[None, None]
+    i, j = _tri_coords_traced(jnp.maximum(ids, 0))
+    rows = jnp.zeros_like(i)[:, None]
+    sgn = (ids >= 0).astype(jnp.int32)[:, None]
+    tables = ((rows, i[:, None], sgn), (rows, j[:, None], sgn))
+    return ops.gemm_tn_fused(stripes, stripes, tables, plan=plan,
+                             out_dtype=acc_dtype)
+
+
+def _takes_direct(plan, use_strassen, n_base, *, m, nb, w, schedule, p_task,
+                  d_row, out, itemsize):
+    """Whether the tile stack takes the direct path (:func:`_direct_stack`).
+
+    It does where the plan runs the Pallas kernels, a tile is one leaf of
+    ``strassen_tn`` (no recursion inside it: ``min(m_local, w) ≤ n_base``),
+    and the planner's per-device memory figure with the stripe-major copy
+    of the operand slab counted (``tune.cost.comm_memory_bytes(…,
+    stripes=True)``) fits the plan's machine — the planner admits a
+    schedule by the figure without the copy, so a plan that fits only
+    without it keeps the sliced path.
+    """
+    from repro.tune.cost import comm_memory_bytes, machine_for
+
+    if not (plan is not None and plan.use_kernels and use_strassen
+            and tree_depth((m // d_row, w, w), n_base) == 0):
+        return False
+    need = comm_memory_bytes(schedule, nb, w, p_task, d_row, m=m, out=out,
+                             itemsize=itemsize, stripes=True)
+    return need <= machine_for(plan.backend).device_memory_bytes
+
+
+def _tile_stack(a_local, ids, always_live, w, *, direct, plan, use_strassen,
+                n_base, variant, leaf_dispatch, acc_dtype):
+    """This device's ``(s, w, w)`` stack of tile slots, under the
+    ``distributed.tile_body`` scope — the body both tile schedules share.
+
+    ``ids`` is the traced ``(s,)`` int32 vector of the device's tri-order
+    tile ids, ``-1`` for a dummy slot; ``always_live[q]`` is ``True`` where
+    slot ``q`` is real on every device. A dummy slot's product never runs
+    and its tile is zeros.
+
+    ``direct`` (from :func:`_takes_direct`, which reads only the plan and
+    the shapes) makes the whole stack one fused launch
+    (:func:`_direct_stack`, counted by ``distributed.tiles_direct``).
+    Otherwise each slot cuts its stripes and runs ``strassen_tn``
+    (:func:`_tile_fn`, counted by ``distributed.tiles_sliced``): the
+    python-unrolled slot loop keeps every tile's products visible to XLA's
+    cost model (``lax.map`` would count the body once) and lets XLA
+    schedule tiles independently.
+    """
+    s = len(always_live)
+    with obs.span("distributed.tile_body", t_per=s, w=w, direct=direct):
+        if direct:
+            obs.metrics.inc("distributed.tiles_direct", s)
+            return _direct_stack(a_local, ids, w, plan, acc_dtype)
+        obs.metrics.inc("distributed.tiles_sliced", s)
+        compute_tile = _tile_fn(
+            w, plan=plan, use_strassen=use_strassen, n_base=n_base,
+            variant=variant, leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
+        )
+        like = jax.eval_shape(compute_tile, a_local, ids[0])
+        return jnp.stack([
+            _slot_tile(compute_tile, a_local, ids[q], True, like)
+            if always_live[q] else
+            _slot_tile(compute_tile, a_local, jnp.maximum(ids[q], 0),
+                       ids[q] >= 0, like)
+            for q in range(s)
+        ])
 
 
 def _assemble(tiles, n, nb, packed_block, alpha, out, *,
@@ -311,7 +410,9 @@ def ata_tile_parallel(
         ``leaf_dispatch`` feed the leaf-level Strassen of every per-device
         tile body — a batched plan runs each device's tile products through
         the level-synchronous one-dot-per-tile dispatch, a fused plan
-        through the coefficient-table combine with no operand stacks). Default: the
+        through the coefficient-table combine with no operand stacks; a
+        kernel plan whose tiles are one leaf computes a device's whole
+        stack in one fused launch, see :func:`ata_bfs_dfs`). Default: the
         planner front door with ``devices=p_task`` and the requested
         ``out`` — packed plans snap ``tile_w`` to the packed block grid so
         retrieval is a pure slice.
@@ -380,32 +481,25 @@ def ata_tile_parallel(
     if n_pad > n:
         a = jnp.pad(a, ((0, 0), (0, n_pad - n)))
 
-    compute_tile = _tile_fn(
-        w, plan=plan, use_strassen=use_strassen, n_base=n_base,
-        variant=variant, leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
-    )
-
     obs.metrics.inc("dispatch.ata_tile_parallel")
     obs.metrics.inc("ata_tile_parallel.tiles", t_total)
+    # slot q of device p holds tile p·t_per+q; it is real on every device
+    # when the last device's is
+    always_live = [(p_task - 1) * t_per + q < t_total for q in range(t_per)]
+    direct = _takes_direct(
+        plan, use_strassen, n_base, m=m, nb=nb, w=w, schedule=None,
+        p_task=p_task, d_row=mesh.shape[row_axis] if row_axis else 1,
+        out=out, itemsize=a.dtype.itemsize,
+    )
 
     def local_fn(a_local):
-        p = jax.lax.axis_index(task_axis)
-        like = jax.eval_shape(compute_tile, a_local, p)
-
-        def tile_slot(q):
-            # slot q of this device: tile p·t_per+q, statically when it is
-            # valid on every device, else behind the dummy mask
-            g = p * t_per + q
-            if (p_task - 1) * t_per + q < t_total:
-                return _slot_tile(compute_tile, a_local, g, True, like)
-            return _slot_tile(compute_tile, a_local,
-                              jnp.minimum(g, t_total - 1), g < t_total, like)
-
-        # python-unrolled tile loop (t_per is small): keeps every tile's
-        # matmuls visible to XLA's cost model (lax.map would count the body
-        # once) and lets XLA schedule tiles independently.
-        with obs.span("distributed.tile_body", t_per=t_per, w=w):
-            tiles = jnp.stack([tile_slot(q) for q in range(t_per)])
+        g = jax.lax.axis_index(task_axis) * t_per + jnp.arange(
+            t_per, dtype=jnp.int32)
+        tiles = _tile_stack(
+            a_local, jnp.where(g < t_total, g, -1), always_live, w,
+            direct=direct, plan=plan, use_strassen=use_strassen, n_base=n_base,
+            variant=variant, leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
+        )
         if row_axis is not None:
             # packed retrieval: reduce the tile stack, not a dense (n, n)
             tiles = _collective("distributed.psum",
@@ -595,9 +689,19 @@ def ata_bfs_dfs(
     axis, so subgroup collectives run on sub-axes of the mesh and never
     cross subgroups; DFS levels keep all of a group's devices cooperating
     on one subproblem, exactly like :func:`ata_tile_parallel`'s contiguous
-    sweep. Leaf tiles dispatch through the planned sequential machinery
-    (``strassen_tn`` with the plan's unrolled/batched/fused leaf body),
-    and retrieval is the packed ``SymmetricMatrix`` stack at the root.
+    sweep. Retrieval is the packed ``SymmetricMatrix`` stack at the root.
+
+    Tile stack (:func:`_tile_stack`, shared with :func:`ata_tile_parallel`):
+    where the plan runs the Pallas kernels, a tile is one kernel leaf
+    (``min(m_local, w) ≤ n_base``) and the planner's memory figure with the
+    copy below fits the device (:func:`_takes_direct`), each device
+    computes all its slots in ONE ``gemm_tn_fused`` launch that reads
+    stripes ``i`` and ``j`` by block index out of a stripe-major copy of
+    its A shard (``m_local·n_pad`` words more per device, relaid once under
+    ``distributed.stripes``); dummy slots are dead leaves (all-zero signs),
+    whose dot the kernel skips. Otherwise each tile cuts its two stripes and dispatches through
+    the planned sequential machinery (``strassen_tn`` with the plan's
+    unrolled/batched/fused leaf body). Both give bitwise-equal tiles.
 
     Communication: any BFS level switches the root exchange to the
     **tri-direct reduce-scatter** — every device stages its partial tiles
@@ -705,11 +809,6 @@ def ata_bfs_dfs(
     if n_pad > n:
         a = jnp.pad(a, ((0, 0), (0, n_pad - n)))
 
-    compute_tile = _tile_fn(
-        w, plan=plan, use_strassen=use_strassen, n_base=n_base,
-        variant=variant, leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
-    )
-
     obs.metrics.inc("dispatch.ata_bfs_dfs")
     obs.metrics.inc("ata_bfs_dfs.tiles", t_total)
     obs.metrics.inc("ata_bfs_dfs.bfs_levels", interleaving.count("B"))
@@ -720,21 +819,19 @@ def ata_bfs_dfs(
     from repro.launch.mesh import merged_axis
 
     merged = merged_axis(task_axis, row_axis)
+    direct = _takes_direct(
+        plan, use_strassen, n_base, m=m, nb=nb, w=w, schedule=interleaving,
+        p_task=p_task, d_row=d_row, out=out, itemsize=a.dtype.itemsize,
+    )
 
     def local_fn(a_local):
         pidx = jax.lax.axis_index(task_axis)
         row = jax.lax.dynamic_slice_in_dim(table, pidx, 1, axis=0)[0]
-        like = jax.eval_shape(compute_tile, a_local, pidx)
-
-        def tile_slot(q):
-            g = row[q]
-            if all_valid[q]:
-                return _slot_tile(compute_tile, a_local, g, True, like)
-            return _slot_tile(compute_tile, a_local, jnp.maximum(g, 0),
-                              g >= 0, like)
-
-        with obs.span("distributed.tile_body", t_per=s_eff, w=w):
-            tiles = jnp.stack([tile_slot(q) for q in range(s_eff)])
+        tiles = _tile_stack(
+            a_local, row, list(all_valid), w,
+            direct=direct, plan=plan, use_strassen=use_strassen, n_base=n_base,
+            variant=variant, leaf_dispatch=leaf_dispatch, acc_dtype=acc_dtype,
+        )
         if scatter:
             # BFS redistribution, tri-direct: stage the partial tiles at
             # their global tri positions in a T-padded buffer (one extra

@@ -55,8 +55,10 @@ wrappers (``repro.kernels.ops``):
   which rounds at the pallas input boundary); sign-0
   (dead) slots contribute an exact ±0 instead of being dropped, so the
   fused launch is value-equal to the trace-time gather (it may flip the
-  sign of a zero — invisible to ``==``). Same kernel body for Mosaic and
-  interpret mode, like everything else here.
+  sign of a zero — invisible to ``==``). A leaf whose A slots or B slots
+  are all dead skips its dot and returns exact zeros (the distributed tile
+  stack's dummy slots; Strassen's tables hold none). Same kernel body for
+  Mosaic and interpret mode, like everything else here.
 
 ``ops`` holds the jit'd public wrappers; ``ref`` holds the pure-jnp oracles
 used by the kernel test sweeps.

@@ -157,6 +157,10 @@ def gemm_tn_pallas(
 # same (bm, bn)×(bm, bk) chunk shapes, same minor-most contraction order,
 # same f32 VMEM accumulation — which is what keeps the fused launch
 # bitwise-equal to the unrolled per-leaf kernel calls.
+#
+# A dead leaf — one whose A slots or whose B slots all carry sign 0 — skips
+# its dot and flushes the zeroed accumulator: exact zeros, no MXU work (its
+# blocks are still fetched). Strassen's tables never hold one.
 # ---------------------------------------------------------------------------
 
 
@@ -184,14 +188,22 @@ def _gemm_tn_fused_kernel(
         mid = (lo + hi) // 2
         return combine(slot_refs, sgn, lo, mid) + combine(slot_refs, sgn, mid, hi)
 
-    a = combine(a_refs, asg, 0, w)
-    b = combine(b_refs, bsg, 0, w)
-    acc_ref[...] += jax.lax.dot_general(
-        a, b,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        precision=dot_precision(a, b),
-        preferred_element_type=jnp.float32,
-    )
+    def any_slot(sgn):
+        live = sgn[t, 0] != 0
+        for s in range(1, w):
+            live = jnp.logical_or(live, sgn[t, s] != 0)
+        return live
+
+    @pl.when(jnp.logical_and(any_slot(asg), any_slot(bsg)))
+    def _dot():
+        a = combine(a_refs, asg, 0, w)
+        b = combine(b_refs, bsg, 0, w)
+        acc_ref[...] += jax.lax.dot_general(
+            a, b,
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=dot_precision(a, b),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(pl.program_id(l_axis) == pl.num_programs(l_axis) - 1)
     def _flush():
@@ -221,6 +233,10 @@ def gemm_tn_fused_pallas(
     is the signed sum of blocks ``a_blocks[g, a_rows[t, w], a_cols[t, w]]``
     over the ``W`` slots. One launch computes all ``G·T`` leaf products —
     the ± combinations run in the kernel prologue, nothing is materialized.
+    A leaf whose A signs or whose B signs are all 0 is dead: its product is
+    exact zeros and its dot never runs. The tables may be traced, and may
+    vary over mesh axes the block grids do not (the result varies over
+    both).
     """
     if a_blocks.ndim not in (5, 6) or a_blocks.ndim != b_blocks.ndim:
         raise ValueError(
@@ -280,6 +296,8 @@ def gemm_tn_fused_pallas(
         idx = args[: len(grid)]
         return (idx[0] * t_count + idx[1],) + _pre(idx) + (idx[-3], idx[-2])
 
+    prefetch = tuple(jnp.asarray(x) for x in (a_rows, a_cols, a_sgn,
+                                              b_rows, b_cols, b_sgn))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=grid,
@@ -299,16 +317,12 @@ def gemm_tn_fused_pallas(
         grid_spec=grid_spec,
         out_shape=out_struct(
             (g_count * t_count,) + batch_dims + (np_, kp), out_dtype,
-            a_blocks, b_blocks,
+            a_blocks, b_blocks, *prefetch,
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * l_axis + ("arbitrary",),
         ),
         interpret=interpret,
         name="gemm_tn_fused",
-    )(
-        jnp.asarray(a_rows), jnp.asarray(a_cols), jnp.asarray(a_sgn),
-        jnp.asarray(b_rows), jnp.asarray(b_cols), jnp.asarray(b_sgn),
-        *([a_blocks] * w), *([b_blocks] * w),
-    )
+    )(*prefetch, *([a_blocks] * w), *([b_blocks] * w))
     return out[..., :n, :k]

@@ -635,6 +635,7 @@ def comm_memory_bytes(
     m: int,
     out: str = "packed",
     itemsize: int = 4,
+    stripes: bool = False,
 ) -> int:
     """Per-device residency of one interleaving (the CAPS memory side).
 
@@ -644,6 +645,9 @@ def comm_memory_bytes(
     partial stack, and the scattered ``T/P`` chunk it keeps); a
     pure-``'D'`` string stays lean — operand slab + slot stack + the
     all-reduce's full reduced copy + its share of the packed result.
+    ``stripes`` counts the operand slab twice: the direct tile path of
+    ``core.distributed`` reads its stripes from a stripe-major copy of it,
+    and takes that path only where this figure fits the device.
     """
     sched = comm_schedule or "D"
     t_total = nb * (nb + 1) // 2
@@ -652,7 +656,7 @@ def comm_memory_bytes(
     scatter = "B" in sched and pool > 1
     s_max = _bfs_makespan(nb, devices, sched)
     tile = tile_w * tile_w * itemsize
-    operand = (m // d) * nb * tile_w * itemsize
+    operand = (2 if stripes else 1) * (m // d) * nb * tile_w * itemsize
     local_stack = s_max * tile
     if scatter:
         t_pad = -(-t_total // pool) * pool

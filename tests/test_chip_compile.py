@@ -135,10 +135,14 @@ def test_row_sharded_f32_gram_cell_compiles_for_v5e(topo, compiled_kernels):
     """The program of the cell ``gram-f32-2x2.rowshard_131072x16384`` at its
     full size: the planner's schedule for an f32 (131072, 16384) A with its
     rows over ``data`` and its tiles over ``model`` of a 2×2 mesh compiles
-    with the Pallas launches inside ``shard_map``; its one collective is the
-    reduce-scatter of the packed tile stack under ``distributed.psum_scatter``;
-    and two operand shards (the cell's pool), the temporaries and the answer
-    fit one chip's 16 GiB with a tenth to spare."""
+    with the Pallas launches inside ``shard_map``; each chip's 72 tiles of
+    1024² are one ``gemm_tn_fused`` launch over the stripe-major copy of its
+    A shard, left in the entry computation (not fused into the staging
+    scatter, where the benchmark's launch reader would not find it); its
+    one collective is the reduce-scatter of the packed tile stack under
+    ``distributed.psum_scatter``; and two operand shards (the cell's pool),
+    the temporaries and the answer fit one chip's 16 GiB with a tenth to
+    spare."""
     from repro.tune.apply import ata_distributed_with_plan
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
@@ -150,7 +154,15 @@ def test_row_sharded_f32_gram_cell_compiles_for_v5e(topo, compiled_kernels):
     compiled = jax.jit(lambda a: ata_distributed_with_plan(
         a, mesh, plan, task_axis="model", row_axis="data")).lower(a).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    computation, launches = None, []
+    for line in text.splitlines():
+        if line.endswith("{") and line.startswith(("%", "ENTRY %")):
+            computation = line.split()[0]
+        if 'custom_call_target="tpu_custom_call"' in line:
+            launches.append((computation, line.split()[0]))
+    assert len(launches) == 1, launches
+    assert launches[0][0] == "ENTRY", launches
+    assert launches[0][1].startswith("%gemm_tn_fused"), launches
     collectives = [line for line in text.splitlines()
                    if " reduce-scatter(" in line or " all-reduce(" in line
                    or " all-gather(" in line or " collective-permute(" in line]

@@ -71,26 +71,105 @@ def test_tile_parallel_packed_single_device():
     )
 
 
-def test_tile_parallel_packed_no_dense_intermediate():
+def _check_tile_schedule_jaxpr(schedule, path):
     """The packed path's jaxpr must not materialize any dense (n, n)
     square — the whole point of packed retrieval. Runs the repro.check
     ``no-dense-square`` rule (its walker descends shard_map/cond bodies)
-    with the shape set pinned by override — the tile schedule has no Plan
-    object here."""
-    from repro import check
+    with the shape set pinned by override, on a one-device mesh (n = 256,
+    two stripes of w = 128, three tiles). The tile stack takes the path the
+    plan allows: ``'direct'`` under a kernel plan whose tiles are one leaf
+    (n_base = w) cuts no (m, w) column stripe out of A and makes exactly
+    one ``gemm_tn_fused`` launch for all three tiles; ``'sliced'`` under
+    pinned tunables (n_base = 64 < w: a recursion inside each tile) cuts
+    two stripes per tile."""
+    import dataclasses
 
+    from repro import check, obs, tune
+    from repro.check.artifacts import walk_eqns
+
+    m, n, w, tiles = 128, 256, 128, 3  # w == packed bn → pure-slice retrieval
+    if path == "direct":
+        plan = dataclasses.replace(
+            tune.plan(op="ata", m=m, n=n, dtype="float32", devices=1,
+                      out="packed"),
+            use_kernels=True, n_base=w, nb=2, tile_w=w, packed_block=w)
+        kw = dict(plan=plan)
+    else:
+        kw = dict(n_base=64, nb=2)
     mesh = jax.make_mesh((1,), ("model",))
-    n = 256  # aligned: w == packed bn == 128 → pure-slice retrieval
-    a_abs = jax.ShapeDtypeStruct((128, n), jnp.float32)
+    obs.enable()
+    obs.metrics.reset()
     jaxpr = jax.make_jaxpr(
-        lambda a: ata_tile_parallel(
-            a, mesh, task_axis="model", n_base=64, nb=2, out="packed"
-        )
-    )(a_abs)
-    art = check.Artifact(label="tile:packed", jaxpr=jaxpr.jaxpr,
+        lambda a: schedule(a, mesh, task_axis="model", out="packed", **kw)
+    )(jax.ShapeDtypeStruct((m, n), jnp.float32))
+    counts = {k: obs.metrics.get(f"distributed.tiles_{k}")
+              for k in ("direct", "sliced")}
+    art = check.Artifact(label=f"tile:packed:{path}", jaxpr=jaxpr.jaxpr,
                          overrides={"forbidden_squares": {(n, n)}})
     report = check.run(art, rules=["no-dense-square"])
     assert not report.violations, report.summary()
+    eqns = [site.eqn for site in walk_eqns(jaxpr.jaxpr)]
+    stripe_cuts = [e for e in eqns if e.primitive.name == "dynamic_slice"
+                   and e.invars[0].aval.shape == (m, n)
+                   and e.outvars[0].aval.shape == (m, w)]
+    launches = [e.params["name"] for e in eqns
+                if e.primitive.name == "pallas_call"]
+    if path == "direct":
+        assert counts == {"direct": tiles, "sliced": 0}, counts
+        assert not stripe_cuts
+        assert launches == ["gemm_tn_fused"], launches
+    else:
+        assert counts == {"direct": 0, "sliced": tiles}, counts
+        assert len(stripe_cuts) == 2 * tiles
+        assert not launches
+
+
+def test_tile_parallel_packed_no_dense_intermediate():
+    """The psum schedule's packed jaxpr on the sliced tile path (see
+    :func:`_check_tile_schedule_jaxpr`)."""
+    _check_tile_schedule_jaxpr(ata_tile_parallel, "sliced")
+
+
+@pytest.mark.parametrize("schedule,path", [
+    ("psum", "direct"), ("bfs_dfs", "direct"), ("bfs_dfs", "sliced")])
+def test_tile_schedule_jaxpr_by_path(schedule, path):
+    """Both schedules build their tile stacks through one helper, so each
+    takes either path: the checks of
+    :func:`_check_tile_schedule_jaxpr` on the other three combinations."""
+    from repro.core.distributed import ata_bfs_dfs
+
+    fn = {"psum": ata_tile_parallel, "bfs_dfs": ata_bfs_dfs}[schedule]
+    _check_tile_schedule_jaxpr(fn, path)
+
+
+@pytest.mark.parametrize("m", [131072, 262144])
+def test_direct_path_needs_room_for_the_stripe_copy(m):
+    """The planner's per-device memory figure for a direct-path plan holds
+    a second copy of the A shard (the stripe-major copy the fused launch
+    reads), and the tile stack takes the direct path only where that
+    figure fits the plan's machine. For the f32 Gram of width 16384 on a
+    2x2 v5e mesh the planner admits the BFS plan at both heights by the
+    figure without the copy; with it, 9.6e9 bytes fit at m = 131072 and
+    18.2e9 do not at m = 262144, which keeps the sliced path."""
+    from repro import tune
+    from repro.core.distributed import _takes_direct
+    from repro.tune import cost
+
+    plan = tune.plan(op="ata", m=m, n=16384, dtype="float32", out="packed",
+                     devices=2, row_devices=2, backend="tpu")
+    assert plan.use_kernels and plan.algorithm != "dense"
+    assert plan.n_base >= plan.tile_w  # each tile is one kernel leaf
+    args = (plan.comm_schedule, plan.nb, plan.tile_w, 2, 2)
+    lean = cost.comm_memory_bytes(*args, m=m, out="packed")
+    full = cost.comm_memory_bytes(*args, m=m, out="packed", stripes=True)
+    assert full - lean == (m // 2) * plan.nb * plan.tile_w * 4
+    room = cost.machine_for("tpu").device_memory_bytes
+    assert lean <= room
+    direct = _takes_direct(
+        plan, True, plan.n_base, m=m, nb=plan.nb, w=plan.tile_w,
+        schedule=plan.comm_schedule, p_task=2, d_row=2, out="packed",
+        itemsize=4)
+    assert direct == (full <= room) == (m == 131072), (full, room)
 
 
 class _StubMesh:
@@ -707,6 +786,91 @@ else:
     assert obs.metrics.get("distributed.collective_bytes") == tiles * w * w * 4
 print("OK")
 """
+
+
+TILE_STACK_2X2_SCRIPT = r"""
+import dataclasses, functools
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro import obs, tune
+from repro.core.distributed import ata_bfs_dfs, ata_tile_parallel
+from repro.core.strassen import strassen_tn
+from repro.kernels import ops
+from repro.kernels.gemm_tn import gemm_tn_pallas
+
+# Interpret-mode kernels cannot run under shard_map's varying-axes check
+# (the HLO interpreter's scratch buffers carry no mesh axes); the compile
+# for a described v5e (tests/test_chip_compile.py) checks that typing.
+jax.shard_map = functools.partial(jax.shard_map, check_vma=False)
+mesh = jax.make_mesh((2, 2), ("data", "model"))
+m, n = 512, 512
+a_host = np.random.default_rng(17).standard_normal((m, n)).astype(np.float32)
+a = jax.device_put(a_host, NamedSharding(mesh, P("data", None)))
+schedule, pins, path = {
+    # BFS over the task axis: 4 and 6 tiles, two dummy slots on one device
+    "bfs_direct": (ata_bfs_dfs, dict(nb=4, tile_w=128, n_base=128), "direct"),
+    # the psum schedule on a ragged grid: 15 tiles, one dummy slot
+    "psum_direct": (ata_tile_parallel, dict(comm_schedule=None, nb=5,
+                                            tile_w=104, n_base=128), "direct"),
+    # w > n_base: a recursion inside each tile keeps the sliced path
+    "bfs_sliced": (ata_bfs_dfs, dict(nb=4, tile_w=128, n_base=64), "sliced"),
+}[CASE]
+plan = dataclasses.replace(
+    tune.plan(op="ata", m=m, n=n, dtype="float32", out="packed", devices=2,
+              row_devices=2),
+    use_kernels=True, packed_block=pins["tile_w"], **pins)
+assert schedule is ata_tile_parallel or "B" in plan.comm_schedule
+obs.enable()
+obs.metrics.reset()
+got = jax.jit(lambda a: schedule(a, mesh, plan=plan, task_axis="model",
+                                 row_axis="data", out="packed"))(a)
+slots = {"bfs_direct": 6, "psum_direct": 8, "bfs_sliced": 6}[CASE]
+other = "sliced" if path == "direct" else "direct"
+assert obs.metrics.get(f"distributed.tiles_{path}") == slots
+assert obs.metrics.get(f"distributed.tiles_{other}") == 0
+
+# the tile each path must reproduce bitwise: the kernel on the cut stripes
+# (direct), or the planned Strassen recursion over them (sliced)
+if path == "direct":
+    tile = lambda x, y: gemm_tn_pallas(x, y, blocks=plan.gemm_blocks,
+                                       interpret=True)
+else:
+    tile = lambda x, y: strassen_tn(
+        x, y, n_base=plan.n_base, variant=plan.variant,
+        leaf_dispatch=plan.leaf_dispatch,
+        base_dot=functools.partial(ops.gemm_tn, blocks=plan.gemm_blocks))
+nb, w = plan.nb, plan.tile_w
+x = np.pad(a_host, ((0, 0), (0, nb * w - n)))
+low = np.zeros((nb * w, nb * w), np.float32)
+for i in range(nb):
+    for j in range(i + 1):
+        # each row shard's partial tile; the collective sums the two
+        p0, p1 = (np.asarray(tile(jnp.asarray(h[:, i * w:(i + 1) * w]),
+                                   jnp.asarray(h[:, j * w:(j + 1) * w])))
+                  for h in np.split(x, 2))
+        low[i * w:(i + 1) * w, j * w:(j + 1) * w] = p0 + p1
+low = low[:n, :n]
+want = np.tril(low) + np.tril(low, -1).T
+dense = np.asarray(got.to_dense())
+assert (dense == want).all(), np.abs(dense - want).max()
+ref = a_host.astype(np.float64).T @ a_host.astype(np.float64)
+err = np.linalg.norm(dense - ref) / np.linalg.norm(ref)
+assert err <= 1e-6, err
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("case", ["bfs_direct", "psum_direct", "bfs_sliced"])
+def test_tile_stack_2x2_matches_kernel_on_cut_stripes(case):
+    """Under a kernel plan on a (2, 2) mesh (A's rows over ``data``), both
+    schedules' tile stacks give the packed Gram bitwise equal to the one
+    assembled from the cut stripes: on the direct path (one fused launch
+    over the stripe-major copy, dummy slots dead) each tile is
+    ``gemm_tn_pallas`` on its two stripes with the plan's blocks; with
+    ``w > n_base`` the sliced path's tile is the planned ``strassen_tn``.
+    The answer matches the float64 Gram, and the counters name the path."""
+    _run_in_subprocess(f"CASE = {case!r}\n" + TILE_STACK_2X2_SCRIPT,
+                       devices=4)
 
 
 @pytest.mark.parametrize("check", ["correct", "obs"])

@@ -375,6 +375,72 @@ def test_gemm_tn_fused_bf16_storage_f32_accumulate():
     )
 
 
+@pytest.mark.parametrize("dead", ["a", "b", "both"])
+def test_gemm_tn_fused_dead_leaf_skips_its_dot(dead):
+    """One-slot leaves over a (1, 1, 3) stripe grid, as the distributed
+    tile stack builds them: leaf (i, j) reads stripe i as A and stripe j as
+    B. A leaf whose A signs, B signs or both are all 0 is dead: its product
+    is exact zeros, even over a NaN stripe (0·NaN would be NaN had its dot
+    run), and each live leaf is bitwise the kernel on its cut stripes."""
+    from repro.kernels import ops
+
+    m, w, blocks = 96, 64, (64, 64, 64)
+    r = np.random.default_rng(31)
+    a = r.standard_normal((m, 3 * w)).astype(np.float32)
+    a[:, 2 * w:] = np.nan
+    stripes = jnp.asarray(a.reshape(m, 3, w).transpose(1, 0, 2))[None, None]
+    ii, jj = np.array([[0], [1], [1], [2]]), np.array([[0], [0], [1], [2]])
+    live = np.array([[1], [1], [1], [0]])
+    one = np.ones_like(live)
+    sa, sb = {"a": (live, one), "b": (one, live), "both": (live, live)}[dead]
+    rows = np.zeros_like(ii)
+    got = np.asarray(ops.gemm_tn_fused(
+        stripes, stripes, ((rows, ii, sa), (rows, jj, sb)), blocks=blocks,
+        interpret=True))
+    np.testing.assert_array_equal(got[3], np.zeros((w, w), np.float32))
+    for t in range(3):
+        i, j = ii[t, 0], jj[t, 0]
+        want = gemm_tn(jnp.asarray(a[:, i * w:(i + 1) * w]),
+                       jnp.asarray(a[:, j * w:(j + 1) * w]),
+                       blocks=blocks, interpret=True)
+        np.testing.assert_array_equal(got[t], np.asarray(want))
+
+
+def test_gemm_tn_fused_one_dead_leaf_leaves_the_rest():
+    """Zeroing one Strassen leaf's signs kills that leaf alone: it reads
+    exact zeros and the other six leaves are bitwise what they were."""
+    from repro.core.strassen import _pad_root, _slot_tables, _to_blocks
+    from repro.kernels import ops
+
+    r = np.random.default_rng(32)
+    a = _pad_root(jnp.asarray(r.standard_normal((128, 96)), jnp.float32), 1)
+    b = _pad_root(jnp.asarray(r.standard_normal((128, 64)), jnp.float32), 1)
+    (ar, ac, asg), (br, bc, bsg) = _slot_tables(1)
+    asg, bsg = asg.copy(), bsg.copy()
+    asg[4] = bsg[4] = 0
+    run = lambda tables: np.asarray(ops.gemm_tn_fused(
+        _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], tables,
+        blocks=(64, 64, 64), interpret=True))
+    want = run(_slot_tables(1))
+    got = run(((ar, ac, asg), (br, bc, bsg)))
+    np.testing.assert_array_equal(got[4], np.zeros_like(got[4]))
+    keep = [t for t in range(7) if t != 4]
+    np.testing.assert_array_equal(got[keep], want[keep])
+
+
+@pytest.mark.parametrize("L", range(6))
+def test_slot_tables_have_no_dead_leaf(L):
+    """Every leaf of Strassen's fused tables has a live A slot and a live B
+    slot, so the fused kernel's dead-leaf skip never fires on them and
+    every fused dispatch computes what it did before the skip existed."""
+    from repro.core.strassen import _slot_tables
+
+    (_, _, asg), (_, _, bsg) = _slot_tables(L)
+    assert asg.shape == bsg.shape == (7 ** L, 2 ** L)
+    assert (asg != 0).any(axis=1).all()
+    assert (bsg != 0).any(axis=1).all()
+
+
 @pytest.mark.parametrize("L", [1, 2])
 def test_syrk_gather_bitwise_vs_stacked_syrk(L):
     """The diagonal half of the contract: gathering leaf pairs through the
