@@ -56,8 +56,7 @@ def compiled_text(fn, *abstract_args) -> str:
 
     The single lowering path shared by every collective-accounting
     consumer (``repro.check``'s collective-budget rule,
-    ``obs.metrics.record_collective_bytes`` call sites, the distributed
-    benchmarks): callers that only need byte counts never hold the
+    ``obs.metrics.record_collective_bytes`` call sites): callers that only need byte counts never hold the
     compiled executable, and nothing lowers the same function twice.
     ``fn`` may already be jitted (it is reused as-is) or a plain callable.
     """

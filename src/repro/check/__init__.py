@@ -13,7 +13,7 @@ rule-registry static analyzer over traced artifacts:
 * :mod:`repro.check.artifacts` — :class:`Artifact`, the canonical
   :func:`walk_eqns` traversal, and :func:`trace_plan` (traces the exact
   callable the autotuner times).
-* :mod:`repro.check.rules` — the registry and the eight shipped rules.
+* :mod:`repro.check.rules` — the registry and the seven shipped rules.
 * :mod:`repro.check.harness` — the canonical plan grid and the
   distributed (multi-device) sweep.
 

@@ -14,8 +14,8 @@ nested jaxpr reachable through equation params (``pjit``, ``shard_map``,
 ``scan``/``while``/``cond`` bodies, custom-call wrappers …) and — by
 default — treats ``pallas_call`` bodies as opaque. In-kernel equations are
 tile-granular by the kernels' block contract; the structural invariants the
-rules police (dense squares, operand stacks, full transposes, dispatch
-counts) are wrapper-level properties, so counting inside kernel bodies
+rules police (dense squares, full transposes, dispatch counts) are
+wrapper-level properties, so counting inside kernel bodies
 would double-book every leaf. Pass ``into_pallas=True`` to audit kernel
 bodies too.
 

@@ -1,7 +1,7 @@
 """Tracing harnesses: the canonical plan grid and the distributed sweep.
 
 :func:`canonical_plans` enumerates the (op × algorithm × leaf_dispatch ×
-out × engine × dtype) grid the CI gate traces on every push — all three
+out × engine × dtype) grid the CI gate traces on every push — both
 leaf dispatches, XLA and kernel (interpret) engines, dense and packed
 outputs, both solver methods, plus a bf16 row per product op so the
 ``acc-dtype`` rule has sub-f32 operands to police. Shapes are rectangular
@@ -16,7 +16,7 @@ half — the tile-parallel and rowshard schedules traced through
 (:func:`bfsdfs_plans` — planner-selected interleaving on a 2-axis
 (row, task) submesh) — compiled once (the ``analysis.hlo.compiled_text``
 path shared with the collective accounting), and checked against the
-packed/fused structural rules plus ``collective-budget`` (which holds
+packed structural rules plus ``collective-budget`` (which holds
 BFS artifacts to the tighter one-chunk reduce-scatter budget). CI runs
 it inside the distributed-smoke job's 8-fake-CPU-device subprocess.
 """
@@ -63,9 +63,9 @@ def canonical_plans() -> List:
     solve = cost.default_plan("solve", m, n, k, out="packed")
 
     plans = []
-    # the product grid: all three leaf dispatches × both engines × both outs
+    # the product grid: both leaf dispatches × both engines × both outs
     for uk in (False, True):
-        for ld in ("unrolled", "batched", "fused"):
+        for ld in ("unrolled", "batched"):
             for out in ("dense", "packed"):
                 plans.append(mk(ata, algorithm="strassen", leaf_dispatch=ld,
                                 use_kernels=uk, out=out))

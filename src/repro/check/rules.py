@@ -12,9 +12,6 @@ module docstrings of ``core.ata`` / ``core.strassen`` / ``solve``):
 ================== ========================================================
 ``no-dense-square``   packed paths never materialize an ``(n, n)`` /
                       ``(n_pad, n_pad)`` square (paper Prop. 4.2's low(C)).
-``no-operand-stacks`` fused dispatch never materializes a ``7``-multiple
-                      leaf *operand* stack (the batched dispatch's
-                      signature traffic) — combines live in the prologue.
 ``dot-budget``        ``dot_general`` count equals the closed-form leaf
                       count the cost model prices (``tune.cost``).
 ``launch-budget``     ``pallas_call`` count equals the kernel-path closed
@@ -42,7 +39,6 @@ are suppressed through the report-level allowlist
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.check.artifacts import Artifact
@@ -112,12 +108,6 @@ def _packed_bn(plan) -> int:
     return default_block_size(plan.n, plan.packed_block)
 
 
-def _ceil_half(d: int, times: int) -> int:
-    for _ in range(times):
-        d = (d + (d & 1)) // 2
-    return d
-
-
 def _itemsize(dtype_str: str) -> int:
     import jax.numpy as jnp
 
@@ -177,54 +167,6 @@ def no_dense_square(art: Artifact) -> List[Finding]:
     return out
 
 
-@rule("no-operand-stacks")
-def no_operand_stacks(art: Artifact) -> List[Finding]:
-    """Fused dispatch must not materialize leaf *operand* stacks.
-
-    The fused-leaf contract (``kernels/__init__.py``): operand ± combines
-    happen in the kernel prologue (or per-leaf at trace time on the XLA
-    path) — never as a cross-leaf ``(…·7^i, m_L, n_L)`` stack in HBM. The
-    discriminator is exact: Strassen operand stacks carry a leading-dim
-    product divisible by 7, while the legal block-major relayouts are
-    power-of-two-leading and the ATA diagonal stack is ``4^L``-leading.
-    Product/decode stacks (trailing ``(n_L, k_L)``) are excluded — those
-    the fused dispatch *does* materialize, by design.
-
-    Applies to ``leaf_dispatch='fused'`` product plans with depth ≥ 1.
-    """
-    plan = art.plan
-    if (plan is None or plan.leaf_dispatch != "fused"
-            or plan.op not in ("ata", "gemm_tn")):
-        return []
-    lv = _depth(plan)
-    if lv == 0:
-        return []
-    m_l, n_l = _ceil_half(plan.m, lv), _ceil_half(plan.n, lv)
-    if plan.op == "gemm_tn":
-        k_l = _ceil_half(plan.k, lv)
-        forbidden = {(m_l, n_l), (m_l, k_l)} - {(n_l, k_l)}
-    else:
-        # both operands of the off-diagonal leaves are A-blocks; the
-        # product tile is (n_l, n_l), excluded when indistinguishable
-        forbidden = {(m_l, n_l)} - {(n_l, n_l)}
-    if not forbidden:
-        return []            # square leaves: operand ≡ product shape
-    out = []
-    for site in art.sites():
-        for v in site.eqn.outvars:
-            shape = tuple(getattr(v.aval, "shape", ()))
-            if len(shape) < 3 or shape[-2:] not in forbidden:
-                continue
-            lead = math.prod(shape[:-2])
-            if lead > 1 and lead % 7 == 0:
-                out.append(_finding(
-                    art, "no-operand-stacks",
-                    f"materialized operand stack {shape} under fused "
-                    f"dispatch (leading {lead} ≡ 0 mod 7)",
-                    site, shape))
-    return out
-
-
 def _expected_dots(plan) -> Optional[int]:
     """Closed-form ``dot_general`` count of the XLA dispatch, or None when
     the rule has no exact form (see ``dot-budget`` docstring)."""
@@ -236,11 +178,9 @@ def _expected_dots(plan) -> Optional[int]:
             return 1                       # one classical dot, any dispatch
         if plan.op == "ata":
             s, g = cost._ata_leaves(plan.m, plan.n, plan.n_base)
-            return {"unrolled": s + g, "batched": 2,
-                    "fused": g + 1}[plan.leaf_dispatch]
+            return {"unrolled": s + g, "batched": 2}[plan.leaf_dispatch]
         leaves = cost._strassen_leaves(plan.m, plan.n, plan.k, plan.n_base)
-        return {"unrolled": leaves, "batched": 1,
-                "fused": leaves}[plan.leaf_dispatch]
+        return {"unrolled": leaves, "batched": 1}[plan.leaf_dispatch]
     if plan.op == "solve":
         if plan.method == "cg":
             # Aᵀb + one loop body (A·p plus the planned TN leaves); the
@@ -268,8 +208,7 @@ def dot_budget(art: Artifact) -> List[Finding]:
     This is the cost model cross-checked against the program it prices:
     unrolled = one dot per leaf (``cost._ata_leaves`` /
     ``cost._strassen_leaves`` — exactly ``cost.dispatch_calls``), batched =
-    O(1) batched dots, fused = per-leaf trace-time gathers feeding one dot
-    per off-diagonal leaf plus the gathered diagonal syrk. Solve plans get
+    O(1) batched dots. Solve plans get
     the gram's form plus the factor/substitution einsum band (method=
     'factor') or the CG operator pair (method='cg'). Applies to XLA-path
     plans (``use_kernels=False``, unbatched); the kernel path is budgeted
@@ -296,8 +235,8 @@ def dot_budget(art: Artifact) -> List[Finding]:
 @rule("launch-budget")
 def launch_budget(art: Artifact) -> List[Finding]:
     """Kernel-path plans: the ``pallas_call`` count must equal the closed
-    form (unrolled = one launch per leaf; batched = one per engine; fused =
-    one per level plus the gathered diagonal) and never exceed the
+    form (unrolled = one launch per leaf; batched = one per engine) and
+    never exceed the
     ``cost.dispatch_calls`` budget the planner prices. Applies to product
     plans with ``use_kernels=True``. Override: ``expected_launches``.
     """
@@ -315,13 +254,11 @@ def launch_budget(art: Artifact) -> List[Finding]:
             expected = 1
         elif plan.op == "ata":
             s, g = cost._ata_leaves(plan.m, plan.n, plan.n_base)
-            expected = {"unrolled": s + g, "batched": 2,
-                        "fused": lv + 1}[plan.leaf_dispatch]
+            expected = {"unrolled": s + g, "batched": 2}[plan.leaf_dispatch]
         else:
             leaves = cost._strassen_leaves(plan.m, plan.n, plan.k,
                                            plan.n_base)
-            expected = {"unrolled": leaves, "batched": 1,
-                        "fused": 1}[plan.leaf_dispatch]
+            expected = {"unrolled": leaves, "batched": 1}[plan.leaf_dispatch]
         budget = cost.dispatch_calls(
             plan.op, plan.algorithm, plan.m, plan.n, plan.k, plan.n_base,
             plan.leaf_dispatch)

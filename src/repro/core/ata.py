@@ -37,12 +37,7 @@ TPU adaptation notes (see DESIGN.md §2):
   level-synchronously — all diagonal leaves as ONE batched syrk and every
   Strassen leaf of every off-diagonal block as ONE batched TN dot — and
   decodes back into the identical ``_TriNode`` assembly, bitwise-equal to
-  the unrolled form (tested; see DESIGN.md §2); ``'fused'`` keeps the
-  level-synchronous tree but never materializes an operand combination:
-  each leaf operand is a per-leaf ±1 slot table over the root leaf-block
-  grid, evaluated in the Pallas kernel prologues (coefficient tables as
-  scalar-prefetch operands) or as trace-time slice gathers on the XLA
-  path — same decode, same ``_TriNode`` assembly, bitwise-equal (tested).
+  the unrolled form (tested; see DESIGN.md §2).
 
 ``ata`` is a pure JAX function: it composes with ``jit``, ``vmap``, ``grad``,
 and ``shard_map`` (used by ``repro.core.distributed``). ``ata_batched`` runs
@@ -67,16 +62,13 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.strassen import (
     DEFAULT_N_BASE,
-    _combine_slots,
     _dot_tn,
     _encode_fns,
     _leaf_dot,
     _pad_root,
     _plan_base_fns,
-    _plan_fused_fns,
     _rec_strassen,
     _rec_winograd,
-    _slot_tables,
     _to_blocks,
     _unblock,
     resolve_tunables,
@@ -189,40 +181,7 @@ def _accum_axis1(x):
     return acc
 
 
-def _combine_level(a, L, lev, mL, nL):
-    """Fused leaf operands of ATA level ``lev`` as trace-time slice gathers.
-
-    One (A, B) operand pair per (slab parent ``p``, Strassen leaf ``t``),
-    ordered parent-major exactly like the encode stacks (``p·7^{L-ℓ} + t``).
-    Every slot block is a direct slice of the root-padded input — no
-    block-major transpose and no operand stack is ever materialized.
-    """
-    R, Rl, H = 1 << L, 1 << lev, 1 << (lev - 1)
-    q = R // Rl
-    (ar, ac, asg), (br, bc, bsg) = _slot_tables(L - lev)
-    T = 7 ** (L - lev)
-
-    def getter(p, side):
-        h, rb = divmod(p, Rl)
-
-        def get(r, c):
-            i = rb * q + r
-            j = (2 * h + side) * q + c
-            return a[..., i * mL:(i + 1) * mL, j * nL:(j + 1) * nL]
-
-        return get
-
-    la, lb = [], []
-    for p in range(H * Rl):
-        ga, gb = getter(p, 1), getter(p, 0)   # A = right slabs, B = left
-        for t in range(T):
-            la.append(_combine_slots(ga, ar[t], ac[t], asg[t]))
-            lb.append(_combine_slots(gb, br[t], bc[t], bsg[t]))
-    return la, lb
-
-
-def _ata_level_sync(a, L, *, variant, base_syrk, base_dot,
-                    fused=False, fused_syrk=None, fused_dot=None):
+def _ata_level_sync(a, L, *, variant, base_syrk, base_dot):
     """The whole ATA tree with batched leaves: encode every off-diagonal
     Strassen product into per-level stacks, run ALL ``Σ_ℓ 2^{2ℓ-1}·7^{L-ℓ}``
     Strassen leaves as one batched TN dot and ALL ``4^L`` diagonal leaves as
@@ -235,19 +194,11 @@ def _ata_level_sync(a, L, *, variant, base_syrk, base_dot,
     ``s = i·2^ℓ + r`` (``i`` = parent column range, ``r`` = row slab), so
     the per-``i`` slab accumulation of the unrolled recursion is a
     left-to-right fold over a reshaped axis.
-
-    ``fused=True`` replaces the encode stacks with per-leaf ±1 slot tables
-    (`core.strassen._slot_tables`): either evaluated in the Pallas fused
-    kernels' prologues (``fused_dot``/``fused_syrk``, one launch per level)
-    or as trace-time slice gathers on the XLA path — zero materialized
-    operand-add stacks either way. The decode side and the ``_TriNode``
-    assembly are shared verbatim with the batched path, so all three leaf
-    dispatches stay bitwise-equal (classical variant; tested).
     """
     if L == 0:
         return base_syrk(a)
     batch = a.shape[:-2]
-    _, dec = _encode_fns(variant)
+    enc, dec = _encode_fns(variant)
     R = 1 << L
     ab = _to_blocks(a, L)           # (R, R, *batch, mL, nL)
     mL, nL = ab.shape[-2:]
@@ -257,21 +208,10 @@ def _ata_level_sync(a, L, *, variant, base_syrk, base_dot,
     # down the remaining L-ℓ Strassen levels, then concatenated into ONE
     # leaf stack across all levels (every leaf has the same (mL, nL) shape).
     parts_a, parts_b, sizes = [], [], []
-    P_levels = [] if fused else None
     for lev in range(1, L + 1):
-      with obs.span("ata.encode", level=lev, fused=fused):
+      with obs.span("ata.encode", level=lev):
         Rl, H = 1 << lev, 1 << (lev - 1)
         q = R // Rl
-        if fused and fused_dot is None:
-            # XLA fallback: per-leaf combine + per-leaf dot (see
-            # `core.strassen._strassen_fused`) — only the product stack,
-            # the decode input, is materialized.
-            la, lb = _combine_level(a, L, lev, mL, nL)
-            P_levels.append(jnp.stack(
-                [base_dot(x, y) for x, y in zip(la, lb)]
-            ))
-            sizes.append(len(la))
-            continue
         # block rows grouped into the 2^ℓ slabs, block columns into
         # (parent i, left/right, q): operand (i, r) is a pure block slice
         g = ab.reshape(Rl, q, H, 2, q, *batch, mL, nL)
@@ -279,45 +219,27 @@ def _ata_level_sync(a, L, *, variant, base_syrk, base_dot,
         left = jnp.moveaxis(g[:, :, :, 0], 2, 0)
         A = right.reshape(H * Rl, q, q, *batch, mL, nL)
         B = left.reshape(H * Rl, q, q, *batch, mL, nL)
-        if fused:
-            # one fused Pallas launch per level: the ±1 combinations run in
-            # the kernel prologue against these block grids
-            with obs.span("ata.fused_dot", level=lev,
-                          leaves=A.shape[0] * 7 ** (L - lev)):
-                P_levels.append(fused_dot(A, B, _slot_tables(L - lev)))
-            sizes.append(A.shape[0] * 7 ** (L - lev))
-            continue
-        enc, _ = _encode_fns(variant)
         for _ in range(L - lev):
             A, B = enc(A, B)
         parts_a.append(A[:, 0, 0])  # grids collapsed to (1, 1): squeeze
         parts_b.append(B[:, 0, 0])
         sizes.append(A.shape[0])
-    if P_levels is None:
-        with obs.span("ata.leaf_dot", leaves=sum(sizes)):
-            P = _leaf_dot(
-                base_dot,
-                jnp.concatenate(parts_a, axis=0),
-                jnp.concatenate(parts_b, axis=0),
-            )
-        P_levels = []
-        off = 0
-        for size in sizes:
-            P_levels.append(P[off : off + size])
-            off += size
+    with obs.span("ata.leaf_dot", leaves=sum(sizes)):
+        P = _leaf_dot(
+            base_dot,
+            jnp.concatenate(parts_a, axis=0),
+            jnp.concatenate(parts_b, axis=0),
+        )
+    P_levels = []
+    off = 0
+    for size in sizes:
+        P_levels.append(P[off : off + size])
+        off += size
 
     # all diagonal leaves as one batched syrk, ordered (column block i, slab r)
-    with obs.span("ata.syrk_batch", leaves=R * R, fused=fused):
-        if fused and fused_syrk is not None:
-            # gather prologue: the kernel pulls each slab straight out of the
-            # block-major layout by its (row, col) index table — no copy of D
-            import numpy as np
-
-            s = np.arange(R * R, dtype=np.int32)
-            Dp = fused_syrk(ab, s % R, s // R)
-        else:
-            D = jnp.swapaxes(ab, 0, 1).reshape(R * R, *batch, mL, nL)
-            Dp = base_syrk(D.reshape(-1, mL, nL))
+    with obs.span("ata.syrk_batch", leaves=R * R):
+        D = jnp.swapaxes(ab, 0, 1).reshape(R * R, *batch, mL, nL)
+        Dp = base_syrk(D.reshape(-1, mL, nL))
     Dp = Dp.reshape(R, R, *batch, *Dp.shape[-2:])
     diag = _accum_axis1(Dp)  # (2^L, *batch, nL, nL)
 
@@ -447,15 +369,6 @@ def _ata_impl(
     )
     if variant not in ("strassen", "winograd"):
         raise ValueError(f"unknown variant {variant!r}")
-    if leaf_dispatch == "fused" and variant != "strassen":
-        raise ValueError(
-            "leaf_dispatch='fused' supports variant='strassen' only: "
-            "Winograd's chained within-level combinations do not fit the "
-            "per-leaf ±1 slot tables (see DESIGN.md §2)"
-        )
-    fused_syrk = fused_dot_kernel = None
-    if leaf_dispatch == "fused" and base_syrk is None and base_dot is None:
-        fused_syrk, fused_dot_kernel = _plan_fused_fns(plan)
     base_syrk, base_dot = _plan_base_fns(plan, base_syrk, base_dot)
     if base_syrk is None:
         base_syrk = functools.partial(_syrk_base, acc_dtype=acc_dtype)
@@ -465,7 +378,7 @@ def _ata_impl(
     n = a.shape[-1]
     L = tree_depth(a.shape[-2:], n_base)
     obs.metrics.inc(f"dispatch.ata.{leaf_dispatch}")
-    # leaf accounting, identical across the three dispatches (the tree is a
+    # leaf accounting, identical across the two dispatches (the tree is a
     # function of L only): 4^L diagonal syrk leaves, Σ_ℓ 2^{2ℓ-1}·7^{L-ℓ}
     # off-diagonal Strassen leaves — what cost.dispatch_calls predicts.
     obs.metrics.inc("ata.leaves.syrk", 4 ** L)
@@ -478,11 +391,9 @@ def _ata_impl(
     ):
         with obs.span("ata.pad"):
             ap = _pad_root(a, L) if L else a
-        if leaf_dispatch in ("batched", "fused"):
+        if leaf_dispatch == "batched":
             node = _ata_level_sync(
                 ap, L, variant=variant, base_syrk=base_syrk, base_dot=base_dot,
-                fused=leaf_dispatch == "fused",
-                fused_syrk=fused_syrk, fused_dot=fused_dot_kernel,
             )
         else:
             strassen_rec = _rec_strassen if variant == "strassen" else _rec_winograd
@@ -555,13 +466,10 @@ def ata(
       variant: Strassen variant for the C21 off-diagonal products —
         ``'strassen'`` (paper-faithful) or ``'winograd'`` (beyond-paper,
         15 adds).
-      leaf_dispatch: ``'unrolled'`` (one op per leaf), ``'batched'``
+      leaf_dispatch: ``'unrolled'`` (one op per leaf) or ``'batched'``
         (level-synchronous: ONE batched syrk for all diagonal leaves + ONE
         batched TN dot for every Strassen leaf — bitwise-equal result,
-        O(levels) jaxpr), or ``'fused'`` (the level-synchronous tree with
-        per-leaf ±1 coefficient tables instead of encode stacks — zero
-        materialized operand combinations, bitwise-equal result; classical
-        variant only). Defaults to the plan's choice; pinning it alone
+        O(levels) jaxpr). Defaults to the plan's choice; pinning it alone
         does not bypass the planner (it never changes values).
       base_syrk: base-case ``f(a) -> aᵀa`` (full, bitwise-symmetric tile).
         Defaults to a TN dot_general (or the plan's Pallas kernel); pass

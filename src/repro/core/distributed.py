@@ -23,9 +23,7 @@ jax-native SPMD (see DESIGN.md §2):
   leaf-level computation") — including the level-synchronous
   ``leaf_dispatch='batched'`` formulation when the plan picks it, so each
   device's tile products cost O(levels) dispatched ops, not O(7^L)
-  (DESIGN.md §4), and the fused-operand ``'fused'`` dispatch, whose ±1
-  leaf combinations never materialize an operand stack in any per-device
-  body (DESIGN.md §2). Partial sums over a ``row_axis`` (if A is also
+  (DESIGN.md §4). Partial sums over a ``row_axis`` (if A is also
   row-sharded — the ATA-D two-level layout) are combined with a single
   ``psum`` **of the packed tile stack** — ``T·w² ≈ n²/2`` words instead of
   the dense ``n²``, reproducing the paper's packed-low(C) retrieval saving
@@ -110,9 +108,8 @@ def gram_rowshard(
     paper's 2/3-Strassen flop saving applies on every chip. Tunables resolve
     through the planner (`repro.tune.plan` on the local shape) unless pinned
     — including ``leaf_dispatch``: the per-device body reuses the batched
-    or fused leaf formulation when the plan (or the caller) asks for it, so
-    the SPMD schedule inherits the O(levels)-jaxpr win per shard (and, for
-    ``'fused'``, the zero-operand-stack leaf combine). ``use_ata=False``
+    leaf formulation when the plan (or the caller) asks for it, so the
+    SPMD schedule inherits the O(levels)-jaxpr win per shard. ``use_ata=False``
     — or a plan whose algorithm is ``'dense'`` — falls back to the
     classical one-dot gram.
 
@@ -409,18 +406,14 @@ def ata_tile_parallel(
         branch supplies the stripe tiling; ``n_base``/``variant``/
         ``leaf_dispatch`` feed the leaf-level Strassen of every per-device
         tile body — a batched plan runs each device's tile products through
-        the level-synchronous one-dot-per-tile dispatch, a fused plan
-        through the coefficient-table combine with no operand stacks; a
-        kernel plan whose tiles are one leaf computes a device's whole
+        the level-synchronous one-dot-per-tile dispatch; a kernel plan whose tiles are one leaf computes a device's whole
         stack in one fused launch, see :func:`ata_bfs_dfs`). Default: the
         planner front door with ``devices=p_task`` and the requested
         ``out`` — packed plans snap ``tile_w`` to the packed block grid so
         retrieval is a pure slice.
       leaf_dispatch: explicit override of the plan's leaf dispatch for the
-        per-device Strassen bodies (``'unrolled'``/``'batched'``/``'fused'``
-        — values are bitwise-identical in every case; ``'fused'`` requires
-        the classical variant, so pin ``variant='strassen'`` alongside it
-        if the resolved plan picked winograd).
+        per-device Strassen bodies (``'unrolled'``/``'batched'`` — values
+        are bitwise-identical in either case).
       nb: stripe count override (default: the plan / :func:`choose_tiling`).
       out: ``'dense'`` → replicated ``(n, n)`` array, assembled as
         ``packed.to_dense()`` at the root (one mirror, at the conversion
@@ -701,7 +694,7 @@ def ata_bfs_dfs(
     ``distributed.stripes``); dummy slots are dead leaves (all-zero signs),
     whose dot the kernel skips. Otherwise each tile cuts its two stripes and dispatches through
     the planned sequential machinery (``strassen_tn`` with the plan's
-    unrolled/batched/fused leaf body). Both give bitwise-equal tiles.
+    unrolled/batched leaf body). Both give bitwise-equal tiles.
 
     Communication: any BFS level switches the root exchange to the
     **tri-direct reduce-scatter** — every device stages its partial tiles
@@ -954,8 +947,7 @@ def gemm_tn_colshard(
     """Distributed ``C = AᵀB``: each device owns C's column stripe for its
     B shard — the FastStrassen leaves of the task tree, collision-free.
     Leaf tunables (including ``leaf_dispatch`` — the per-device stripe
-    product reuses the batched or fused leaf formulation when the plan
-    picks it) resolve through the planner unless pinned."""
+    product reuses the batched leaf formulation when the plan picks it) resolve through the planner unless pinned."""
     m, n = a.shape
     mb, k = b.shape
     if m != mb:

@@ -26,10 +26,10 @@ This is the paper's FastStrassen (Algorithm 1, lines 14-18) adapted to JAX/TPU:
   ``'winograd'`` (beyond-paper: 7 mults, 15 adds; lowers the memory roofline
   term).
 
-* **Leaf dispatch** — three formulations of the same arithmetic
+* **Leaf dispatch** — two formulations of the same arithmetic
   (``leaf_dispatch`` on the plan, DESIGN.md §2):
 
-  - ``'unrolled'`` (legacy): the recursion emits one ``base_dot`` per leaf —
+  - ``'unrolled'``: the recursion emits one ``base_dot`` per leaf —
     ``7^L`` separate dots in the jaxpr.
   - ``'batched'``: an iterative, level-synchronous schedule. Each level
     *encodes* Strassen's ±1 operand combinations into a stacked tensor with a
@@ -38,13 +38,6 @@ This is the paper's FastStrassen (Algorithm 1, lines 14-18) adapted to JAX/TPU:
     the result is *decoded* level-by-level (the c11..c22 recombinations on
     stacks, quadrant concatenation). O(L) ops in the jaxpr instead of
     O(7^L); bitwise-equal to the unrolled form (tested).
-  - ``'fused'``: no materialized operand combinations at all. Each leaf
-    operand is described by a per-leaf ±1 *slot table* over the root
-    leaf-block grid (built at trace time); the combinations are either
-    folded into the Pallas leaf kernel's prologue (coefficient tables ride
-    in as scalar-prefetch operands) or built as trace-time slice gathers on
-    the XLA path. One leaf launch, shared decode, bitwise-equal to the
-    other two (tested); classical variant only.
 
 * **Base case** — recursion cuts off when any dimension ≤ ``n_base`` and hands
   the tile to ``base_dot`` (default: MXU-dense ``dot_general``; the Pallas
@@ -61,7 +54,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro import obs
 from repro.core.precision import dot_precision
@@ -129,10 +121,9 @@ def resolve_tunables(
         )
     if leaf_dispatch is None:
         leaf_dispatch = _defaults.DEFAULT_LEAF_DISPATCH
-    if leaf_dispatch not in ("unrolled", "batched", "fused"):
+    if leaf_dispatch not in ("unrolled", "batched"):
         raise ValueError(
-            f"unknown leaf_dispatch {leaf_dispatch!r}; "
-            "use 'unrolled', 'batched' or 'fused'"
+            f"unknown leaf_dispatch {leaf_dispatch!r}; use 'unrolled' or 'batched'"
         )
     return plan, n_base, variant, packed_block, leaf_dispatch
 
@@ -144,16 +135,6 @@ def _plan_base_fns(plan, base_syrk, base_dot):
 
         return base_fns(plan)
     return base_syrk, base_dot
-
-
-def _plan_fused_fns(plan):
-    """(fused_syrk, fused_dot) Pallas fused leaf launches per the plan —
-    ``(None, None)`` keeps the XLA trace-time gather path."""
-    if plan is not None and plan.use_kernels:
-        from repro.tune.apply import fused_fns
-
-        return fused_fns(plan)
-    return None, None
 
 
 def _dot_tn(a, b, acc_dtype):
@@ -246,10 +227,9 @@ def _rec_strassen(a, b, n_base, base_dot, acc_dtype):
         ]
     m1, m2, m3, m4, m5, m6, m7 = (rec(x, y) for x, y in operands)
 
-    # Balanced association (not the textbook left-to-right chain): the fused
-    # leaf dispatch evaluates its per-leaf slot tables as perfect binary add
-    # trees, and keeping every dispatch on the same association keeps the
-    # three of them bitwise-equal.
+    # Balanced association (not the textbook left-to-right chain), shared
+    # with `_decode_strassen`: the same association keeps the unrolled and
+    # batched dispatches bitwise-equal.
     with obs.span("strassen.decode", n=n):
         c11 = (m1 + m4) + (m7 - m5)
         c12 = m3 + m5
@@ -464,158 +444,6 @@ def _strassen_batched(a, b, L, base_dot, variant):
     return _unblock(P)[0]
 
 
-# ---------------------------------------------------------------------------
-# fused leaf dispatch: per-leaf ±1 coefficient tables, zero operand stacks
-#
-# The batched dispatch materializes every encode level as a (7^ℓ, …) stack
-# that the next level re-reads — the 2.0-words/add traffic the cost model
-# charges it for. The fused dispatch never materializes an operand
-# combination: each of the 7^L leaf operands is described by a *slot table*
-# of 2^L (row, col, sign) entries over the root leaf-block grid
-# (`_to_blocks` coordinates), built at trace time by mirroring
-# `_encode_strassen` symbolically:
-#
-#   * two-term combination  x + σ·y  → concat slots(x) ++ σ·slots(y)
-#   * single-term copy      x        → concat slots(x) ++ zero slots
-#
-# so slot k of a leaf operand is the coefficient of root block
-# (rows[k], cols[k]) and the *position* of k encodes where that block sits
-# in the unrolled recursion's add tree: evaluating the slots as a perfect
-# binary tree (level-1 adds innermost, level-L outermost; zero slots drop
-# out symbolically at trace time) reproduces the unrolled operand
-# combinations bitwise — x−y ≡ x+(−y) and −(x+y) ≡ (−x)+(−y) are IEEE-754
-# identities, and the quadrant slicing commutes with the elementwise adds.
-#
-# The tables are tiny (7^L · 2^L · 3 ints per operand side) and static, so
-# they ride into the Pallas kernels as scalar-prefetch operands (the
-# coefficient-table contract in `repro.kernels`); the XLA fallback gathers
-# the blocks as plain slices of the original operand — no block-major
-# transpose is ever materialized on that path. Only the classical variant
-# has the one-add-per-level structure the slot encoding needs: Winograd's
-# chained within-level combinations (s2 = s1 − a11, …) would square the
-# table width per level, so `leaf_dispatch='fused'` requires
-# `variant='strassen'`.
-# ---------------------------------------------------------------------------
-
-_FUSED_A_COMBOS = ((0, 3, 1), (1, 3, 1), (0, None, 0), (3, None, 0),
-                   (0, 2, 1), (1, 0, -1), (2, 3, -1))
-_FUSED_B_COMBOS = ((0, 3, 1), (0, None, 0), (1, 3, -1), (2, 0, -1),
-                   (3, None, 0), (0, 1, 1), (2, 3, 1))
-
-
-@functools.lru_cache(maxsize=None)
-def _slot_tables(L: int):
-    """Per-leaf ±1 coefficient tables of the fused dispatch.
-
-    Returns ``((a_rows, a_cols, a_sgn), (b_rows, b_cols, b_sgn))`` — six
-    ``(7**L, 2**L)`` int32 arrays. Row ``s`` describes leaf product ``s``
-    (same leaf ordering as ``_stack7``: level-1 digit is the most
-    significant base-7 digit); sign 0 marks a dead slot.
-    """
-
-    def build(combos):
-        R = 1 << L
-        r, c = np.indices((R, R))
-        # (S, rows, cols, slots, {row, col, sign}) — starts as the identity
-        slots = np.stack([r, c, np.ones((R, R), np.int64)], axis=-1)
-        slots = slots[None, :, :, None, :]
-        for _ in range(L):
-            S, Rg, Cg, W, _ = slots.shape
-            h, w = Rg // 2, Cg // 2
-            quad = (slots[:, :h, :w], slots[:, :h, w:],
-                    slots[:, h:, :w], slots[:, h:, w:])
-            parts = []
-            for p, q, sg in combos:
-                first = quad[p]
-                if q is None:
-                    second = np.zeros_like(first)
-                else:
-                    second = quad[q].copy()
-                    second[..., 2] *= sg
-                parts.append(np.concatenate([first, second], axis=3))
-            slots = np.stack(parts, axis=1).reshape(S * 7, h, w, 2 * W, 3)
-        slots = slots[:, 0, 0]
-        return (slots[..., 0].astype(np.int32),
-                slots[..., 1].astype(np.int32),
-                slots[..., 2].astype(np.int32))
-
-    return build(_FUSED_A_COMBOS), build(_FUSED_B_COMBOS)
-
-
-def _combine_slots(get_block, rows, cols, sgn):
-    """One leaf operand from its slot table: the perfect binary add tree of
-    the unrolled recursion. ``get_block(r, c)`` fetches root leaf block
-    (r, c); dead (sign-0) slots drop out at trace time, so the jaxpr holds
-    exactly the adds the unrolled recursion performs on this operand."""
-
-    def ev(lo, hi):
-        if hi - lo == 1:
-            s = int(sgn[lo])
-            if s == 0:
-                return None
-            blk = get_block(int(rows[lo]), int(cols[lo]))
-            return -blk if s < 0 else blk
-        mid = (lo + hi) // 2
-        left, right = ev(lo, mid), ev(mid, hi)
-        if left is None:
-            return right
-        if right is None:
-            return left
-        return left + right
-
-    return ev(0, len(sgn))
-
-
-def _block_getter(x, L):
-    """Leaf-block fetcher in `_to_blocks` coordinates, as direct slices of
-    the unblocked operand — the XLA fused path never materializes the
-    block-major transpose."""
-    mb, nb = x.shape[-2] >> L, x.shape[-1] >> L
-
-    def get(r, c):
-        return x[..., r * mb:(r + 1) * mb, c * nb:(c + 1) * nb]
-
-    return get
-
-
-def _strassen_fused(a, b, L, base_dot, fused_dot=None):
-    """Fused-operand Strassen: slot-table gather+combine per leaf, one leaf
-    launch, shared balanced decode. Operands arrive root-padded.
-
-    With ``fused_dot`` (the Pallas fused kernel, `kernels.ops.gemm_tn_fused`)
-    the gather+combine runs in the kernel prologue against the block-major
-    layout; otherwise the combinations are built as trace-time slice
-    gathers and the leaf stack feeds one batched ``base_dot``.
-    """
-    if L == 0:
-        return base_dot(a, b)
-    (ar, ac, asg), (br, bc, bsg) = _slot_tables(L)
-    with obs.span("strassen.fused_leaves", leaves=7 ** L,
-                  kernel=fused_dot is not None):
-        if fused_dot is not None:
-            # the Pallas fused launch: gather+combine happens in the kernel
-            # prologue against the block-major layout (one leading group here)
-            P = fused_dot(_to_blocks(a, L)[None], _to_blocks(b, L)[None],
-                          _slot_tables(L))
-        else:
-            # XLA fallback: per-leaf combine + per-leaf dot. Stacking the
-            # combined operands for one batched dot would just rebuild the
-            # operand stack the fused dispatch exists to avoid (and XLA:CPU
-            # runs a leading batch dim slower than the same dots unbatched);
-            # only the product stack — the decode input — is materialized.
-            ga, gb = _block_getter(a, L), _block_getter(b, L)
-            P = jnp.stack([
-                base_dot(_combine_slots(ga, ar[s], ac[s], asg[s]),
-                         _combine_slots(gb, br[s], bc[s], bsg[s]))
-                for s in range(7 ** L)
-            ])
-    P = P[:, None, None]
-    for lev in range(L, 0, -1):
-        with obs.span("strassen.decode", level=lev):
-            P = _decode_strassen(P)
-    return _unblock(P)[0]
-
-
 def strassen_tn(
     a: jax.Array,
     b: jax.Array,
@@ -644,13 +472,11 @@ def strassen_tn(
       n_base: recursion cutoff — any dim ≤ n_base goes to the base matmul.
         Pinning this (or ``variant``) manually bypasses the planner.
       variant: ``'strassen'`` (paper-faithful) or ``'winograd'`` (15 adds).
-      leaf_dispatch: ``'unrolled'`` (one dot per leaf, legacy),
-        ``'batched'`` (level-synchronous: every leaf of the tree in one
-        batched TN dot — bitwise-equal output, O(levels) jaxpr), or
-        ``'fused'`` (per-leaf ±1 coefficient tables folded into the leaf
-        launch — zero materialized operand-add stacks; classical variant
-        only). Defaults to the plan's choice; does not bypass the planner
-        when pinned alone (it never changes values).
+      leaf_dispatch: ``'unrolled'`` (one dot per leaf) or ``'batched'``
+        (level-synchronous: every leaf of the tree in one batched TN dot —
+        bitwise-equal output, O(levels) jaxpr). Defaults to the plan's
+        choice; does not bypass the planner when pinned alone (it never
+        changes values).
       base_dot: base-case TN matmul ``f(a, b) -> aᵀb``. Defaults to a TN
         ``dot_general`` (MXU-native; the plan may swap in the Pallas
         ``gemm_tn`` kernel). Pass ``repro.kernels.ops.gemm_tn`` explicitly
@@ -677,17 +503,8 @@ def strassen_tn(
     )
     if variant not in ("strassen", "winograd"):
         raise ValueError(f"unknown variant {variant!r}")
-    if leaf_dispatch == "fused" and variant != "strassen":
-        raise ValueError(
-            "leaf_dispatch='fused' supports variant='strassen' only: "
-            "Winograd's chained within-level combinations do not fit the "
-            "per-leaf ±1 slot tables (see DESIGN.md §2)"
-        )
-    fused_dot = None
     if base_dot is None:
         _, base_dot = _plan_base_fns(plan, None, base_dot)
-        if leaf_dispatch == "fused":
-            _, fused_dot = _plan_fused_fns(plan)
     if base_dot is None:
         base_dot = functools.partial(_dot_tn, acc_dtype=acc_dtype)
 
@@ -706,8 +523,6 @@ def strassen_tn(
             b = _pad_root(b, L)
         if leaf_dispatch == "batched":
             out = _strassen_batched(a, b, L, base_dot, variant)
-        elif leaf_dispatch == "fused":
-            out = _strassen_fused(a, b, L, base_dot, fused_dot)
         else:
             rec = _rec_strassen if variant == "strassen" else _rec_winograd
             out = rec(a, b, n_base=n_base, base_dot=base_dot, acc_dtype=acc_dtype)

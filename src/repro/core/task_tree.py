@@ -23,9 +23,8 @@ Two expansion modes mirror the paper:
 
 The SPMD executor (`repro.core.distributed`) uses a shape-uniform
 block-cyclic realization of the same disjoint-task principle (see its
-docstring); this module is the faithful model — used for tests, the
-analytic speedup benchmarks (paper Fig. 5/6), and for choosing stripe
-widths.
+docstring); this module is the faithful model — used for tests and for
+choosing stripe widths.
 """
 
 from __future__ import annotations

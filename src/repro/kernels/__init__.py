@@ -34,31 +34,21 @@ wrappers (``repro.kernels.ops``):
   batch dims × panel rows — as ONE ``trsm`` launch, and a batched stat
   stack's diagonal tiles as ONE ``potrf`` launch.
 
-* **Coefficient tables** (fused-operand leaves): the fused leaf launches
-  (``ops.gemm_tn_fused``, ``ops.syrk_gather`` — the
-  ``Plan.leaf_dispatch='fused'`` engines) take their operands in the
-  block-major leaf-grid layout of ``core.strassen._to_blocks`` plus
-  per-leaf int32 ``(rows, cols, sign)`` slot tables
-  (``core.strassen._slot_tables``), passed as scalar-prefetch operands.
-  The kernel PROLOGUE gathers each slot block through the tables in its
-  index maps and combines them as the recursion's balanced ± add tree
-  before the MXU dot; the epilogue writes one product per leaf into the
-  level's decode stack. No operand-combination stack is ever materialized
-  in HBM — the combine traffic the batched dispatch pays simply does not
-  exist (machine-checked: the ``no-operand-stacks`` rule of ``repro.check``
-  flags any 7-multiple leaf-operand stack in a fused-dispatch jaxpr). The blocked dot inside (chunk shapes, contraction order, f32
-  VMEM accumulation, flush cast) is identical to the unbatched kernels',
-  which is what keeps all three leaf dispatches bitwise-equal for f32/f64
-  operands (sub-f32 operands forfeit bitwise: the in-kernel combine feeds
-  the dot inside one XLA computation, where float normalization may keep
-  bf16 adds at f32 precision — more accurate than the trace-time gather,
-  which rounds at the pallas input boundary); sign-0
-  (dead) slots contribute an exact ±0 instead of being dropped, so the
-  fused launch is value-equal to the trace-time gather (it may flip the
-  sign of a zero — invisible to ``==``). A leaf whose A slots or B slots
-  are all dead skips its dot and returns exact zeros (the distributed tile
-  stack's dummy slots; Strassen's tables hold none). Same kernel body for
-  Mosaic and interpret mode, like everything else here.
+* **Coefficient tables** (``ops.gemm_tn_fused``): the table-addressed
+  leaf launch takes its operands in the block-major leaf-grid layout of
+  ``core.strassen._to_blocks`` plus per-leaf int32 ``(rows, cols, sign)``
+  slot tables, passed as scalar-prefetch operands. The kernel PROLOGUE
+  gathers each slot block through the tables in its index maps and
+  combines them as a balanced ± add tree before the MXU dot; the epilogue
+  writes one product per leaf. The blocked dot inside (chunk shapes,
+  contraction order, f32 VMEM accumulation, flush cast) is identical to
+  ``gemm_tn``'s, so a one-slot leaf of sign 1 is bitwise ``gemm_tn`` on
+  its cut blocks. A leaf whose A slots or B slots are all dead (sign 0)
+  skips its dot and returns exact zeros. Its one caller is the distributed
+  tile stack (``core.distributed._direct_stack``): one one-slot leaf per
+  tile, reading stripe ``i`` as A and stripe ``j`` as B of a stripe-major
+  copy of the device's A shard, and a dead leaf per dummy slot. Same
+  kernel body for Mosaic and interpret mode, like everything else here.
 
 ``ops`` holds the jit'd public wrappers; ``ref`` holds the pure-jnp oracles
 used by the kernel test sweeps.

@@ -143,31 +143,33 @@ def gemm_tn_pallas(
 
 
 # ---------------------------------------------------------------------------
-# fused-operand leaf launch (leaf_dispatch='fused')
+# table-addressed leaf launch (the distributed tile stack)
 #
 # Per the repro.kernels coefficient-table contract: the operands arrive in
-# the block-major leaf-grid layout of `core.strassen._to_blocks` and the
-# per-leaf ±1 combinations run in the PROLOGUE of this kernel, against the
-# prefetched slot tables — no operand-combination stack is ever written to
-# HBM. Each slot is one input ref (the same operand array passed W times
-# with a per-slot index map off the prefetched (row, col) tables); the body
-# combines them as the same balanced add tree as the trace-time paths
-# (sign-0 slots contribute an exact ±0 instead of being dropped — value-
-# equal), then runs the identical blocked TN dot as `_gemm_tn_kernel`:
-# same (bm, bn)×(bm, bk) chunk shapes, same minor-most contraction order,
-# same f32 VMEM accumulation — which is what keeps the fused launch
-# bitwise-equal to the unrolled per-leaf kernel calls.
+# the block-major leaf-grid layout of `core.strassen._to_blocks` and each
+# leaf's ±1 combination of slot blocks runs in the PROLOGUE of this kernel,
+# against the prefetched slot tables. Each slot is one input ref (the same
+# operand array passed W times with a per-slot index map off the prefetched
+# (row, col) tables); the body combines them as a balanced binary add tree
+# (sign-0 slots contribute an exact ±0), then runs the identical blocked TN
+# dot as `_gemm_tn_kernel`: same (bm, bn)×(bm, bk) chunk shapes, same
+# minor-most contraction order, same f32 VMEM accumulation — which is what
+# keeps a one-slot, sign-1 leaf bitwise-equal to `gemm_tn_pallas` on the
+# cut blocks.
 #
-# A dead leaf — one whose A slots or whose B slots all carry sign 0 — skips
-# its dot and flushes the zeroed accumulator: exact zeros, no MXU work (its
-# blocks are still fetched). Strassen's tables never hold one.
+# Its one caller, `core.distributed._direct_stack`, builds one-slot leaves:
+# leaf q reads stripe i as A and stripe j as B of a stripe-major copy of a
+# device's A shard. A dead leaf — one whose A slots or whose B slots all
+# carry sign 0 (the stack's dummy slots) — skips its dot and flushes the
+# zeroed accumulator: exact zeros, no MXU work (its blocks are still
+# fetched).
 # ---------------------------------------------------------------------------
 
 
 def _gemm_tn_fused_kernel(
     ar, ac, asg, br, bc, bsg, *refs, w: int, alpha: float, t_axis: int, l_axis: int
 ):
-    """One ([g, t, b,] i, j, l) grid step of the fused leaf launch:
+    """One ([g, t, b,] i, j, l) grid step of the table-addressed launch:
     acc += combine(A slots)ᵀ · combine(B slots)."""
     del ar, ac, br, bc  # consumed by the index maps
     a_refs, b_refs = refs[:w], refs[w : 2 * w]
@@ -179,9 +181,9 @@ def _gemm_tn_fused_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def combine(slot_refs, sgn, lo, hi):
-        # the balanced slot tree of `core.strassen._combine_slots`, with
-        # runtime ±1/0 signs (a sign multiply is exact; adding the ±0 of a
-        # dead slot is exact for every non-zero partial sum)
+        # a balanced binary tree over the slots, with runtime ±1/0 signs
+        # (a sign multiply is exact; adding the ±0 of a dead slot is exact
+        # for every non-zero partial sum)
         if hi - lo == 1:
             x = slot_refs[lo][...].reshape(slot_refs[lo].shape[-2:])
             return sgn[t, lo].astype(x.dtype) * x
@@ -223,16 +225,18 @@ def gemm_tn_fused_pallas(
     interpret: bool = False,
     out_dtype=jnp.float32,
 ) -> jax.Array:
-    """Fused-operand Strassen leaf launch ``P[g·T+t] = alpha·Â(g,t)ᵀB̂(g,t)``.
+    """Table-addressed leaf launch ``P[g·T+t] = alpha·Â(g,t)ᵀB̂(g,t)``.
 
     ``a_blocks``: ``(G, R, C, [B,] mb, n)`` block-major leaf grids
     (`core.strassen._to_blocks` layout, ``G`` independent groups);
     ``b_blocks`` the same with trailing ``(mb, k)``. ``tables`` =
     ``((a_rows, a_cols, a_sgn), (b_rows, b_cols, b_sgn))``, six ``(T, W)``
-    int32 arrays (`core.strassen._slot_tables`): leaf operand ``Â(g, t)``
-    is the signed sum of blocks ``a_blocks[g, a_rows[t, w], a_cols[t, w]]``
-    over the ``W`` slots. One launch computes all ``G·T`` leaf products —
-    the ± combinations run in the kernel prologue, nothing is materialized.
+    int32 arrays: leaf operand ``Â(g, t)`` is the signed sum of blocks
+    ``a_blocks[g, a_rows[t, w], a_cols[t, w]]`` over the ``W`` slots. One
+    launch computes all ``G·T`` leaf products — the ± combinations run in
+    the kernel prologue, nothing is materialized. The distributed tile
+    stack (`core.distributed._direct_stack`) is its one caller, with one
+    slot per leaf.
     A leaf whose A signs or whose B signs are all 0 is dead: its product is
     exact zeros and its dot never runs. The tables may be traced, and may
     vary over mesh axes the block grids do not (the result varies over
@@ -258,7 +262,7 @@ def gemm_tn_fused_pallas(
     k = b_blocks.shape[-1]
     bm, bn, bk = blocks
     # the same clamp rule as `gemm_tn_pallas` on one leaf's (m, n, k) —
-    # identical chunking is what makes fused bitwise-equal to unrolled
+    # identical chunking is what makes a leaf bitwise-equal to it
     bm = min(bm, max(8, -(-m // 8) * 8))
     bn = min(bn, max(128, -(-n // 128) * 128))
     bk = min(bk, max(128, -(-k // 128) * 128))

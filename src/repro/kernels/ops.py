@@ -21,11 +21,11 @@ from repro.kernels.gemm_tn import DEFAULT_BLOCKS as GEMM_BLOCKS
 from repro.kernels.gemm_tn import gemm_tn_fused_pallas, gemm_tn_pallas
 from repro.kernels.potrf import potrf_pallas
 from repro.kernels.syrk import DEFAULT_BLOCKS as SYRK_BLOCKS
-from repro.kernels.syrk import syrk_gather_pallas, syrk_pallas
+from repro.kernels.syrk import syrk_pallas
 from repro.kernels.trsm import trsm_pallas
 
 __all__ = [
-    "syrk", "gemm_tn", "gemm_tn_fused", "syrk_gather", "potrf", "trsm",
+    "syrk", "gemm_tn", "gemm_tn_fused", "potrf", "trsm",
     "interpret_default",
 ]
 
@@ -123,14 +123,15 @@ def gemm_tn_fused(
     interpret=None,
     out_dtype=jnp.float32,
 ):
-    """All ``G·T`` fused-operand Strassen leaf products in ONE launch.
+    """All ``G·T`` table-addressed leaf products in ONE launch.
 
-    The ``leaf_dispatch='fused'`` leaf engine (the ``repro.kernels``
-    coefficient-table contract): ``a_blocks``/``b_blocks`` are block-major
-    leaf grids (`core.strassen._to_blocks`), ``tables`` the per-leaf
-    ``(rows, cols, sign)`` slot tables (`core.strassen._slot_tables`); the
-    ±1 combinations run in the kernel prologue against the prefetched
-    tables — zero materialized operand stacks. Blocks from the argument,
+    The ``repro.kernels`` coefficient-table contract: ``a_blocks``/
+    ``b_blocks`` are block-major leaf grids (`core.strassen._to_blocks`'s
+    layout), ``tables`` the per-leaf ``(rows, cols, sign)`` slot tables;
+    each leaf's operands are gathered and combined in the kernel prologue
+    against the prefetched tables. Its one caller is the distributed tile
+    stack (`core.distributed._direct_stack`), whose one-slot leaves read
+    stripe ``i`` as A and stripe ``j`` as B. Blocks from the argument,
     else the ``plan``, else the tuned defaults; ``interpret=None``
     resolves via :func:`interpret_default`.
     """
@@ -146,43 +147,6 @@ def gemm_tn_fused(
             tables,
             alpha=alpha,
             blocks=tuple(blocks or GEMM_BLOCKS),
-            interpret=interpret,
-            out_dtype=out_dtype,
-        )
-
-
-def syrk_gather(
-    a_blocks,
-    rows,
-    cols,
-    *,
-    alpha: float = 1.0,
-    blocks=None,
-    plan=None,
-    interpret=None,
-    out_dtype=jnp.float32,
-):
-    """All gathered diagonal leaves ``a_blocks[rows[s], cols[s]]ᵀ·(…)`` in
-    ONE launch (dense output stack).
-
-    The diagonal half of the fused dispatch's coefficient-table contract:
-    the gather indices feed the kernel's index maps, so the ``(4^L, …)``
-    diagonal slab stack of the batched dispatch is never materialized.
-    Blocks from the argument, else the ``plan``, else the tuned defaults;
-    ``interpret=None`` resolves via :func:`interpret_default`.
-    """
-    if interpret is None:
-        interpret = interpret_default()
-    if blocks is None and plan is not None:
-        blocks = plan.syrk_blocks
-    obs.metrics.inc("kernels.launch.syrk_gather")
-    with obs.span("kernels.syrk_gather", interpret=interpret):
-        return syrk_gather_pallas(
-            a_blocks,
-            rows,
-            cols,
-            alpha=alpha,
-            blocks=tuple(blocks or SYRK_BLOCKS),
             interpret=interpret,
             out_dtype=out_dtype,
         )

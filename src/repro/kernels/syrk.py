@@ -58,7 +58,7 @@ from repro.kernels.shapes import out_struct
 # autotuner sweeps alternatives per shape (repro.tune.plan → syrk_blocks).
 from repro.tune.defaults import SYRK_BLOCKS as DEFAULT_BLOCKS
 
-__all__ = ["syrk_pallas", "syrk_gather_pallas", "DEFAULT_BLOCKS"]
+__all__ = ["syrk_pallas", "DEFAULT_BLOCKS"]
 
 
 def _tri_coords(t):
@@ -236,111 +236,4 @@ def syrk_pallas(
 
     if out == "packed":
         return SymmetricMatrix(raw, n=n, bn=bn)
-    return raw[..., :n, :n]
-
-
-# ---------------------------------------------------------------------------
-# gathered diagonal-leaf launch (leaf_dispatch='fused')
-#
-# Per the repro.kernels coefficient-table contract: the ATA recursion's
-# fused dispatch hands this kernel the block-major leaf grid of
-# `core.strassen._to_blocks` plus prefetched (row, col) index tables, and
-# the PROLOGUE's index maps pull each diagonal slab straight out of the
-# grid — the `(4^L, …)` gathered stack of the batched dispatch is never
-# materialized. The grid, kernel body (`_syrk_kernel`, dense dual-write)
-# and block clamps are identical to `syrk_pallas` on the equivalent
-# stacked input, which keeps the fused diagonal bitwise-equal to the
-# batched one. Diagonal coefficients are trivially +1, so the tables here
-# are pure gather indices — the ± structure lives in the gemm twin
-# (`gemm_tn_fused_pallas`).
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(
-    jax.jit, static_argnames=("alpha", "blocks", "interpret", "out_dtype")
-)
-def syrk_gather_pallas(
-    a_blocks: jax.Array,
-    rows: jax.Array,
-    cols: jax.Array,
-    *,
-    alpha: float = 1.0,
-    blocks: tuple = DEFAULT_BLOCKS,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-):
-    """``C[s] = alpha·ÂᵀÂ`` with ``Â = a_blocks[rows[s], cols[s]]``.
-
-    ``a_blocks``: ``(R, C, [B,] mL, nL)`` block-major leaf grid;
-    ``rows``/``cols``: ``(S,)`` int32 gather tables. Returns the dense
-    ``(S, [B,] nL, nL)`` stack — one launch for every diagonal leaf, the
-    gather running in the kernel's index maps.
-    """
-    if a_blocks.ndim not in (4, 5):
-        raise ValueError(f"bad gathered block grid: {a_blocks.shape}")
-    batched = a_blocks.ndim == 5
-    s_count = rows.shape[0]
-    m, n = a_blocks.shape[-2:]
-    bm, bn = blocks
-    # the same clamp rule as `syrk_pallas` dense mode on one (mL, nL) leaf
-    bm = min(bm, max(8, -(-m // 8) * 8))
-    bn = min(bn, max(128, -(-n // 128) * 128))
-
-    a_blocks = _pad_to(a_blocks, bm, bn)
-    mp, np_ = a_blocks.shape[-2:]
-    nb = np_ // bn
-    t_total = nb * (nb + 1) // 2
-    n_l = mp // bm
-    t_axis = 2 if batched else 1
-
-    def kernel(rows_ref, cols_ref, *refs):
-        del rows_ref, cols_ref  # consumed by the index maps
-        _syrk_kernel(*refs, alpha=alpha, t_axis=t_axis, n_l=n_l, packed=False)
-
-    l_clamp = lambda l: jnp.minimum(l, n_l - 1)
-
-    lead = (1,) if batched else ()
-    batch_dims = a_blocks.shape[2:-2]
-    grid = (s_count,) + batch_dims + (t_total, n_l + 1)
-    _pre = lambda idx: idx[1:-2]  # () unbatched, (b,) batched
-
-    def _a_index(which):
-        def index(*args):
-            idx, rows_ref, cols_ref = args[:-2], args[-2], args[-1]
-            return (rows_ref[idx[0]], cols_ref[idx[0]]) + _pre(idx) + (
-                l_clamp(idx[-1]), _tri_coords(idx[-2])[which]
-            )
-
-        return index
-
-    def _c_index(*args):
-        idx = args[:-2]
-        i, j = _tri_coords(idx[-2])
-        lower = idx[-1] < n_l
-        return (idx[0],) + _pre(idx) + (
-            jnp.where(lower, i, j), jnp.where(lower, j, i)
-        )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1) + lead + (bm, bn), _a_index(0)),
-            pl.BlockSpec((1, 1) + lead + (bm, bn), _a_index(1)),
-        ],
-        out_specs=pl.BlockSpec((1,) + lead + (bn, bn), _c_index),
-        scratch_shapes=[pltpu.VMEM((bn, bn), jnp.float32)],
-    )
-    raw = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_struct(
-            (s_count,) + batch_dims + (np_, np_), out_dtype, a_blocks
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",),
-        ),
-        interpret=interpret,
-        name="syrk_gather",
-    )(jnp.asarray(rows), jnp.asarray(cols), a_blocks, a_blocks)
     return raw[..., :n, :n]

@@ -1,7 +1,7 @@
 """Nestable span API — the tracing half of ``repro.obs``.
 
 A *span* names one region of the dispatch pipeline: a recursion step, a
-batched/fused leaf launch, a kernel wrapper, the solve front door, an SPMD
+batched leaf launch, a kernel wrapper, the solve front door, an SPMD
 schedule body. Names are stable dotted paths; a level or a size goes in
 the attrs, never in the name. Each span has two parts:
 

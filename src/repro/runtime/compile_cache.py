@@ -1,8 +1,8 @@
 """Where JAX's persistent compilation cache lives.
 
 The entry points (``chip_smoke.py``, ``python -m repro.serve``,
-``benchmarks/run.py``, ``python -m repro.obs``) call
-:func:`enable_compile_cache` from their ``main``; nothing calls it at
+``python -m repro.obs``) call :func:`enable_compile_cache` from their
+``main``; nothing calls it at
 import, so the library and the tests never touch a cache as a side effect.
 
 ``JAX_COMPILATION_CACHE_DIR``, where it is set, wins: JAX reads it itself

@@ -18,7 +18,6 @@ from repro.tune import cost
 
 __all__ = [
     "base_fns",
-    "fused_fns",
     "build_callable",
     "ata_with_plan",
     "ata_distributed_with_plan",
@@ -42,26 +41,6 @@ def base_fns(plan: cost.Plan):
     base_syrk = functools.partial(ops.syrk, blocks=plan.syrk_blocks)
     base_dot = functools.partial(ops.gemm_tn, blocks=plan.gemm_blocks)
     return base_syrk, base_dot
-
-
-def fused_fns(plan: cost.Plan):
-    """(fused_syrk, fused_dot) for ``leaf_dispatch='fused'`` under this plan.
-
-    The fused leaf launches of the ``repro.kernels`` coefficient-table
-    contract, with the plan's block shapes: ``fused_dot(A, B, tables)``
-    runs every leaf product of one level as ONE ``ops.gemm_tn_fused``
-    launch, ``fused_syrk(ab, rows, cols)`` every gathered diagonal leaf as
-    ONE ``ops.syrk_gather`` launch. ``(None, None)`` when the plan doesn't
-    use kernels — the recursion then falls back to its trace-time slot
-    gathers (same values, XLA path).
-    """
-    if not plan.use_kernels:
-        return None, None
-    from repro.kernels import ops
-
-    fused_syrk = functools.partial(ops.syrk_gather, blocks=plan.syrk_blocks)
-    fused_dot = functools.partial(ops.gemm_tn_fused, blocks=plan.gemm_blocks)
-    return fused_syrk, fused_dot
 
 
 def ata_with_plan(a, plan: cost.Plan, **kw):

@@ -19,8 +19,10 @@ cache state, and a cache file round-trips through JSON bit-exactly
 (`Plan.to_json`/`from_json` — tested in ``tests/test_tune.py``).
 
 Cache location: ``$REPRO_TUNE_CACHE`` if set, else
-``~/.cache/repro/tune_plans.json``. ``bench_tune`` regenerates tuned plans
-(see DESIGN.md §7): ``PYTHONPATH=src python -m benchmarks.run tune``.
+``~/.cache/repro/tune_plans.json``. To fill it, call ``plan(...,
+autotune=True)`` per key (``tune.autotune`` measures the candidates and the
+winner is written back); :func:`warm` then resolves many keys from the file
+in one read (see DESIGN.md §7).
 """
 
 from __future__ import annotations
@@ -98,7 +100,7 @@ _COMPAT_SCHEMAS = ("v1", "v2", "v3")
 
 # every leaf_dispatch this revision's recursions accept (mirrors
 # core.strassen.resolve_tunables; kept literal so load never imports jax)
-_KNOWN_LEAF_DISPATCHES = ("unrolled", "batched", "fused")
+_KNOWN_LEAF_DISPATCHES = ("unrolled", "batched")
 
 
 def _valid_comm_schedule(cs) -> bool:
@@ -185,8 +187,8 @@ def load_cache(path: Optional[str] = None) -> dict:
             skipped += 1
             continue
         if p.leaf_dispatch not in _KNOWN_LEAF_DISPATCHES:
-            # a future schema's dispatch value: fall back to the always-
-            # valid unrolled form (bitwise-identical output) rather than
+            # a future schema's dispatch value, or one this revision
+            # dropped ('fused'): fall back to the always-valid unrolled form (bitwise-identical output) rather than
             # letting resolve_tunables raise on every dispatch of the key.
             import dataclasses
 

@@ -29,28 +29,24 @@ dense dot and for the recursion's base tiles, which is what makes the
 comparison honest; ``combine_bytes`` charges the operand-combination
 traffic — each VPU addition flop ``add_word_cost`` words for unrolled
 (≈1 on TPU where XLA fuses operand combinations into the consuming dot's
-reads; higher on CPU), ``stack_word_cost`` words for batched's
-materialized stacks, and the 3^L slot-gather amplification for fused —
-the Strassen memory overhead the paper's Section 3.3 engineers around.
+reads; higher on CPU) and ``stack_word_cost`` words for batched's
+materialized stacks — the Strassen memory overhead the paper's Section 3.3 engineers around.
 It is an *additive* term, not part of the compute/memory max: the combine
 passes serialize with the leaf matmuls on every measured backend.
 
 A third, previously-unpriced term joins the roofline in this revision:
 **per-call launch/graph overhead** (``dispatch_calls × launch_overhead_s``).
 The unrolled recursion hands the runtime one op per leaf — ``7^L`` dots —
-and on small leaves that dispatch tax, not flops, is what loses to a single
-plain dot (BENCH_strassen's 0.19–0.61 speedups). The level-synchronous
-``leaf_dispatch='batched'`` formulation collapses it to O(levels) calls at
-the price of materialized (un-fused) operand-combination stacks;
-``leaf_dispatch='fused'`` collapses both at once — one launch per level
-and zero materialized stacks, paying only the slot-gather read
-amplification (3^L) and the coefficient tables. The model prices all
-three so the argmin can pick per shape.
+and on small leaves that dispatch tax, not flops, can lose to a single
+plain dot. The level-synchronous ``leaf_dispatch='batched'`` formulation
+collapses it to O(levels) calls at the price of materialized
+operand-combination stacks. The model prices both so the argmin can pick
+per shape.
 
 Candidate axes (``candidates``): algorithm (dense-dot vs strassen vs
 winograd vs the ATA recursion), output mode (dense vs packed), recursion
-cutoff ``n_base``, leaf dispatch (unrolled vs batched vs fused —
-value-identical, speed-different; fused is classical-variant-only), and
+cutoff ``n_base``, leaf dispatch (unrolled vs batched — value-identical,
+speed-different), and
 the Pallas kernel block shapes. The algorithm /
 ``n_base`` choice is deliberately **out-invariant** (scored with the dense
 output term) so that ``out='packed'`` and ``out='dense'`` plans of one
@@ -164,7 +160,7 @@ class Plan:
     predicted_s: Optional[float] = None
     measured_s: Optional[float] = None
     # seconds of the hardcoded-default dispatch, measured interleaved with
-    # this plan by the autotuner (time_pair) — baseline_s/measured_s is the
+    # this plan by the autotuner (time_ratio) — baseline_s/measured_s is the
     # drift-resistant speedup-vs-default the tuning run actually observed.
     baseline_s: Optional[float] = None
 
@@ -265,7 +261,7 @@ MACHINES = {
     # the nominal 2.0 hid behind the compute roofline and ranked batched
     # above unrolled, inverting the measured order. Under this model the
     # argmin at the bench shapes matches the measured per-shape ranking:
-    # dense < unrolled(L=1) < fused(L=1) < batched(L=1) < deep recursions.
+    # dense < unrolled(L=1) < batched(L=1) < deep recursions.
     # α-β terms calibrated on the 8-fake-device container via the
     # obs.calibrate drift rows of the distributed sweep (fake devices
     # share one memory): a collective "message" costs a thunk dispatch
@@ -375,20 +371,10 @@ def dispatch_calls(op, algorithm, m, n, k, n_base, leaf_dispatch) -> int:
     ``'unrolled'`` pays one dispatched dot/syrk per leaf (``7^L`` for
     Strassen, ``4^L`` syrks + the off-diagonal leaf dots for ATA);
     ``'batched'`` pays the two batched leaf calls plus O(levels)
-    encode/decode stack ops. ``'fused'`` is cheapest of all: the slot
-    gather lives inside the kernel prologue, so Strassen is one fused
-    leaf launch plus one decode pass per level, and ATA is one gathered
-    diagonal syrk plus one fused off-diagonal launch and one decode pass
-    per level — one launch per *level*, never per leaf. 'dense' is the
-    single classical dot.
+    encode/decode stack ops. 'dense' is the single classical dot.
     """
     if algorithm == "dense":
         return 1
-    if leaf_dispatch == "fused":
-        lv = _levels(op, m, n, k, n_base)
-        if op == "ata":
-            return 2 + 2 * lv
-        return 1 + lv
     if leaf_dispatch == "batched":
         return 2 + 4 * _levels(op, m, n, k, n_base)
     if op == "ata":
@@ -774,18 +760,12 @@ def predict_seconds(
     recursions look free); ``'batched'`` pays O(levels) calls but its
     operand-combination adds are *materialized* stacks the leaf dot then
     re-reads, charged ``stack_word_cost`` words per add (nominal write+read
-    = 2.0, measured higher on cpu); ``'fused'`` pays neither — its stack
-    charge drops to ~0, replaced by the slot-gather read amplification
-    (each root leaf block is read once per nonzero slot: Strassen's combos
-    total 12 terms per 7 children per side, so L levels amplify the operand
-    read by (12/4)^L = 3^L) plus the coefficient tables themselves.
+    = 2.0, measured higher on cpu).
 
     The combine/add traffic is charged *additively* on top of the
     compute/memory roofline max, not inside it: on every backend we
     measured, the operand-combination passes serialize with the leaf
-    matmuls (XLA:CPU runs them as separate thunks; the fused kernel runs
-    them in the same launch but on the VPU ahead of each MXU tile), and
-    folding them into the max() hid them entirely at compute-bound shapes
+    matmuls (XLA:CPU runs them as separate thunks), and folding them into the max() hid them entirely at compute-bound shapes
     — which is exactly where the bench measurements show the dispatches
     separating.
     """
@@ -804,26 +784,12 @@ def predict_seconds(
     bn = min(bn, max(d_base, 1))
     bk = min(bk, max(d_base, 1))
     stream_bytes = (mult / 2) * (1.0 / bn + 1.0 / bk) * itemsize
-    if leaf_dispatch == "fused" and algorithm != "dense":
-        # no materialized stacks: the slot gather reads each root leaf
-        # block once per nonzero slot (3^L amplification, see docstring),
-        # plus the six (7^L, 2^L) int32 coefficient tables.
-        lv = _levels(op, m, n, k, n_base)
-        operand_words = (m * n + m * k) if op == "gemm_tn" else 2 * m * n
-        combine_bytes = operand_words * 3.0**lv * itemsize + 6 * 14**lv * 4
-        if not mach.kernels:
-            # interpret/XLA fallback: the gathered combinations still
-            # materialize per leaf (briefly — never as cross-leaf stacks)
-            # and are re-read by the leaf dot; charge the addition flops
-            # like the unrolled form on top of the gather reads.
-            combine_bytes += mach.add_word_cost * adds * itemsize
-    else:
-        add_word_cost = (
-            mach.stack_word_cost
-            if leaf_dispatch == "batched" and algorithm != "dense"
-            else mach.add_word_cost
-        )
-        combine_bytes = add_word_cost * adds * itemsize
+    add_word_cost = (
+        mach.stack_word_cost
+        if leaf_dispatch == "batched" and algorithm != "dense"
+        else mach.add_word_cost
+    )
+    combine_bytes = add_word_cost * adds * itemsize
     comm_s = 0.0
     pool = devices * max(row_devices, 1)
     if op == "ata" and pool > 1:
@@ -974,11 +940,6 @@ def candidates(
     for algo in algos:
         for n_base in n_bases if algo != "dense" else [defaults.DEFAULT_N_BASE]:
             lds = defaults.LEAF_DISPATCH_CANDIDATES
-            if algo != "strassen":
-                # fused slot tables encode the classical 7-term combos
-                # only — winograd's chained within-level sums don't fit
-                # (core.strassen raises), and dense has nothing to fuse.
-                lds = tuple(ld for ld in lds if ld != "fused")
             if algo == "dense":
                 lds = ("unrolled",)  # one classical dot — nothing to batch
             elif min(m, n, k) <= n_base:

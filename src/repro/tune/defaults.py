@@ -54,16 +54,13 @@ DEFAULT_VARIANT = "strassen"
 # How the recursion's leaf products reach the hardware when nothing chose:
 # 'unrolled' emits one dot/syrk per leaf (the historical trace-time form);
 # 'batched' runs the whole tree level-synchronously — every leaf in one
-# batched call; 'fused' gathers-and-combines the ±1 operand combinations
-# inside the leaf kernel's prologue from per-leaf slot tables — zero
-# materialized add stacks, one launch per level (classical variant only).
-# All three are bitwise-equal; the planner prices launch overhead against
-# combine traffic and picks per shape.
+# batched call. Both are bitwise-equal; the planner prices launch overhead
+# against combine traffic and picks per shape.
 DEFAULT_LEAF_DISPATCH = "unrolled"
 
-# Leaf-dispatch axis the planner enumerates ('fused' is dropped for the
-# winograd variant and for dense/degenerate candidates by `cost.candidates`).
-LEAF_DISPATCH_CANDIDATES = ("unrolled", "batched", "fused")
+# Leaf-dispatch axis the planner enumerates (dense and degenerate
+# candidates keep 'unrolled' alone, see `cost.candidates`).
+LEAF_DISPATCH_CANDIDATES = ("unrolled", "batched")
 
 # Normal-equations solver (repro.solve) when nothing chose a method:
 # 'factor' = planned packed gram → packed Cholesky → two substitutions;

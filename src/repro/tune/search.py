@@ -1,17 +1,14 @@
 """Measured autotuning: time candidate plans, keep the fastest.
 
-Also the home of the repo's **single** warmup/median timing discipline —
-``time_fn`` / ``time_pair`` used to live in ``benchmarks/common.py``; the
-benchmarks now import them from here so the autotuner and the benchmark
-suite cannot drift apart in methodology:
+The autotuner's timing discipline:
 
 * every timed call is synced with ``jax.block_until_ready``;
 * ``warmup`` calls are discarded (jit compile + first-touch);
-* the reported number is the **median** over ``iters`` (robust to the
+* ``time_fn`` reports the **median** over ``iters`` (robust to the
   ±20-30% background jitter of shared containers);
-* when the quantity of interest is a *ratio* between two functions, use
-  ``time_pair`` — it interleaves the two (A, B, A, B, …) so load drift
-  hits both equally.
+* when the quantity of interest is a *ratio* between two functions,
+  ``time_ratio`` interleaves the two with alternating order and keeps the
+  minimum of each series, so load drift and bursts hit both equally.
 """
 
 from __future__ import annotations
@@ -26,7 +23,7 @@ import numpy as np
 from repro.obs import calibrate, metrics
 from repro.tune import cost
 
-__all__ = ["time_fn", "time_pair", "measure_plan", "autotune"]
+__all__ = ["time_fn", "measure_plan", "autotune"]
 
 
 def time_fn(fn, *args, iters: int = 5, warmup: int = 2) -> float:
@@ -43,29 +40,13 @@ def time_fn(fn, *args, iters: int = 5, warmup: int = 2) -> float:
     return float(np.median(ts))
 
 
-def time_pair(fn_a, fn_b, *args, iters: int = 7, warmup: int = 2):
-    """Median wall times of two functions measured **interleaved**."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn_a(*args))
-        jax.block_until_ready(fn_b(*args))
-    ta, tb = [], []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn_a(*args))
-        ta.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn_b(*args))
-        tb.append(time.perf_counter() - t0)
-    return float(np.median(ta)), float(np.median(tb))
-
-
 def time_ratio(fn_a, fn_b, *args, iters: int = 8, warmup: int = 1):
     """Robust speed ratio ``t_a / t_b``: **minimum** per series, calls
     interleaved with alternating order.
 
-    ``time_pair``'s independent series medians survive slow drift but not
-    burst noise: background spikes on this container last about as long as
-    one call, so a median over a handful of samples still swings 30-80%
+    Independent per-series medians survive slow drift but not burst noise:
+    background spikes on this container last about as long as one call,
+    so a median over a handful of samples still swings 30-80%
     even for *identical* functions. Interference only ever ADDS time, so
     the min over an interleaved series is the clean-machine floor of each
     function — measured identical-function min-ratios stay within ~±10%

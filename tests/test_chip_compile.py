@@ -23,11 +23,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from repro import tune
 from repro.core.ata import ata
 from repro.core.distributed import ata_bfs_dfs, ata_tile_parallel
-from repro.core.strassen import _slot_tables, _to_blocks
+from repro.core.strassen import _to_blocks
 from repro.kernels import ops
 from repro.kernels.gemm_tn import gemm_tn_fused_pallas, gemm_tn_pallas
 from repro.kernels.potrf import potrf_pallas
-from repro.kernels.syrk import syrk_gather_pallas, syrk_pallas
+from repro.kernels.syrk import syrk_pallas
 from repro.kernels.trsm import trsm_pallas
 
 
@@ -67,9 +67,25 @@ def _text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _strassen_tables():
+    """One level of classical Strassen as ``gemm_tn_fused`` slot tables over
+    a 2×2 leaf-block grid (blocks numbered 11, 12, 21, 22 → 0..3): seven
+    two-slot leaves per side, signs ±1 and 0 for an empty slot."""
+    a = ((0, 3, 1), (1, 3, 1), (0, 0, 0), (3, 0, 0), (0, 2, 1), (1, 0, -1),
+         (2, 3, -1))
+    b = ((0, 3, 1), (0, 0, 0), (1, 3, -1), (2, 0, -1), (3, 0, 0), (0, 1, 1),
+         (2, 3, 1))
+
+    def side(combos):
+        blocks = np.array([[p, q] for p, q, _ in combos], np.int32)
+        sgn = np.array([[1, s] for _, _, s in combos], np.int32)
+        return blocks // 2, blocks % 2, sgn
+
+    return side(a), side(b)
+
+
 def _kernel_programs():
     syrk_blocks, gemm_blocks = (512, 256), (512, 512, 256)
-    rows, cols = np.arange(4) % 2, np.arange(4) // 2
     return {
         "syrk_dense": (lambda a: syrk_pallas(a, blocks=syrk_blocks, interpret=False),
                        [((2048, 1024), jnp.float32)]),
@@ -81,13 +97,9 @@ def _kernel_programs():
         "gemm_tn": (lambda a, b: gemm_tn_pallas(a, b, blocks=gemm_blocks, interpret=False),
                     [((2048, 1024), jnp.float32), ((2048, 768), jnp.float32)]),
         "gemm_tn_fused": (lambda a, b: gemm_tn_fused_pallas(
-            _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], _slot_tables(1),
+            _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], _strassen_tables(),
             blocks=gemm_blocks, interpret=False),
             [((2048, 2048), jnp.float32), ((2048, 1024), jnp.float32)]),
-        "syrk_gather": (lambda a: syrk_gather_pallas(
-            _to_blocks(a, 1), jnp.asarray(rows, jnp.int32),
-            jnp.asarray(cols, jnp.int32), blocks=syrk_blocks, interpret=False),
-            [((2048, 2048), jnp.float32)]),
         "potrf": (lambda a: potrf_pallas(a, interpret=False),
                   [((4, 128, 128), jnp.float32)]),
         "trsm": (lambda l, b: trsm_pallas(l, b, interpret=False),

@@ -465,39 +465,39 @@ np.testing.assert_allclose(np.asarray(c, np.float32), ref,
 print("OK")
 """
 
-FUSED_DISPATCH_SCRIPT = r"""
+BATCHED_DISPATCH_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.distributed import ata_tile_parallel, gemm_tn_colshard, gram_rowshard
 mesh = jax.make_mesh((8,), ("model",))
 r = np.random.default_rng(10)
 a = jnp.asarray(r.standard_normal((256, 192)), dtype=jnp.float32)
-# the per-device tile bodies inherit the fused dispatch: bitwise parity with
+# the per-device tile bodies inherit the batched dispatch: bitwise parity with
 # the unrolled schedule on the same tiling (leaf_dispatch never changes
 # values, only how the leaves reach the hardware)
 mk = lambda ld: jax.jit(lambda a: ata_tile_parallel(
     a, mesh, task_axis="model", n_base=32, variant="strassen",
     leaf_dispatch=ld))
-cu, cf = mk("unrolled")(a), mk("fused")(a)
-assert (np.asarray(cu) == np.asarray(cf)).all()
-np.testing.assert_allclose(np.asarray(cf), np.asarray(a.T @ a),
+cu, cb = mk("unrolled")(a), mk("batched")(a)
+assert (np.asarray(cu) == np.asarray(cb)).all()
+np.testing.assert_allclose(np.asarray(cb), np.asarray(a.T @ a),
                            rtol=1e-4, atol=1e-4)
-# colshard stripes through the fused per-device body
+# colshard stripes through the batched per-device body
 b = jnp.asarray(r.standard_normal((256, 64)), dtype=jnp.float32)
 mkg = lambda ld: jax.jit(lambda a, b: gemm_tn_colshard(
     a, b, mesh, task_axis="model", n_base=32, variant="strassen",
     leaf_dispatch=ld))
-gu, gf = mkg("unrolled")(a, b), mkg("fused")(a, b)
-assert (np.asarray(gu) == np.asarray(gf)).all()
-# rowshard: fused local gram under the packed psum
+gu, gb = mkg("unrolled")(a, b), mkg("batched")(a, b)
+assert (np.asarray(gu) == np.asarray(gb)).all()
+# rowshard: batched local gram under the psum
 mesh2 = jax.make_mesh((8,), ("data",))
 a2 = jnp.asarray(r.standard_normal((512, 96)), dtype=jnp.float32)
 mkr = lambda ld: jax.jit(jax.shard_map(
     lambda x: gram_rowshard(x, "data", n_base=32, variant="strassen",
                             leaf_dispatch=ld),
     mesh=mesh2, in_specs=(P("data", None),), out_specs=P(None, None)))
-ru, rf = mkr("unrolled")(a2), mkr("fused")(a2)
-assert (np.asarray(ru) == np.asarray(rf)).all()
+ru, rb = mkr("unrolled")(a2), mkr("batched")(a2)
+assert (np.asarray(ru) == np.asarray(rb)).all()
 print("OK")
 """
 
@@ -538,15 +538,14 @@ mesh = jax.make_mesh((8,), ("model",))
 r = np.random.default_rng(12)
 a = jnp.asarray(r.standard_normal((256, 384)), dtype=jnp.float32)
 # nb=4 -> w=96 > n_base: the per-tile Strassen actually recurses, so the
-# three leaf bodies compile genuinely different programs — which must still
+# two leaf bodies compile genuinely different programs — which must still
 # agree bitwise (leaf_dispatch never changes values) under the BFS scatter
 mk = lambda ld: jax.jit(lambda a: ata_bfs_dfs(
     a, mesh, task_axis="model", interleaving="B", n_base=32, nb=4,
     packed_block=96, variant="strassen", leaf_dispatch=ld))
-cu, cb, cf = mk("unrolled")(a), mk("batched")(a), mk("fused")(a)
+cu, cb = mk("unrolled")(a), mk("batched")(a)
 assert (np.asarray(cu) == np.asarray(cb)).all()
-assert (np.asarray(cu) == np.asarray(cf)).all()
-np.testing.assert_allclose(np.asarray(cf), np.asarray(a.T @ a),
+np.testing.assert_allclose(np.asarray(cb), np.asarray(a.T @ a),
                            rtol=1e-4, atol=1e-4)
 print("OK")
 """
@@ -692,13 +691,13 @@ print("OK")
     "script",
     [TILE_SCRIPT, TILE_2D_SCRIPT, ROWSHARD_SCRIPT, COLSHARD_SCRIPT,
      TILE_RAGGED_SCRIPT, TILE_PACKED_SCRIPT, TILE_2D_PACKED_SCRIPT,
-     ROWSHARD_PACKED_SCRIPT, TILE_BF16_SCRIPT, FUSED_DISPATCH_SCRIPT,
+     ROWSHARD_PACKED_SCRIPT, TILE_BF16_SCRIPT, BATCHED_DISPATCH_SCRIPT,
      BFSDFS_PARITY_SCRIPT, BFSDFS_LEAF_DISPATCH_SCRIPT,
      BFSDFS_PURE_DFS_SCRIPT, BFSDFS_RANKING_SCRIPT,
      POWERSGD_SHARDED_SCRIPT],
     ids=["tile_8dev", "tile_2d", "rowshard", "colshard", "tile_ragged",
          "tile_packed", "tile_2d_packed", "rowshard_packed", "tile_bf16",
-         "fused_dispatch", "bfsdfs_parity", "bfsdfs_leaf_dispatch",
+         "batched_dispatch", "bfsdfs_parity", "bfsdfs_leaf_dispatch",
          "bfsdfs_pure_dfs", "bfsdfs_ranking", "powersgd_sharded"],
 )
 def test_multidevice(script):

@@ -34,9 +34,24 @@ SHAPES_SYRK = [
 
 DTYPES = [jnp.float32, jnp.bfloat16]
 
+# f32 kernels are held to the float64 product under the elementwise bound
+# F32_C·√m·2⁻²⁴·(|A|ᵀ|B|), which grows with the contraction length m as
+# f32 rounding does. An f32 oracle is no yardstick here: it errs more than
+# the kernel. The kernels' worst error at these shapes reads 1.2 of the
+# unit bound (syrk, m = 8); a kernel that rounded its products to bf16
+# would read hundreds.
+F32_C = 3.0
+
 
 def _tol(dtype):
     return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(rtol=1e-5, atol=1e-5)
+
+
+def _assert_f32_close(got, a, b):
+    a64, b64 = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    bound = F32_C * np.sqrt(a64.shape[0]) * 2.0**-24 * (np.abs(a64).T @ np.abs(b64))
+    err = np.abs(np.asarray(got, np.float64) - a64.T @ b64)
+    assert (err <= bound).all(), float(np.max(err / bound))
 
 
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
@@ -46,10 +61,12 @@ def test_gemm_tn_kernel_matches_ref(m, n, k, dtype):
     a = jnp.asarray(r.standard_normal((m, n)), dtype=dtype)
     b = jnp.asarray(r.standard_normal((m, k)), dtype=dtype)
     got = gemm_tn(a, b, blocks=(256, 128, 128), interpret=True)
-    want = gemm_tn_ref(a, b)
     assert got.shape == (n, k)
     assert got.dtype == jnp.float32
-    np.testing.assert_allclose(got, want, **_tol(dtype))
+    if dtype == jnp.float32:
+        _assert_f32_close(got, a, b)
+    else:
+        np.testing.assert_allclose(got, gemm_tn_ref(a, b), **_tol(dtype))
 
 
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
@@ -58,9 +75,11 @@ def test_syrk_kernel_matches_ref(m, n, dtype):
     r = np.random.default_rng(hash((m, n)) % 2**32)
     a = jnp.asarray(r.standard_normal((m, n)), dtype=dtype)
     got = syrk(a, blocks=(256, 128), interpret=True)
-    want = syrk_ref(a)
     assert got.shape == (n, n)
-    np.testing.assert_allclose(got, want, **_tol(dtype))
+    if dtype == jnp.float32:
+        _assert_f32_close(got, a, a)
+    else:
+        np.testing.assert_allclose(got, syrk_ref(a), **_tol(dtype))
     # bitwise symmetry contract
     np.testing.assert_array_equal(np.asarray(got), np.asarray(got).T)
 
@@ -261,23 +280,80 @@ def test_strassen_batched_leaves_with_pallas_base():
 
 
 # ---------------------------------------------------------------------------
-# fused-operand leaves (the coefficient-table contract)
+# table-addressed leaves (the coefficient-table contract of gemm_tn_fused)
 # ---------------------------------------------------------------------------
+
+# Classical Strassen's seven operand combinations per side, as
+# (first quadrant, second quadrant or None, sign of the second), quadrants
+# numbered 11, 12, 21, 22 → 0..3: multi-slot tables that exercise every
+# part of the kernel's table contract (several slots, signs −1/0/+1).
+_STRASSEN_A = ((0, 3, 1), (1, 3, 1), (0, None, 0), (3, None, 0),
+               (0, 2, 1), (1, 0, -1), (2, 3, -1))
+_STRASSEN_B = ((0, 3, 1), (0, None, 0), (1, 3, -1), (2, 0, -1),
+               (3, None, 0), (0, 1, 1), (2, 3, 1))
+
+
+def _strassen_tables(L):
+    """``((a_rows, a_cols, a_sgn), (b_rows, b_cols, b_sgn))``: six
+    ``(7^L, 2^L)`` int32 slot tables of L levels of classical Strassen
+    over the root leaf-block grid of ``core.strassen._to_blocks``. Slot
+    ``k`` of a leaf is the coefficient of root block ``(rows[k],
+    cols[k])``; sign 0 marks an empty slot."""
+
+    def build(combos):
+        R = 1 << L
+        r, c = np.indices((R, R))
+        slots = np.stack([r, c, np.ones((R, R), np.int64)], axis=-1)
+        slots = slots[None, :, :, None, :]   # (S, rows, cols, W, {r, c, sign})
+        for _ in range(L):
+            S, Rg, Cg, W, _ = slots.shape
+            h, w = Rg // 2, Cg // 2
+            quad = (slots[:, :h, :w], slots[:, :h, w:],
+                    slots[:, h:, :w], slots[:, h:, w:])
+            parts = []
+            for p, q, sg in combos:
+                second = np.zeros_like(quad[p]) if q is None else quad[q].copy()
+                second[..., 2] *= sg
+                parts.append(np.concatenate([quad[p], second], axis=3))
+            slots = np.stack(parts, axis=1).reshape(S * 7, h, w, 2 * W, 3)
+        slots = slots[:, 0, 0]
+        return tuple(slots[..., i].astype(np.int32) for i in range(3))
+
+    return build(_STRASSEN_A), build(_STRASSEN_B)
+
+
+def _gather_leaf(x, L, rows, cols, sgn):
+    """One leaf operand gathered at trace time from the unblocked ``x``:
+    the slots' signed blocks summed as the same balanced binary tree the
+    kernel prologue evaluates, empty slots dropped."""
+    mb, nb = x.shape[-2] >> L, x.shape[-1] >> L
+
+    def ev(lo, hi):
+        if hi - lo == 1:
+            if sgn[lo] == 0:
+                return None
+            blk = x[..., rows[lo] * mb:(rows[lo] + 1) * mb,
+                    cols[lo] * nb:(cols[lo] + 1) * nb]
+            return -blk if sgn[lo] < 0 else blk
+        mid = (lo + hi) // 2
+        left, right = ev(lo, mid), ev(mid, hi)
+        if left is None or right is None:
+            return right if left is None else left
+        return left + right
+
+    return ev(0, len(sgn))
 
 
 def _slot_path(a, b, L, blocks):
-    """The XLA half of the fused contract: per-leaf trace-time slot gather
-    (`_combine_slots`' balanced ± tree) + the SAME unbatched blocked kernel
-    per leaf. The fused launch must match it bitwise — identical chunk
-    dots, identical add-tree association, signs applied in-kernel."""
-    from repro.core.strassen import _block_getter, _combine_slots, _slot_tables
-
-    (ar, ac, asg), (br, bc, bsg) = _slot_tables(L)
-    ga, gb = _block_getter(a, L), _block_getter(b, L)
+    """The trace-time half of the contract: each leaf's operands gathered
+    by `_gather_leaf` + the SAME unbatched blocked kernel per leaf. The
+    table-addressed launch must match it bitwise — identical chunk dots,
+    identical add-tree association, signs applied in-kernel."""
+    (ar, ac, asg), (br, bc, bsg) = _strassen_tables(L)
     return jnp.stack([
         gemm_tn(
-            _combine_slots(ga, ar[t], ac[t], asg[t]),
-            _combine_slots(gb, br[t], bc[t], bsg[t]),
+            _gather_leaf(a, L, ar[t], ac[t], asg[t]),
+            _gather_leaf(b, L, br[t], bc[t], bsg[t]),
             blocks=blocks, interpret=True,
         )
         for t in range(ar.shape[0])
@@ -293,7 +369,7 @@ def _slot_path(a, b, L, blocks):
     ],
 )
 def test_gemm_tn_fused_bitwise_vs_slot_gather(m, n, k, L):
-    from repro.core.strassen import _pad_root, _slot_tables, _to_blocks
+    from repro.core.strassen import _pad_root, _to_blocks
     from repro.kernels import ops
 
     r = np.random.default_rng(hash((m, n, k, L)) % 2**32)
@@ -302,7 +378,7 @@ def test_gemm_tn_fused_bitwise_vs_slot_gather(m, n, k, L):
     blocks = (64, 64, 64)
     want = _slot_path(a, b, L, blocks)
     got = ops.gemm_tn_fused(
-        _to_blocks(a, L)[None], _to_blocks(b, L)[None], _slot_tables(L),
+        _to_blocks(a, L)[None], _to_blocks(b, L)[None], _strassen_tables(L),
         blocks=blocks, interpret=True,
     )
     assert got.shape == want.shape and got.dtype == jnp.float32
@@ -313,7 +389,7 @@ def test_gemm_tn_fused_bitwise_vs_slot_gather(m, n, k, L):
 def test_gemm_tn_fused_batched_grid(B):
     """An operand batch dim rides the grid like everything else — including
     the B=1 degenerate leading dim the level-synchronous ATA tree emits."""
-    from repro.core.strassen import _pad_root, _slot_tables, _to_blocks
+    from repro.core.strassen import _pad_root, _to_blocks
     from repro.kernels import ops
 
     r = np.random.default_rng(20 + B)
@@ -322,14 +398,14 @@ def test_gemm_tn_fused_batched_grid(B):
     blocks = (64, 64, 64)
     want = _slot_path(a, b, 1, blocks)  # (7, B, n/2, k/2): leaf-major stack
     got = ops.gemm_tn_fused(
-        _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], _slot_tables(1),
+        _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], _strassen_tables(1),
         blocks=blocks, interpret=True,
     )
     assert got.shape == (7, B, 48, 32)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
     # B=1 slices agree with the unbatched launch (same grids, same math)
     one = ops.gemm_tn_fused(
-        _to_blocks(a[0], 1)[None], _to_blocks(b[0], 1)[None], _slot_tables(1),
+        _to_blocks(a[0], 1)[None], _to_blocks(b[0], 1)[None], _strassen_tables(1),
         blocks=blocks, interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(got[:, 0]), np.asarray(one))
@@ -344,7 +420,7 @@ def test_gemm_tn_fused_bf16_storage_f32_accumulate():
     — strictly *more* accurate, never bitwise. So bf16 asserts the combine
     is at least operand-precision against the f32 oracle, plus the flush
     cast contract."""
-    from repro.core.strassen import _pad_root, _slot_tables, _to_blocks
+    from repro.core.strassen import _pad_root, _to_blocks
     from repro.kernels import ops
 
     r = np.random.default_rng(30)
@@ -352,7 +428,7 @@ def test_gemm_tn_fused_bf16_storage_f32_accumulate():
     b = _pad_root(jnp.asarray(r.standard_normal((128, 64)), jnp.bfloat16), 1)
     blocks = (64, 64, 64)
     got = ops.gemm_tn_fused(
-        _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], _slot_tables(1),
+        _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], _strassen_tables(1),
         blocks=blocks, interpret=True,
     )
     assert got.dtype == jnp.float32
@@ -366,7 +442,7 @@ def test_gemm_tn_fused_bf16_storage_f32_accumulate():
     np.testing.assert_allclose(np.asarray(slot), np.asarray(want),
                                rtol=2e-2, atol=2e-1)
     lo = ops.gemm_tn_fused(
-        _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], _slot_tables(1),
+        _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], _strassen_tables(1),
         blocks=blocks, interpret=True, out_dtype=jnp.bfloat16,
     )
     assert lo.dtype == jnp.bfloat16
@@ -409,101 +485,20 @@ def test_gemm_tn_fused_dead_leaf_skips_its_dot(dead):
 def test_gemm_tn_fused_one_dead_leaf_leaves_the_rest():
     """Zeroing one Strassen leaf's signs kills that leaf alone: it reads
     exact zeros and the other six leaves are bitwise what they were."""
-    from repro.core.strassen import _pad_root, _slot_tables, _to_blocks
+    from repro.core.strassen import _pad_root, _to_blocks
     from repro.kernels import ops
 
     r = np.random.default_rng(32)
     a = _pad_root(jnp.asarray(r.standard_normal((128, 96)), jnp.float32), 1)
     b = _pad_root(jnp.asarray(r.standard_normal((128, 64)), jnp.float32), 1)
-    (ar, ac, asg), (br, bc, bsg) = _slot_tables(1)
+    (ar, ac, asg), (br, bc, bsg) = _strassen_tables(1)
     asg, bsg = asg.copy(), bsg.copy()
     asg[4] = bsg[4] = 0
     run = lambda tables: np.asarray(ops.gemm_tn_fused(
         _to_blocks(a, 1)[None], _to_blocks(b, 1)[None], tables,
         blocks=(64, 64, 64), interpret=True))
-    want = run(_slot_tables(1))
+    want = run(_strassen_tables(1))
     got = run(((ar, ac, asg), (br, bc, bsg)))
     np.testing.assert_array_equal(got[4], np.zeros_like(got[4]))
     keep = [t for t in range(7) if t != 4]
     np.testing.assert_array_equal(got[keep], want[keep])
-
-
-@pytest.mark.parametrize("L", range(6))
-def test_slot_tables_have_no_dead_leaf(L):
-    """Every leaf of Strassen's fused tables has a live A slot and a live B
-    slot, so the fused kernel's dead-leaf skip never fires on them and
-    every fused dispatch computes what it did before the skip existed."""
-    from repro.core.strassen import _slot_tables
-
-    (_, _, asg), (_, _, bsg) = _slot_tables(L)
-    assert asg.shape == bsg.shape == (7 ** L, 2 ** L)
-    assert (asg != 0).any(axis=1).all()
-    assert (bsg != 0).any(axis=1).all()
-
-
-@pytest.mark.parametrize("L", [1, 2])
-def test_syrk_gather_bitwise_vs_stacked_syrk(L):
-    """The diagonal half of the contract: gathering leaf pairs through the
-    index maps equals stacking them first and running the batched syrk —
-    the stack is simply never built."""
-    from repro.core.strassen import _to_blocks
-    from repro.kernels import ops
-
-    r = np.random.default_rng(40 + L)
-    a = jnp.asarray(r.standard_normal((256, 256)), jnp.float32)
-    ab = _to_blocks(a, L)
-    R = 1 << L
-    s = np.arange(R * R, dtype=np.int32)
-    stacked = jnp.swapaxes(ab, 0, 1).reshape(R * R, *ab.shape[-2:])
-    want = syrk(stacked, blocks=(128, 64), interpret=True)
-    got = ops.syrk_gather(ab, s % R, s // R, blocks=(128, 64), interpret=True)
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
-
-
-def test_syrk_gather_batched_grid():
-    from repro.core.strassen import _to_blocks
-    from repro.kernels import ops
-
-    r = np.random.default_rng(42)
-    a = jnp.asarray(r.standard_normal((2, 128, 128)), jnp.float32)
-    ab = _to_blocks(a, 1)
-    s = np.arange(4, dtype=np.int32)
-    stacked = jnp.swapaxes(ab, 0, 1).reshape(4, 2, *ab.shape[-2:])
-    want = syrk(
-        stacked.reshape(-1, *ab.shape[-2:]), blocks=(64, 64), interpret=True
-    ).reshape(4, 2, ab.shape[-1], ab.shape[-1])
-    got = ops.syrk_gather(ab, s % 2, s // 2, blocks=(64, 64), interpret=True)
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
-
-
-def test_fused_leaves_with_pallas_kernels_end_to_end():
-    """A use_kernels fused plan runs the whole recursion through ONE fused
-    launch per level — bitwise with the unrolled kernel dispatch on the
-    same plan, for strassen_tn and both ata output modes (odd shapes)."""
-    import dataclasses
-
-    from repro.core import ata, strassen_tn
-    from repro.tune import cost
-
-    r = np.random.default_rng(50)
-
-    def mk(op, m, n, k, ld, **kw):
-        return dataclasses.replace(
-            cost.default_plan(op, m, n, k), algorithm="strassen", n_base=64,
-            use_kernels=True, leaf_dispatch=ld, **kw,
-        )
-
-    a = jnp.asarray(r.standard_normal((300, 260)), jnp.float32)
-    du = ata(a, plan=mk("ata", 300, 260, None, "unrolled"))
-    df = ata(a, plan=mk("ata", 300, 260, None, "fused"))
-    np.testing.assert_array_equal(np.asarray(du), np.asarray(df))
-    np.testing.assert_allclose(df, a.T @ a, rtol=2e-4, atol=2e-4)
-    pf = ata(a, plan=mk("ata", 300, 260, None, "fused", out="packed"),
-             out="packed")
-    np.testing.assert_array_equal(np.asarray(pf.to_dense()), np.asarray(du))
-
-    b = jnp.asarray(r.standard_normal((300, 200)), jnp.float32)
-    gu = strassen_tn(a, b, plan=mk("gemm_tn", 300, 260, 200, "unrolled"))
-    gf = strassen_tn(a, b, plan=mk("gemm_tn", 300, 260, 200, "fused"))
-    np.testing.assert_array_equal(np.asarray(gu), np.asarray(gf))
-    np.testing.assert_allclose(gf, a.T @ b, rtol=2e-4, atol=2e-4)

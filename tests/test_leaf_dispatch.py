@@ -1,22 +1,20 @@
-"""Batched- and fused-leaf dispatch: bitwise parity with the unrolled
-recursion, jaxpr-size regressions, and plan threading.
+"""Batched-leaf dispatch: bitwise parity with the unrolled recursion,
+jaxpr-size regressions, and plan threading.
 
-The acceptance contract of the batched-leaf and fused-leaf PRs:
+The contract of the two leaf dispatches:
 
-* ``leaf_dispatch='batched'`` and ``'fused'`` are **bitwise-equal** to
-  ``'unrolled'`` on the same plan, for ``strassen_tn``/``ata``/
-  ``ata_batched``, across odd and rectangular shapes, dense and packed
-  output, and alpha/c/beta accumulation (batched: both variants; fused:
-  classical only — winograd raises);
+* ``leaf_dispatch='batched'`` is **bitwise-equal** to ``'unrolled'`` on
+  the same plan, for ``strassen_tn``/``ata``/``ata_batched``, across odd
+  and rectangular shapes, both variants, dense and packed output, and
+  alpha/c/beta accumulation;
 * the batched dispatch emits **O(levels)** dots (one batched TN gemm + one
-  batched syrk for the whole ATA tree), not O(7^L); the fused dispatch
-  emits one dot per leaf but **zero materialized operand-add stacks** —
-  both jaxpr regression tests;
+  batched syrk for the whole ATA tree), not O(7^L) — a jaxpr regression
+  test;
 * the planner carries the choice (``Plan.leaf_dispatch``): candidates
-  enumerate all three (fused for classical Strassen only), JSON
-  round-trips it, pre-leaf_dispatch cache entries deserialize to
-  ``'unrolled'``, and the overhead pricing makes the dispatches
-  distinguishable to the analytic model.
+  enumerate both, JSON round-trips it, pre-leaf_dispatch cache entries
+  deserialize to ``'unrolled'``, and the overhead pricing makes the
+  dispatches distinguishable to the analytic model. No other value
+  (``'fused'`` included) is accepted.
 """
 
 import dataclasses
@@ -155,105 +153,6 @@ def test_batched_under_jit_and_grad():
 
 
 # ---------------------------------------------------------------------------
-# fused dispatch parity (XLA slot-gather path; the kernel launch path is
-# covered by test_kernels.py's coefficient-table section)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize(
-    "m,n,k",
-    [
-        (64, 64, 64),
-        (128, 96, 80),   # rectangular
-        (67, 53, 41),    # odd everywhere -> root pad, cropped leaves
-        (100, 200, 50),  # tall/wide mix
-        (33, 1, 7),      # degenerate (L = 0: every dispatch IS one dot)
-    ],
-)
-def test_strassen_fused_bitwise_equals_unrolled(m, n, k):
-    r = rng(hash(("fused", m, n, k)) % 2**32)
-    a = jnp.asarray(r.standard_normal((m, n)))
-    b = jnp.asarray(r.standard_normal((m, k)))
-    kw = dict(n_base=8, variant="strassen", acc_dtype=jnp.float64)
-    _bitwise(
-        strassen_tn(a, b, leaf_dispatch="unrolled", **kw),
-        strassen_tn(a, b, leaf_dispatch="fused", **kw),
-    )
-
-
-def test_strassen_fused_alpha_beta_accumulate_bitwise():
-    r = rng(21)
-    a = jnp.asarray(r.standard_normal((32, 24)))
-    b = jnp.asarray(r.standard_normal((32, 40)))
-    c = jnp.asarray(r.standard_normal((24, 40)))
-    kw = dict(alpha=2.5, c=c, beta=-0.5, n_base=8, variant="strassen",
-              acc_dtype=jnp.float64)
-    got = strassen_tn(a, b, leaf_dispatch="fused", **kw)
-    _bitwise(strassen_tn(a, b, leaf_dispatch="unrolled", **kw), got)
-    np.testing.assert_allclose(got, 2.5 * (a.T @ b) - 0.5 * c, rtol=1e-9, atol=1e-9)
-
-
-@pytest.mark.parametrize("m,n", [(64, 64), (67, 53), (200, 100), (257, 129)])
-def test_ata_fused_leaf_bitwise_equals_unrolled(m, n):
-    r = rng(hash(("fused", m, n)) % 2**32)
-    a = jnp.asarray(r.standard_normal((m, n)))
-    kw = dict(n_base=8, variant="strassen", acc_dtype=jnp.float64)
-    dense_u = ata(a, leaf_dispatch="unrolled", **kw)
-    dense_f = ata(a, leaf_dispatch="fused", **kw)
-    _bitwise(dense_u, dense_f)
-    np.testing.assert_allclose(dense_f, a.T @ a, rtol=1e-9, atol=1e-9)
-    pu = ata(a, leaf_dispatch="unrolled", out="packed", packed_block=32, **kw)
-    pf = ata(a, leaf_dispatch="fused", out="packed", packed_block=32, **kw)
-    _bitwise(pu.blocks, pf.blocks)
-    _bitwise(pf.to_dense(), dense_f)
-
-
-@pytest.mark.parametrize("B", [1, 5])
-@pytest.mark.parametrize("out", ["dense", "packed"])
-def test_ata_batched_op_fused_bitwise(B, out):
-    """The (B, m, n) gram entry point — including the B=1 leading dim the
-    fused level grids must carry through their batch axis."""
-    r = rng(22 + B)
-    a = jnp.asarray(r.standard_normal((B, 48, 28)))
-    kw = dict(n_base=8, variant="strassen", acc_dtype=jnp.float64, out=out)
-    if out == "packed":
-        kw["packed_block"] = 16
-    u = ata_batched(a, leaf_dispatch="unrolled", **kw)
-    f = ata_batched(a, leaf_dispatch="fused", **kw)
-    if out == "packed":
-        _bitwise(u.blocks, f.blocks)
-    else:
-        _bitwise(u, f)
-        np.testing.assert_allclose(
-            f, jnp.einsum("bmi,bmj->bij", a, a), rtol=1e-9, atol=1e-9
-        )
-
-
-def test_fused_requires_classical_variant():
-    """The slot tables encode the 7-term classical combos; winograd's
-    chained within-level sums have no per-leaf ±1 table, so the fused
-    dispatch refuses rather than silently switching algorithms."""
-    a = jnp.zeros((32, 32))
-    with pytest.raises(ValueError, match="fused"):
-        strassen_tn(a, a, n_base=8, variant="winograd", leaf_dispatch="fused")
-    with pytest.raises(ValueError, match="fused"):
-        ata(a, n_base=8, variant="winograd", leaf_dispatch="fused")
-
-
-def test_fused_under_jit_and_grad():
-    r = rng(23)
-    a = jnp.asarray(r.standard_normal((64, 48)))
-    kw = dict(n_base=16, variant="strassen", acc_dtype=jnp.float64)
-    f = jax.jit(lambda a: ata(a, leaf_dispatch="fused", **kw))
-    _bitwise(f(a), ata(a, leaf_dispatch="unrolled", **kw))
-    g = jax.grad(
-        lambda a: strassen_tn(a, a, leaf_dispatch="fused", **kw).sum()
-    )(a)
-    g_ref = jax.grad(lambda a: (a.T @ a).sum())(a)
-    np.testing.assert_allclose(g, g_ref, rtol=1e-9, atol=1e-9)
-
-
-# ---------------------------------------------------------------------------
 # jaxpr-size regression: O(levels) dots, not O(7^L)
 # ---------------------------------------------------------------------------
 
@@ -298,8 +197,8 @@ def test_batched_strassen_emits_one_dot_and_scales_by_levels():
 
 def test_batched_jaxpr_total_size_grows_linearly_not_geometrically():
     """Total eqn count of the batched dispatch is O(levels): deepening the
-    recursion by a level adds a constant band of encode/decode ops, while
-    the unrolled jaxpr multiplies by ~7."""
+    recursion by a level adds the same band of encode/decode ops every
+    time, while the unrolled jaxpr multiplies by ~7."""
     a = jnp.zeros((512, 512), jnp.float32)
     b = jnp.zeros((512, 512), jnp.float32)
 
@@ -309,65 +208,11 @@ def test_batched_jaxpr_total_size_grows_linearly_not_geometrically():
         )(a, b)
         return len(jaxpr.jaxpr.eqns)
 
-    b1, b2, b3 = eqns(256, "batched"), eqns(128, "batched"), eqns(64, "batched")
+    sizes = [eqns(n_base, "batched") for n_base in (256, 128, 64, 32)]
+    growth = {sizes[i + 1] - sizes[i] for i in range(3)}
+    assert len(growth) == 1 and growth.pop() > 0, sizes  # one band a level
     u2, u3 = eqns(128, "unrolled"), eqns(64, "unrolled")
-    assert b3 - b2 < 2 * (b2 - b1) + 40   # additive growth, small constant
     assert u3 > 5 * u2                    # geometric growth
-    assert b3 < u3 / 10
-
-
-def test_fused_jaxpr_one_dot_per_leaf_and_zero_operand_stacks():
-    """The fused XLA path's acceptance property: every leaf is its own dot
-    (the combines happen per-leaf at trace time, 7^L dots total) and NO
-    operand-combination stack is ever materialized — the repro.check
-    ``no-operand-stacks`` + ``dot-budget`` rules run against the real
-    fused program. Rectangular dims keep the operand block shapes
-    distinguishable from the product/decode shapes; as the positive
-    control, the *batched* dispatch's jaxpr (which materializes both
-    operand stacks by design) must FIRE the rule when presented under a
-    fused-claiming plan."""
-    from repro import check
-
-    m, n, k, n_base = 96, 32, 16, 4   # L = 2 -> 49 leaves
-    a = jnp.zeros((m, n), jnp.float32)
-    b = jnp.zeros((m, k), jnp.float32)
-    nb, kb = n // 4, k // 4
-
-    def trace(ld):
-        return jax.make_jaxpr(
-            lambda x, y: strassen_tn(
-                x, y, n_base=n_base, variant="strassen", leaf_dispatch=ld
-            )
-        )(a, b)
-
-    def plan(ld):
-        return dataclasses.replace(
-            cost.default_plan("gemm_tn", m, n, k, backend="cpu"),
-            algorithm="strassen", leaf_dispatch=ld, n_base=n_base,
-            use_kernels=False,
-        )
-
-    fused = trace("fused")
-    art = check.Artifact(label="gemm:fused", jaxpr=fused.jaxpr,
-                         plan=plan("fused"))
-    report = check.run(art, rules=["no-operand-stacks", "dot-budget"])
-    assert not report.violations, report.summary()
-    # 49 = one dot per leaf (the dot-budget closed form, asserted again
-    # directly so a registry regression can't silently weaken this test)
-    n_dots = sum(1 for s in check.walk_eqns(fused.jaxpr)
-                 if s.eqn.primitive.name == "dot_general")
-    assert n_dots == 49, n_dots
-    # the product stack IS materialized, by design
-    fused_shapes = [tuple(v.aval.shape) for s in check.walk_eqns(fused.jaxpr)
-                    for v in s.eqn.outvars]
-    assert (49, nb, kb) in fused_shapes
-    # positive control: the batched dispatch materializes both operand
-    # stacks — under a fused-claiming plan the rule must fire
-    batched = trace("batched")
-    art_b = check.Artifact(label="gemm:batched-as-fused", jaxpr=batched.jaxpr,
-                           plan=plan("fused"))
-    fired = check.run(art_b, rules=["no-operand-stacks"])
-    assert fired.violations, "no-operand-stacks failed to fire on a stack"
 
 
 # ---------------------------------------------------------------------------
@@ -388,12 +233,8 @@ def test_candidates_enumerate_leaf_dispatch():
     lds = {(c.algorithm, c.leaf_dispatch) for c in cands}
     assert any(ld == "batched" for _, ld in lds)
     assert any(ld == "unrolled" for _, ld in lds)
-    # fused is enumerated for the classical variant only: the slot tables
-    # encode the 7-term classical combos (winograd's chained within-level
-    # sums raise in core.strassen), and dense has nothing to batch or fuse
-    assert ("strassen", "fused") in lds
-    assert ("winograd", "fused") not in lds
-    assert ("dense", "fused") not in lds
+    # the two dispatches and nothing else; dense has nothing to batch
+    assert {ld for _, ld in lds} == {"unrolled", "batched"}
     assert ("dense", "batched") not in lds
 
 
@@ -401,13 +242,11 @@ def test_overhead_pricing_separates_the_dispatches():
     """With thousands of leaves, unrolled must be priced above batched on
     the machine models whose stack charge is the nominal write+read (that
     is the launch-overhead term the batched dispatch removes). The cpu
-    model is the deliberate exception since the fused-leaf recalibration:
-    its measured stack_word_cost (≈5.5, cache-thrash dominated) outweighs
-    even 7^6 thunk launches at depth 6 — matching the measured cpu ranking
-    where deep batched trails deep unrolled. Fused must undercut batched
-    in its shallow regime (the fig-4 bench shapes live at 1–2 levels):
-    same O(levels) launches, zero materialized stacks — while at depth 6
-    its 3^L slot-gather amplification prices it out, as measured."""
+    model is the deliberate exception: its measured stack_word_cost (≈5.5,
+    cache-thrash dominated) outweighs even 7^6 thunk launches at depth 6 —
+    matching the measured cpu ranking where deep batched trails deep
+    unrolled. These two are the whole dispatch axis the planner prices."""
+    assert defaults.LEAF_DISPATCH_CANDIDATES == ("unrolled", "batched")
     for backend in ("cpu", "tpu", "gpu"):
         pu, pb = (
             cost.predict_seconds(
@@ -424,15 +263,6 @@ def test_overhead_pricing_separates_the_dispatches():
             assert pb > pu, backend  # recalibrated: stacks beat launches
         else:
             assert pu > pb, backend
-    for backend in ("cpu", "tpu"):  # gpu's untuned model keeps them tied
-        pb1, pf1 = (
-            cost.predict_seconds(
-                "gemm_tn", "strassen", 8192, 8192, 8192, 4096,
-                backend=backend, leaf_dispatch=ld,
-            )
-            for ld in ("batched", "fused")
-        )
-        assert pf1 < pb1, backend
 
 
 def test_dispatch_calls_counts():
@@ -442,12 +272,6 @@ def test_dispatch_calls_counts():
     assert cost.dispatch_calls("gemm_tn", "strassen", 1024, 1024, 1024, 256, "batched") == 10
     s, g = cost._ata_leaves(1024, 1024, 256)
     assert cost.dispatch_calls("ata", "strassen", 1024, 1024, 1024, 256, "unrolled") == s + g
-    # fused: one launch per LEVEL, never per leaf — one fused leaf launch
-    # + one decode pass per level for Strassen; gathered diagonal syrk +
-    # per-level fused dot + per-level decode for ATA
-    assert cost.dispatch_calls("gemm_tn", "strassen", 1024, 1024, 1024, 256, "fused") == 3
-    assert cost.dispatch_calls("gemm_tn", "strassen", 1024, 1024, 1024, 512, "fused") == 2
-    assert cost.dispatch_calls("ata", "strassen", 1024, 1024, 1024, 256, "fused") == 6
 
 
 def test_plan_json_roundtrip_and_legacy_entries(_fresh_memo):
@@ -470,9 +294,10 @@ def test_autotuner_distinguishes_leaf_dispatch():
     base = cost.default_plan("ata", 512, 512)
     flipped = dataclasses.replace(base, leaf_dispatch="batched")
     assert not _same_dispatch(base, flipped)
-    fused = dataclasses.replace(base, leaf_dispatch="fused")
-    assert not _same_dispatch(base, fused)
-    assert not _same_dispatch(flipped, fused)
+    # the autotuner times only what the planner enumerates: the two
+    # dispatches, no 'fused'
+    assert {c.leaf_dispatch for c in cost.candidates("ata", 4096, 4096)} == {
+        "unrolled", "batched"}
 
 
 def test_ata_honors_plan_leaf_dispatch_bitwise(_fresh_memo):
@@ -488,8 +313,9 @@ def test_ata_honors_plan_leaf_dispatch_bitwise(_fresh_memo):
     by_hand = ata(a, n_base=64, variant="strassen", leaf_dispatch="batched")
     _bitwise(via_plan, by_hand)
     _bitwise(via_plan, ata(a, n_base=64, variant="strassen", leaf_dispatch="unrolled"))
-    p_fused = dataclasses.replace(p, leaf_dispatch="fused")
-    _bitwise(ata(a, plan=p_fused), via_plan)
+    # a plan naming the removed 'fused' dispatch is refused, not run
+    with pytest.raises(ValueError, match="leaf_dispatch"):
+        ata(a, plan=dataclasses.replace(p, leaf_dispatch="fused"))
 
 
 def test_root_pad_hoist_depth_matches_legacy_recursion():
@@ -513,7 +339,8 @@ def test_root_pad_hoist_depth_matches_legacy_recursion():
 
 def test_leaf_dispatch_validation():
     a = jnp.zeros((16, 16))
-    with pytest.raises(ValueError):
-        strassen_tn(a, a, n_base=8, leaf_dispatch="nope")
-    with pytest.raises(ValueError):
-        ata(a, n_base=8, leaf_dispatch="nope")
+    for ld in ("nope", "fused"):
+        with pytest.raises(ValueError, match="leaf_dispatch"):
+            strassen_tn(a, a, n_base=8, leaf_dispatch=ld)
+        with pytest.raises(ValueError, match="leaf_dispatch"):
+            ata(a, n_base=8, leaf_dispatch=ld)

@@ -200,16 +200,19 @@ def test_old_schema_cache_files_still_load_and_serve(tmp_path):
         assert served.source == "cache" and served.n_base == 128
 
 
-def test_unknown_leaf_dispatch_in_cache_falls_back_to_unrolled(tmp_path):
+@pytest.mark.parametrize("leaf_dispatch", ["hypercube", "fused"])
+def test_unknown_leaf_dispatch_in_cache_falls_back_to_unrolled(tmp_path,
+                                                               leaf_dispatch):
     """Regression (fused-leaf PR hardening): a cache entry written by a
     *future* schema may carry a leaf_dispatch this revision has never heard
-    of. Loading must sanitize it to 'unrolled' (always valid, bitwise-
-    identical output), not raise at every planned dispatch — the same
-    never-fatal contract as the corrupt-entry tolerance."""
+    of, and an older one may carry 'fused', a dispatch this revision no
+    longer has. Loading must sanitize either to 'unrolled' (always valid,
+    bitwise-identical output), not raise at every planned dispatch — the
+    same never-fatal contract as the corrupt-entry tolerance."""
     path = str(tmp_path / "future.json")
     p = dataclasses.replace(
         tune.plan(op="ata", m=640, n=640), n_base=256,
-        leaf_dispatch="hypercube", source="measured", measured_s=1e-3,
+        leaf_dispatch=leaf_dispatch, source="measured", measured_s=1e-3,
     )
     key = plan_key("ata", 640, 640, 640, 0, "float32", "dense", p.backend)
     with open(path, "w") as f:
